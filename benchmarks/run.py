@@ -32,6 +32,7 @@ from repro import runtime_config
 # Runtime knobs (REPRO_FAKE_DEVICES et al.) must land before anything can
 # initialise a jax backend — the shard lane's device grid depends on it.
 runtime_config.apply_env()
+runtime_config.compilation_cache()
 
 from repro.obs import metrics, runrecord, trace  # noqa: E402
 
@@ -52,9 +53,15 @@ from benchmarks.common import RESULT_DIR
 def run_tests():
     """Test lane: the tier-1 suite with the 25 slowest tests reported
     (the randomized differential suite's generator budgets are reviewed
-    through this listing — a slow random-graph strategy shows up here)."""
+    through this listing — a slow random-graph strategy shows up here).
+
+    The suite runs on the CPU backend: this process may already hold the
+    accelerator (an earlier lane of the same invocation), and a chip
+    belongs to one process at a time, so a child reaching for it would
+    fail or hang."""
     return subprocess.run(
         [sys.executable, "-m", "pytest", "-x", "-q", "--durations=25"],
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
         check=False).returncode
 
 

@@ -9,8 +9,9 @@ invariant classes:
 
   jaxpr/host-callback    banned host-interaction primitives inside a
                          schedule (``pure_callback``/``io_callback``/
-                         ``debug_callback``): one host round-trip turns
-                         "one cached device program" into a ping-pong.
+                         ``debug_callback``/``debug_print``): one host
+                         round-trip turns "one cached device program"
+                         into a ping-pong.
   jaxpr/dtype-drift      float avals whose dtype differs from the
                          lowering's float dtype. Audited under x64 the
                          lowering is float64 end to end, so any f32 aval
@@ -46,7 +47,8 @@ import numpy as np
 from repro.analysis import Violation
 
 #: primitives that are host round-trips — never legal inside a schedule
-BANNED_HOST_PRIMS = ("pure_callback", "io_callback", "debug_callback")
+BANNED_HOST_PRIMS = ("pure_callback", "io_callback", "debug_callback",
+                     "debug_print")
 
 #: gathers at or above this many output elements with >= 2 batching dims
 #: are flagged; below it they are sweep-body menu draws and harmless
@@ -68,7 +70,7 @@ class EntryPoint:
 # ----------------------------------------------------------------------
 
 def iter_eqns(jaxpr):
-    """Yield every eqn in ``jaxpr`` and all nested jaxprs (pjit / scan /
+    """Yield every eqn in ``jaxpr`` and all nested jaxprs (jit / scan /
     while / cond bodies), depth-first."""
     for eqn in jaxpr.eqns:
         yield eqn
@@ -77,12 +79,12 @@ def iter_eqns(jaxpr):
 
 
 def _sub_jaxprs(eqn):
-    import jax
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     def as_jaxpr(val):
-        if isinstance(val, jax.core.ClosedJaxpr):
+        if isinstance(val, ClosedJaxpr):
             return val.jaxpr
-        if isinstance(val, jax.core.Jaxpr):
+        if isinstance(val, Jaxpr):
             return val
         return None
 

@@ -94,12 +94,12 @@ def require_jax(feature: str = "the 'jax' search engine"):
     return jax
 
 
-def resolve_engine(name: str, *, allow_fallback: bool = True) -> str:
+def resolve_engine(name: str) -> str:
     """Normalise an engine name and check availability.
 
     ``auto`` picks ``jax`` when available, else ``numpy``. An explicit
-    ``jax`` request with jax missing raises ``EngineUnavailable`` unless
-    ``allow_fallback`` is set, in which case it degrades to ``numpy``.
+    ``jax`` request with jax missing raises ``EngineUnavailable``: it never
+    degrades to a host engine.
     """
     name = _ALIASES.get(name, name)
     if name == "auto":
@@ -108,8 +108,6 @@ def resolve_engine(name: str, *, allow_fallback: bool = True) -> str:
         raise ValueError(f"unknown engine {name!r}; known: "
                          f"{ENGINES + tuple(a for a in _ALIASES if a != 'auto')}")
     if name == "jax" and not jax_available():
-        if allow_fallback:
-            return "numpy"
         require_jax()
     return name
 

@@ -259,7 +259,7 @@ def _fleet_bf_chunk(static: StaticSpec, B: int, no_cut: bool,
                                 cb_row, take)
 
 
-def _shard_problem_axis(body, mesh, n_in: int, n_out, check_rep=True):
+def _shard_problem_axis(body, mesh, n_in: int, n_out, check_vma=True):
     """``shard_map`` a fleet bucket body over the mesh's ``dev`` axis.
 
     Pure data parallelism: every input and output splits its leading
@@ -270,18 +270,18 @@ def _shard_problem_axis(body, mesh, n_in: int, n_out, check_rep=True):
     pad ragged bucket sizes to a multiple of D with no-op lanes
     (``take=0`` / ``cap=0`` / duplicated lane 0, discarded on host).
 
-    ``check_rep=False`` for bodies containing ``lax.while_loop`` — the
-    static replication checker has no rule for it. The check only guards
-    replicated (``P()``) outputs; every output here is sharded, so
-    disabling it costs nothing.
+    ``check_vma=False`` for bodies containing ``lax.while_loop``: their
+    carries start from constants (device-invariant) and leave the body
+    device-varying, which the varying-manual-axes checker rejects. The
+    check only guards replicated (``P()``) outputs; every output here is
+    sharded, so disabling it costs nothing.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
-    return shard_map(body, mesh=mesh, in_specs=(P("dev"),) * n_in,
-                     out_specs=jax.tree_util.tree_map(
-                         lambda _: P("dev"), n_out),
-                     check_rep=check_rep)
+    return jax.shard_map(body, mesh=mesh, in_specs=(P("dev"),) * n_in,
+                         out_specs=jax.tree_util.tree_map(
+                             lambda _: P("dev"), n_out),
+                         check_vma=check_vma)
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
@@ -319,7 +319,6 @@ def _fleet_sa_sweeps_shard(static: StaticSpec, gran, has_cut_edges: bool,
                            n_sweeps: int, mesh, A, menus, menu_sizes,
                            clamp, kv_fix, state, temps, scale, cooling,
                            k_min):
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     TRACE_COUNTS["fleet_sa_sweeps_shard"] += 1
@@ -327,7 +326,7 @@ def _fleet_sa_sweeps_shard(static: StaticSpec, gran, has_cut_edges: bool,
                              has_cut_edges, n_sweeps)
     # cooling / k_min are traced schedule scalars — replicated, not
     # problem-axis data, hence the two trailing P() specs
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P("dev"),) * 8 + (P(), P()),
         out_specs=(P("dev"), P("dev"), P("dev")),
@@ -772,7 +771,7 @@ def _fleet_rb_descend_shard(static: StaticSpec, gran, mesh, A, menus,
     TRACE_COUNTS["fleet_rb_descend_shard"] += 1
     body = functools.partial(_fleet_rb_descend_core, static, gran)
     return _shard_problem_axis(body, mesh, 12, (0, 0, 0, 0),
-                               check_rep=False)(
+                               check_vma=False)(
         A, menus, menu_sizes, clamp, si, so, kk, cb_row, part_mask, pidx,
         amort, cap)
 

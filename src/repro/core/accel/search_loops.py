@@ -513,13 +513,12 @@ def _bf_chunk_shard(static: StaticSpec, B: int, no_cut: bool, mesh,
     so it rides along as one more static argument and device counts get
     their own executables (asserted via the ``bf_chunk_shard`` trace key).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     TRACE_COUNTS["bf_chunk_shard"] += 1
     D = int(mesh.devices.size)
     body = functools.partial(_bf_shard_chunk, static, B, no_cut, D)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), P(), P(), P(), P(), P()),
         out_specs=(P("dev"), P(), P(), P()),
@@ -538,9 +537,10 @@ def brute_force_jax(problem, include_cuts: bool, max_cuts: int,
     compiles once per problem family.
 
     ``devices=D`` shards each chunk's row axis over the first D visible
-    devices (``runtime_config.device_mesh``); results stay bit-identical
-    to ``devices=None`` — the single-device program — for any D (the
-    randomized differential suite asserts the {1, 2, 8} grid).
+    devices (``runtime_config.device_mesh``), ``batch_size`` rows per
+    device; results stay bit-identical to ``devices=None`` — the
+    single-device program — for any D (the randomized differential suite
+    asserts the {1, 2, 8} grid; the history is chunking-invariant).
     """
     from repro.core.optimizers.brute_force import (
         _clamp_tables,
@@ -569,8 +569,10 @@ def brute_force_jax(problem, include_cuts: bool, max_cuts: int,
         from repro import runtime_config
         mesh = runtime_config.device_mesh(devices)
         D = int(mesh.devices.size)
-        B = -(-B // D) * D        # D | B (chunk boundaries may move; the
-        #                           history is chunking-invariant)
+        # each device takes the single-device chunk's B rows: the TPU
+        # compiler's float32 rounding of a row's objective depends on the
+        # compiled row count, so a B/D-row slice would drift by an ulp
+        B *= D
 
     base = backend.initial(graph).with_cuts(())
 

@@ -109,7 +109,7 @@ def joint_search(cp: CoMapProblem, optimiser: str = "rule_based",
     if optimiser not in OPTIMIZERS:
         raise ValueError(f"unknown optimiser {optimiser!r}; choose from "
                          f"{sorted(OPTIMIZERS)}")
-    eng = resolve_engine(engine, allow_fallback=False)
+    eng = resolve_engine(engine)
     t0 = time.monotonic()
     menu = cp.resolved_splits()
     S, N = len(menu), cp.n_nets

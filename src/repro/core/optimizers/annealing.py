@@ -61,7 +61,7 @@ def optimise(problem: Problem,
              engine: str = "host") -> OptimResult:
     if engine not in ("host", "scalar", "numpy", "batched"):
         from repro.core.accel import resolve_engine
-        engine = resolve_engine(engine, allow_fallback=False)
+        engine = resolve_engine(engine)
     if engine == "jax":
         result = _optimise_jax(problem, seed, k_start, k_min, cooling,
                                time_budget_s, max_iters, objective_scale,

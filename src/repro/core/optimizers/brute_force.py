@@ -47,7 +47,7 @@ def optimise(problem: Problem,
              batch_size: int = 4096,
              devices: Optional[int] = None) -> OptimResult:
     from repro.core.accel import resolve_engine
-    engine = resolve_engine(engine, allow_fallback=False)
+    engine = resolve_engine(engine)
     if devices is not None and engine != "jax":
         raise ValueError(
             f"devices={devices} requires the jax engine (sharded chunk "

@@ -409,7 +409,7 @@ def optimise(problem: Problem,
     # move sequence. The outer merge loop (_algorithm2) is shared verbatim
     # by all three.
     from repro.core.accel import resolve_engine
-    eng = resolve_engine(engine, allow_fallback=False)
+    eng = resolve_engine(engine)
     if eng == "jax":
         from repro.core.accel.search_loops import DeviceRuleBased
         descend = DeviceRuleBased(problem).descend

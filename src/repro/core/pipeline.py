@@ -210,7 +210,7 @@ def optimise_portfolio(archs: Sequence, shapes,
         problems = [make_problem(a, s, p, backend, o, exec_model, opts)
                     for a, s, p, o in
                     zip(archs, shapes, platforms, objectives)]
-    eng = resolve_engine(engine, allow_fallback=False)
+    eng = resolve_engine(engine)
     # Identical Problems — same canonical lowered program, hence identical
     # results from every deterministic engine — used to be re-validated,
     # re-lowered and re-searched once per duplicate. Coalesce them by the

@@ -33,10 +33,12 @@ instead of silently doing nothing. :func:`jax_initialised` performs that
 check without importing jax, so this module stays importable in the
 ``REPRO_NO_JAX`` matrix.
 
-``device_mesh`` is the one jax-touching helper (lazy import): the 1-D
-``Mesh`` over the ``"dev"`` axis that the sharded engines
-(``core/accel/search_loops.py`` / ``core/accel/fleet.py``, see
-docs/distributed.md) consume.
+``device_mesh`` and ``compilation_cache`` are the jax-touching helpers
+(lazy imports): the 1-D ``Mesh`` over the ``"dev"`` axis that the sharded
+engines (``core/accel/search_loops.py`` / ``core/accel/fleet.py``, see
+docs/distributed.md) consume, and the persistent compilation cache that
+``chip_smoke.py`` and ``benchmarks/run.py`` turn on (the test suite does
+not: its compiles are per-process and small).
 """
 from __future__ import annotations
 
@@ -48,14 +50,24 @@ from typing import Callable, Optional, TypeVar
 __all__ = [
     "RuntimeConfig", "resolve", "configure", "apply_env", "fake_devices",
     "merge_xla_flags", "set_backend", "enable_x64", "set_debug_nans",
-    "jax_initialised", "device_mesh",
+    "jax_initialised", "device_mesh", "compilation_cache",
     "ENV_BACKEND", "ENV_FAKE_DEVICES", "ENV_X64", "ENV_DEBUG_NANS",
+    "ENV_CACHE_DIR", "DEFAULT_CACHE_DIR",
 ]
 
 ENV_BACKEND = "REPRO_BACKEND"
 ENV_FAKE_DEVICES = "REPRO_FAKE_DEVICES"
 ENV_X64 = "REPRO_X64"
 ENV_DEBUG_NANS = "REPRO_DEBUG_NANS"
+#: jax's own variable for the persistent compilation cache directory
+ENV_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
+
+#: the cache directory when ``JAX_COMPILATION_CACHE_DIR`` is unset: a fixed
+#: path under the checkout (src/repro/ -> root), so every run of the same
+#: checkout finds the executables the previous run wrote (.gitignore'd)
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 _COUNT_FLAG = "--xla_force_host_platform_device_count"
 
@@ -282,3 +294,29 @@ def device_mesh(devices: Optional[int] = None):
             f"for CPU testing call runtime_config.fake_devices({n}) (or "
             f"set {ENV_FAKE_DEVICES}={n}) before the first jax use.")
     return Mesh(np.asarray(devs[:n]), ("dev",))
+
+
+# ----------------------------------------------------------------------
+# the persistent compilation cache
+# ----------------------------------------------------------------------
+
+def compilation_cache() -> Optional[str]:
+    """Turn on jax's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is jax's own setting and is
+    left alone: the cache lives there and nowhere else. Otherwise the
+    cache goes to ``DEFAULT_CACHE_DIR`` (``<checkout>/.jax_cache``). Every
+    compile is cached, however quick: jax's default skips compiles under a
+    second, which on CPU is most of the engine programs. Without jax
+    (absent, or masked by ``REPRO_NO_JAX``) nothing is set and the result
+    is None."""
+    from repro.core.accel import jax_available
+    if not jax_available():
+        return None
+    import jax
+
+    path = os.environ.get(ENV_CACHE_DIR) or DEFAULT_CACHE_DIR
+    if not os.environ.get(ENV_CACHE_DIR):
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path

@@ -345,8 +345,7 @@ class MappingServer:
         if self._expired(req):
             return
         try:
-            req.resolved_engine = resolve_engine(req.engine,
-                                                 allow_fallback=False)
+            req.resolved_engine = resolve_engine(req.engine)
             req.key = request_key(req.problem, req.optimiser,
                                   req.resolved_engine, req.kwargs)
         except Exception as e:

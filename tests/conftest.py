@@ -33,6 +33,7 @@ if not jax_available():
         "test_runtime.py",
         "test_shard.py",
         "test_steps.py",
+        "test_tpu_compile.py",
     ]
 from repro.configs.base import ArchConfig, ShapeSpec
 from repro.core.backends import BACKENDS

@@ -130,7 +130,7 @@ def test_jax_matches_objectives_exec_models_and_options():
 
 def test_jax_float64_matches_at_1e9():
     """x64 on-device arrays recover the numpy engine's 1e-9 contract."""
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         prob = _problem("tinyllama-1.1b", TRAIN)
         designs = _random_designs(prob, 20, seed=5)
         jev = JaxEvaluator.from_problem(prob)
@@ -757,9 +757,9 @@ def test_registry_names_missing_extra(monkeypatch):
     missing extra, instead of an ImportError mid-search."""
     import repro.core.accel as accel
     monkeypatch.setattr(accel, "jax_available", lambda: False)
-    assert accel.resolve_engine("jax", allow_fallback=True) == "numpy"
     with pytest.raises(EngineUnavailable, match="jax"):
-        accel.resolve_engine("jax", allow_fallback=False)
+        accel.resolve_engine("jax")
+    assert accel.resolve_engine("auto") == "numpy"
     with pytest.raises(EngineUnavailable, match="pip install jax"):
         accel.require_jax()
 

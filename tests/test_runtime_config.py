@@ -175,3 +175,33 @@ def test_device_mesh_bounds():
         rc.device_mesh(0)
     with pytest.raises(ValueError, match="fake_devices"):
         rc.device_mesh(n + 1)
+
+
+@pytest.mark.skipif(not jax_available(), reason="needs jax")
+@pytest.mark.parametrize("where", ["env", "checkout", "no_jax"])
+def test_compilation_cache_directory(where, monkeypatch, tmp_path):
+    """$JAX_COMPILATION_CACHE_DIR is used as is (jax reads it itself);
+    without it the cache goes to the fixed git-ignored <checkout>/.jax_cache;
+    without jax nothing is set. jax.config is stubbed: the suite keeps no
+    persistent cache."""
+    import jax
+    updates = {}
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, value: updates.__setitem__(name, value))
+    monkeypatch.delenv(rc.ENV_CACHE_DIR, raising=False)
+    if where == "env":
+        monkeypatch.setenv(rc.ENV_CACHE_DIR, str(tmp_path))
+        assert rc.compilation_cache() == str(tmp_path)
+        assert "jax_compilation_cache_dir" not in updates
+    elif where == "checkout":
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert rc.compilation_cache() == os.path.join(root, ".jax_cache")
+        assert updates["jax_compilation_cache_dir"] == rc.DEFAULT_CACHE_DIR
+        with open(os.path.join(root, ".gitignore")) as f:
+            assert ".jax_cache/" in f.read().split()
+    else:
+        monkeypatch.setenv("REPRO_NO_JAX", "1")
+        assert rc.compilation_cache() is None
+        assert updates == {}
+    if where != "no_jax":
+        assert updates["jax_persistent_cache_min_compile_time_secs"] == 0.0

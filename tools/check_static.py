@@ -15,9 +15,12 @@ Modes:
   jax    require the jaxpr audit; exit 2 if jax is unavailable. x64 is
          enabled first so the audit checks the strict float64
          differential regime.
-  nojax  AST pack + recompile lint only (sets ``REPRO_NO_JAX=1`` so an
-         installed jax cannot leak in) — runnable with nothing but the
-         standard library + numpy.
+  nojax  AST pack + recompile lint only (sets ``REPRO_NO_JAX=1`` for
+         the passes so an installed jax cannot leak in) — runnable with
+         nothing but the standard library + numpy.
+
+Both settings hold only while the passes run and are restored after, so
+``main`` can be called inside a longer-lived process (the test suite).
 
 Exit status: 0 clean (or report-only), 1 new violations with
 ``--fail-on-new`` (each printed with its rule id and location), 2 usage /
@@ -33,6 +36,7 @@ the current tree — review the diff before committing it.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import os
 import sys
@@ -46,7 +50,6 @@ DEFAULT_BASELINE = os.path.join(ROOT, "tools", "static_baseline.json")
 
 def resolve_mode(mode: str) -> str:
     if mode == "nojax":
-        os.environ["REPRO_NO_JAX"] = "1"
         return "nojax"
     from repro.core.accel import jax_available
     if mode == "jax":
@@ -57,6 +60,26 @@ def resolve_mode(mode: str) -> str:
             raise SystemExit(2)
         return "jax"
     return "jax" if jax_available() else "nojax"
+
+
+@contextlib.contextmanager
+def mode_settings(mode: str):
+    """``REPRO_NO_JAX=1`` (nojax) or ``jax_enable_x64`` (jax) for the
+    duration of the block; the previous value is restored on exit."""
+    if mode == "jax":
+        import jax
+        with jax.enable_x64(True):
+            yield
+        return
+    old = os.environ.get("REPRO_NO_JAX")
+    os.environ["REPRO_NO_JAX"] = "1"
+    try:
+        yield
+    finally:
+        if old is None:
+            del os.environ["REPRO_NO_JAX"]
+        else:
+            os.environ["REPRO_NO_JAX"] = old
 
 
 def run_passes(mode: str):
@@ -80,8 +103,6 @@ def run_passes(mode: str):
     add_pass(recompile_lint.run(), time.perf_counter() - t0)
 
     if mode == "jax":
-        import jax
-        jax.config.update("jax_enable_x64", True)
         from repro.analysis import jaxpr_audit
         t0 = time.perf_counter()
         add_pass(jaxpr_audit.run(timings=lower_timings),
@@ -106,7 +127,8 @@ def main(argv=None) -> int:
     mode = resolve_mode(args.mode)
     from repro.analysis import load_baseline
 
-    report, lower_timings = run_passes(mode)
+    with mode_settings(mode):
+        report, lower_timings = run_passes(mode)
     baseline = load_baseline(args.baseline)
     data = report.to_json(baseline)
     data["lowerings"] = {k: round(v, 4)
