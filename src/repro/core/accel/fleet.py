@@ -410,9 +410,6 @@ class _BFMember:
         """Identical improvement bookkeeping to the per-problem engine
         (same shared helper)."""
         objs = np.asarray(objs[:take], np.float64)
-        if _trace.enabled():
-            _metrics.histogram("accel.fleet_bf.feasible_fraction").observe(
-                float(np.isfinite(objs).mean()) if take else 0.0)
         self.problem.note_batch_evals(take)
         last_imp, self.best_obj = absorb_improvements(
             objs, self.best_obj, self.points, self.history)

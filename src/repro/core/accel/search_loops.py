@@ -627,9 +627,6 @@ def brute_force_jax(problem, include_cuts: bool, max_cuts: int,
                 # above, absorbs the device compute time
                 with _trace.span("accel.d2h.bf_chunk", take=take):
                     objs = np.asarray(objs[:take], np.float64)
-                if _trace.enabled():
-                    _metrics.histogram("accel.bf.feasible_fraction").observe(
-                        float(np.isfinite(objs).mean()) if take else 0.0)
                 problem.note_batch_evals(take)
                 last_imp, best_obj = absorb_improvements(objs, best_obj,
                                                          points, history)
@@ -661,6 +658,7 @@ def brute_force_jax(problem, include_cuts: bool, max_cuts: int,
 # multi-chain simulated annealing, one lax.scan sweep loop on device
 # ----------------------------------------------------------------------
 
+@_trace.traced("accel.build_sa_tables")
 def build_sa_tables(problem, *, pad_nodes: Optional[int] = None,
                     pad_menu: Optional[int] = None,
                     pad_val: Optional[int] = None):
@@ -762,6 +760,7 @@ class DeviceSA:
         self.has_cut_edges = has_cuts
 
     # ------------------------------------------------------------------
+    @_trace.traced("accel.h2d.sa_state")
     def init_state(self, v0: Variables, ev0, chains: int, seed: int):
         n = self.static.n_nodes
         idt = self.A.batch.dtype
@@ -803,12 +802,13 @@ class DeviceSA:
     def best_variables(self, state):
         """Per-chain incumbents as host ``Variables`` + (objective, feasible)."""
         nr = self.n_real
-        si = np.asarray(state["best_si"])[:, :nr]
-        so = np.asarray(state["best_so"])[:, :nr]
-        kk = np.asarray(state["best_kk"])[:, :nr]
-        cb = np.asarray(state["best_cb"])[:, :max(nr - 1, 0)]
-        objs = np.asarray(state["best_obj"], np.float64)
-        feas = np.asarray(state["best_feas"], bool)
+        with _trace.span("accel.d2h.sa_best"):
+            si = np.asarray(state["best_si"])[:, :nr]
+            so = np.asarray(state["best_so"])[:, :nr]
+            kk = np.asarray(state["best_kk"])[:, :nr]
+            cb = np.asarray(state["best_cb"])[:, :max(nr - 1, 0)]
+            objs = np.asarray(state["best_obj"], np.float64)
+            feas = np.asarray(state["best_feas"], bool)
         out = []
         for c in range(si.shape[0]):
             cuts = tuple(int(e) for e in np.nonzero(cb[c])[0])
@@ -1176,14 +1176,22 @@ class DeviceRuleBased:
         return v2, int(pts)
 
     def descend(self, v: Variables, part):
+        """One descent: pack and copy the request to the device
+        (``accel.h2d.rb_descend``), enqueue ``_rb_descend``
+        (``accel.dispatch.rb_descend``), then the blocking readback
+        (``accel.d2h.rb_descend``), which absorbs the device time."""
         idt = self.A.batch.dtype
         fdt = self.A.flops.dtype
-        si, so, kk, cb_row, part_mask, pidx, cap = self.pack_request(v, part)
+        with _trace.span("accel.h2d.rb_descend"):
+            si, so, kk, cb_row, part_mask, pidx, cap = \
+                self.pack_request(v, part)
+            args = (jnp.asarray(si, idt), jnp.asarray(so, idt),
+                    jnp.asarray(kk, idt), jnp.asarray(cb_row),
+                    jnp.asarray(part_mask), jnp.asarray(pidx, idt),
+                    jnp.asarray(self.amort, fdt), jnp.asarray(cap, idt))
         with _metrics.device_dispatch("rb_descend", part=len(part)):
-            o_si, o_so, o_kk, pts = _rb_descend(
-                self.static, self.gran, self.A, self.menus, self.menu_sizes,
-                self.clamp, jnp.asarray(si, idt), jnp.asarray(so, idt),
-                jnp.asarray(kk, idt), jnp.asarray(cb_row),
-                jnp.asarray(part_mask), jnp.asarray(pidx, idt),
-                jnp.asarray(self.amort, fdt), jnp.asarray(cap, idt))
+            out = _rb_descend(self.static, self.gran, self.A, self.menus,
+                              self.menu_sizes, self.clamp, *args)
+        with _trace.span("accel.d2h.rb_descend"):
+            o_si, o_so, o_kk, pts = (np.asarray(x) for x in out)
         return self.unpack(v, o_si, o_so, o_kk, pts)

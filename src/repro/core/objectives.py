@@ -64,6 +64,7 @@ class Problem:
     opts: ModelOptions = field(default_factory=ModelOptions)
 
     _eval_count: int = 0                  # points/s accounting (Table IV)
+    _host_evals: int = 0                  # memo-miss scalar evaluations
     _cache: dict = field(default_factory=dict, repr=False)
     _cache_cap: int = 200_000
 
@@ -99,6 +100,7 @@ class Problem:
         if cached is not None:
             return cached
         self._eval_count += 1
+        self._host_evals += 1
         evals = self._eval_nodes(v)
         rep = C.check_all(self.graph, v, self.platform, evals,
                           self.exec_model, self.backend, C.ConstraintReport())
@@ -193,6 +195,12 @@ class Problem:
     @property
     def evals_done(self) -> int:
         return self._eval_count
+
+    @property
+    def host_evals(self) -> int:
+        """Float64 scalar evaluations that missed the memo (the host's
+        share of ``evals_done``; batched device points not counted)."""
+        return self._host_evals
 
 
 # ----------------------------------------------------------------------

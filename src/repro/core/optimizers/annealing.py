@@ -43,6 +43,7 @@ from repro.core.hdgraph import Variables
 from repro.core.objectives import Problem
 from repro.core.optimizers.common import OptimResult, incumbent_better, repair
 from repro.obs import metrics as _metrics
+from repro.obs import trace as _trace
 
 #: temperature ratio between adjacent parallel-tempering chains
 LADDER_SPREAD = 1.6
@@ -264,8 +265,10 @@ def _optimise_jax(problem, seed, k_start, k_min, cooling, time_budget_s,
             break
         state, temps, (t_obj, t_feas) = sa.run(state, temps, scale,
                                                cooling, k_min, chunk)
-        t_obj = np.asarray(t_obj, np.float64)
-        t_feas = np.asarray(t_feas, bool)
+        # blocking readback: absorbs the scan's device time
+        with _trace.span("accel.d2h.sa_sweeps"):
+            t_obj = np.asarray(t_obj, np.float64)
+            t_feas = np.asarray(t_feas, bool)
         for t in range(chunk):
             # feasibility-aware best across chains after this sweep
             row_f = t_feas[t]

@@ -7,6 +7,7 @@ from typing import List, Optional, Tuple
 
 from repro.core.hdgraph import Variables, partitions_from_cuts
 from repro.core.objectives import Evaluation, Problem
+from repro.obs import trace as _trace
 
 
 @dataclass
@@ -36,6 +37,7 @@ def incumbent_better(cand_feasible: bool, cand_objective: float,
     return cand_objective < best_objective
 
 
+@_trace.traced("optim.repair")
 def repair(problem: Problem, v: Variables, max_steps: int = 1024) -> Variables:
     """Greedy feasibility repair.
 

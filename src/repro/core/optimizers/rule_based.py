@@ -20,6 +20,7 @@ from repro.core.objectives import Problem
 from repro.core.optimizers.common import OptimResult, repair
 from repro.core.perfmodel import partition_time, t_conf
 from repro.obs import metrics as _metrics
+from repro.obs import trace as _trace
 
 VARS = ("s_in", "s_out", "kern")
 
@@ -386,13 +387,19 @@ def _algorithm2(problem: Problem,
 
 def drive(gen, descend) -> OptimResult:
     """Run an ``_algorithm2`` generator to completion against a descent
-    callable ``descend(v, part) -> (v_optimised, probe_points)``."""
-    try:
-        req = next(gen)
-        while True:
-            req = gen.send(descend(*req))
-    except StopIteration as stop:
-        return stop.value
+    callable ``descend(v, part) -> (v_optimised, probe_points)``.
+
+    Each stretch of host work between two descents (merge bookkeeping,
+    propagate, ``repair``, the float64 evaluations) is one
+    ``optim.rb.host`` span."""
+    answer = None                       # send(None) starts the generator
+    while True:
+        with _trace.span("optim.rb.host"):
+            try:
+                req = gen.send(answer)
+            except StopIteration as stop:
+                return stop.value
+        answer = descend(*req)
 
 
 def optimise(problem: Problem,

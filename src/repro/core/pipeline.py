@@ -108,6 +108,7 @@ def optimise_mapping(arch: ArchConfig, shape: ShapeSpec,
             optimiser_kwargs["engine"] = engine
         with _trace.span("pipeline.optimise", optimiser=optimiser):
             result = OPTIMIZERS[optimiser](problem, **optimiser_kwargs)
+        _metrics.counter("optim.host_evals").inc(problem.host_evals)
         with _trace.span("pipeline.export_plan"):
             return export_plan(problem.graph, result.variables, platform,
                                exec_model, result.evaluation)

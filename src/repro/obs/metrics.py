@@ -2,18 +2,17 @@
 
 Always on. A counter increment is a dict lookup plus an integer add —
 the same cost class as the bare ``TRACE_COUNTS`` dict this module
-absorbs — so instrumentation points don't need an enabled-check. The
-exceptions are *derived* observations (feasible fractions, per-chunk
-histograms) whose computation costs something; call sites gate those on
-``trace.enabled()``.
+absorbs — so instrumentation points don't need an enabled-check. A
+count that would cost a registry call per event is kept by its owner
+and added once per request (``optim.host_evals``).
 
 Instrument types
 ----------------
   Counter    monotone int; ``inc(n)``. Evaluation counts, dispatches,
              executable-cache hits.
   Gauge      last-written float; ``set(v)``. points/s of the latest run.
-  Histogram  count/sum/min/max summary; ``observe(v)``. Chunk sizes,
-             feasible fractions.
+  Histogram  count/sum/min/max summary; ``observe(v)``. Seconds per
+             run, request latencies.
   Series     bounded list of (x, y) float pairs; ``append(x, y)``.
              Incumbent-objective-vs-points convergence curves.
 

@@ -22,6 +22,12 @@ Design constraints, in order:
      so a runaway loop degrades telemetry instead of memory.
   4. **Zero dependencies.** stdlib only; this module is part of the
      ``REPRO_NO_JAX`` import matrix.
+  5. **One clock with the device trace.** While a span is recorded, a
+     ``jax.profiler.TraceAnnotation`` of the same name is open around
+     it, so a profile taken with tracing on shows every program span on
+     the host plane, on the profiler's own clock, beside the device
+     operations. The annotation factory is looked up in ``enable`` (a
+     function-level import); without jax there is no mirror.
 
 Usage::
 
@@ -64,7 +70,7 @@ class Span:
     """
 
     __slots__ = ("name", "attrs", "t0", "t1", "_rec", "_tr", "id", "parent",
-                 "depth")
+                 "depth", "_note")
 
     def __init__(self, name: str, attrs: Optional[Dict[str, Any]],
                  tracer: Optional["Tracer"]) -> None:
@@ -77,6 +83,7 @@ class Span:
         self.id = -1
         self.parent = -1
         self.depth = 0
+        self._note = None
 
     def __enter__(self) -> "Span":
         if self._rec:
@@ -117,9 +124,11 @@ class Tracer:
         self._spans: List[Dict[str, Any]] = []
         self._dropped = 0
         self._epoch = time.perf_counter()
+        self._annotate: Optional[Callable[[str], Any]] = None
 
     # -- lifecycle -----------------------------------------------------
     def enable(self) -> None:
+        self._annotate = _profiler_annotation()
         self._enabled = True
 
     def disable(self) -> None:
@@ -167,6 +176,9 @@ class Tracer:
         sp.parent = st[-1].id if st else -1
         sp.depth = len(st)
         st.append(sp)
+        if self._annotate is not None:
+            sp._note = self._annotate(sp.name)
+            sp._note.__enter__()
 
     def _pop(self, sp: Span, failed: bool = False) -> None:
         st = self._stack()
@@ -175,6 +187,8 @@ class Tracer:
             st.pop()
         elif sp in st:
             st.remove(sp)
+        if sp._note is not None:
+            sp._note.__exit__(None, None, None)
         if failed:
             sp.set(failed=True)
         rec = {
@@ -203,6 +217,15 @@ class Tracer:
     def dropped(self) -> int:
         with self._lock:
             return self._dropped
+
+
+def _profiler_annotation() -> Optional[Callable[[str], Any]]:
+    """``jax.profiler.TraceAnnotation``, or None where jax is absent."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation
 
 
 #: the process-wide tracer every instrumentation point talks to.

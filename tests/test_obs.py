@@ -336,6 +336,21 @@ def test_telemetry_does_not_change_results(data):
             snap = metrics.snapshot()
             assert any(k.startswith("optim.") and k.endswith(".runs")
                        for k in snap["counters"]), (label, eng)
+            names = {s["name"] for s in trace.snapshot()}
+            assert SPAN_SITES.get((label, eng), set()) <= names, (label, eng)
+
+
+#: spans each (optimiser, engine) run of the differential must record
+SPAN_SITES = {
+    ("rb", "scalar"): {"optim.rb.host", "optim.repair"},
+    ("rb", "numpy"): {"optim.rb.host", "optim.repair"},
+    ("rb", "jax"): {"optim.rb.host", "optim.repair", "accel.build_sa_tables",
+                    "accel.h2d.rb_descend", "accel.dispatch.rb_descend",
+                    "accel.d2h.rb_descend"},
+    ("sa", "jax"): {"optim.repair", "accel.build_sa_tables",
+                    "accel.h2d.sa_state", "accel.d2h.sa_sweeps",
+                    "accel.d2h.sa_best"},
+}
 
 
 @pytest.mark.skipif(not jax_available(), reason="jax engines absent")
