@@ -297,15 +297,10 @@ def build_entry_points(problems: Optional[Sequence] = None
     def rb_descend():
         rb = DeviceRuleBased(p)
         v0 = p.backend.initial(p.graph)
-        si, so, kk, cb_row, pm, pidx, cap = rb.pack_request(
-            v0, tuple(range(rb.n_real)))
-        idt, fdt = rb.A.batch.dtype, rb.A.flops.dtype
+        req = rb.pack_descent(v0, tuple(range(rb.n_real)))
         return jax.make_jaxpr(_rb_descend, static_argnums=(0, 1))(
             rb.static, rb.gran, rb.A, rb.menus, rb.menu_sizes, rb.clamp,
-            jnp.asarray(si, idt), jnp.asarray(so, idt),
-            jnp.asarray(kk, idt), jnp.asarray(cb_row), jnp.asarray(pm),
-            jnp.asarray(pidx, idt), jnp.asarray(rb.amort, fdt),
-            jnp.asarray(cap, idt)), fdt
+            jnp.asarray(req), rb.amort_dev), rb.A.flops.dtype
 
     def fleet_bf_chunk():
         members = [_BFMember(i, q, False, 1)

@@ -1100,11 +1100,25 @@ def _rb_descend_core(static: StaticSpec, gran: Tuple[str, str, str],
 
 @functools.partial(jax.jit, static_argnums=(0, 1))
 def _rb_descend(static: StaticSpec, gran: Tuple[str, str, str],
-                A: DeviceArrays, menus, menu_sizes, clamp,
-                si, so, kk, cb_row, part_mask, pidx, amort, cap):
+                A: DeviceArrays, menus, menu_sizes, clamp, req, amort):
+    """One single-problem descent from one packed request vector.
+
+    ``req`` is ``si | so | kk | cb_row | part_mask | pidx | cap`` in the
+    device int dtype, the masks as 0/1 (``DeviceRuleBased.descend``
+    packs it); the offsets follow from ``static.n_nodes``. Returns one
+    vector ``si | so | kk | points`` in the same dtype, so a descent is
+    one copy each way."""
     TRACE_COUNTS["rb_descend"] += 1
-    return _rb_descend_core(static, gran, A, menus, menu_sizes, clamp,
-                            si, so, kk, cb_row, part_mask, pidx, amort, cap)
+    n = static.n_nodes
+    e = max(n - 1, 0)
+    si, so, kk = req[:n], req[n:2 * n], req[2 * n:3 * n]
+    cb_row = req[3 * n:3 * n + e] != 0
+    part_mask = req[3 * n + e:4 * n + e] != 0
+    pidx, cap = req[4 * n + e], req[4 * n + e + 1]
+    si, so, kk, points = _rb_descend_core(
+        static, gran, A, menus, menu_sizes, clamp, si, so, kk, cb_row,
+        part_mask, pidx, amort, cap)
+    return jnp.concatenate([si, so, kk, points[None]])
 
 
 class DeviceRuleBased:
@@ -1145,9 +1159,13 @@ class DeviceRuleBased:
         self.menu_sizes = jnp.asarray(menu_sizes, idt)
         self.clamp = jnp.asarray(clamp, idt)
         self.gran = gran
-        # Eq. 3/4 reconfiguration amortisation, as in optimise_partition
+        # Eq. 3/4 reconfiguration amortisation, as in optimise_partition;
+        # the float is what the fleet stacks, the device scalar is what
+        # ``descend`` passes (a constant of the problem, copied once)
         self.amort = (1.0 if problem.objective == "latency"
                       else 1.0 / max(problem.batch_amortisation, 1))
+        self.amort_dev = jax.device_put(
+            np.asarray(self.amort, self.A.flops.dtype))
 
     # ------------------------------------------------------------------
     def pack_request(self, v: Variables, part):
@@ -1166,6 +1184,14 @@ class DeviceRuleBased:
         return (av(v.s_in), av(v.s_out), av(v.kern), cb_row, part_mask,
                 pidx, cap)
 
+    def pack_descent(self, v: Variables, part) -> np.ndarray:
+        """``pack_request`` as the one vector ``_rb_descend`` reads:
+        ``si | so | kk | cb_row | part_mask | pidx | cap`` in the device
+        int dtype, the masks as 0/1."""
+        si, so, kk, cb_row, part_mask, pidx, cap = self.pack_request(v, part)
+        return np.concatenate((si, so, kk, cb_row, part_mask, (pidx, cap)),
+                              dtype=self.A.batch.dtype)
+
     def unpack(self, v: Variables, o_si, o_so, o_kk, pts):
         nr = self.n_real
         v2 = Variables(v.cuts,
@@ -1176,22 +1202,23 @@ class DeviceRuleBased:
         return v2, int(pts)
 
     def descend(self, v: Variables, part):
-        """One descent: pack and copy the request to the device
-        (``accel.h2d.rb_descend``), enqueue ``_rb_descend``
-        (``accel.dispatch.rb_descend``), then the blocking readback
-        (``accel.d2h.rb_descend``), which absorbs the device time."""
-        idt = self.A.batch.dtype
-        fdt = self.A.flops.dtype
+        """One descent, one copy each way: pack the request into one
+        vector and copy it to the device (``accel.h2d.rb_descend``),
+        enqueue ``_rb_descend`` (``accel.dispatch.rb_descend``), then one
+        blocking readback of the packed answer (``accel.d2h.rb_descend``),
+        which absorbs the device time. ``accel.transfers.rb_descend``
+        counts both copies."""
+        n = self.static.n_nodes
+        transfers = _metrics.counter("accel.transfers.rb_descend")
         with _trace.span("accel.h2d.rb_descend"):
-            si, so, kk, cb_row, part_mask, pidx, cap = \
-                self.pack_request(v, part)
-            args = (jnp.asarray(si, idt), jnp.asarray(so, idt),
-                    jnp.asarray(kk, idt), jnp.asarray(cb_row),
-                    jnp.asarray(part_mask), jnp.asarray(pidx, idt),
-                    jnp.asarray(self.amort, fdt), jnp.asarray(cap, idt))
+            req = jax.device_put(self.pack_descent(v, part))
+            transfers.inc()
         with _metrics.device_dispatch("rb_descend", part=len(part)):
             out = _rb_descend(self.static, self.gran, self.A, self.menus,
-                              self.menu_sizes, self.clamp, *args)
+                              self.menu_sizes, self.clamp, req,
+                              self.amort_dev)
         with _trace.span("accel.d2h.rb_descend"):
-            o_si, o_so, o_kk, pts = (np.asarray(x) for x in out)
-        return self.unpack(v, o_si, o_so, o_kk, pts)
+            ans = np.asarray(out)
+            transfers.inc()
+        return self.unpack(v, ans[:n], ans[n:2 * n], ans[2 * n:3 * n],
+                           ans[3 * n])

@@ -360,6 +360,55 @@ def test_rule_based_descend_single_trace(assert_max_traces):
     assert pts1 > 0
 
 
+@pytest.mark.parametrize("arch_name", ["tinyllama-1.1b", "stablelm-3b"])
+def test_rule_based_descend_packed_one_copy_each_way(arch_name):
+    """``descend`` sends one packed request and reads back one packed
+    answer, and answers exactly what the unpacked descent core does
+    (fed the ``pack_request`` arguments one array each) and what the
+    numpy engine's ``optimise_partition`` does, on the first, a middle
+    and the last partition of a repaired initial design."""
+    import jax.numpy as jnp
+
+    from repro.core.accel.search_loops import (
+        DeviceRuleBased,
+        _rb_descend_core,
+    )
+    from repro.core.hdgraph import partitions_from_cuts
+    from repro.core.optimizers.common import repair
+    from repro.core.optimizers.rule_based import optimise_partition
+    from repro.obs import metrics
+
+    prob = _problem(arch_name, TRAIN)
+    rb = DeviceRuleBased(prob)
+    idt, fdt = rb.A.batch.dtype, rb.A.flops.dtype
+    core = jax.jit(_rb_descend_core, static_argnums=(0, 1))
+    v0 = repair(prob, prob.backend.initial(prob.graph))
+    parts = partitions_from_cuts(prob.graph, v0.cuts)
+    picks = sorted({0, len(parts) // 2, len(parts) - 1})
+    assert len(parts) >= 2 and len(picks) >= 2
+    counters = metrics.snapshot()["counters"]
+    transfers0 = counters.get("accel.transfers.rb_descend", 0)
+    dispatches0 = counters.get("accel.dispatches.rb_descend", 0)
+    for i in picks:
+        part = parts[i]
+        v1, pts1 = rb.descend(v0, part)
+        si, so, kk, cb_row, part_mask, pidx, cap = rb.pack_request(v0, part)
+        out = core(rb.static, rb.gran, rb.A, rb.menus, rb.menu_sizes,
+                   rb.clamp, jnp.asarray(si, idt), jnp.asarray(so, idt),
+                   jnp.asarray(kk, idt), jnp.asarray(cb_row),
+                   jnp.asarray(part_mask), jnp.asarray(pidx, idt),
+                   jnp.asarray(rb.amort, fdt), jnp.asarray(cap, idt))
+        v2, pts2 = rb.unpack(v0, *(np.asarray(x) for x in out))
+        assert (v1, pts1) == (v2, pts2), (arch_name, i)
+        v3, _ = optimise_partition(prob, v0, part)
+        assert v1 == v3, (arch_name, i)
+    counters = metrics.snapshot()["counters"]
+    dispatches = counters["accel.dispatches.rb_descend"] - dispatches0
+    assert dispatches == len(picks)
+    assert counters["accel.transfers.rb_descend"] - transfers0 \
+        == 2 * dispatches
+
+
 def test_fleet_rule_based_identical_to_loop(assert_max_traces):
     """A mixed-size portfolio advances its greedy descents in lockstep as
     ONE vmapped executable, with per-problem merge sequences, designs,
