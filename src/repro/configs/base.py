@@ -30,6 +30,15 @@ class ArchConfig:
     experts_per_token: int = 0
     moe_period: int = 1            # every `moe_period`-th FFN is MoE (jamba: 2)
     first_layer_dense: bool = False  # kimi-k2 style: layer 0 dense FFN
+    moe_d_ff: int = 0              # routed-expert width (0 => d_ff)
+    n_shared_experts: int = 0      # always-on experts beside the routed ones
+
+    # --- multi-head latent attention (kimi-k2 / deepseek-v3); 0 => GQA ---
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0          # latent KV width: one vector per token
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     # --- hybrid (jamba): one attention layer per `attn_period` layers ---
     attn_period: int = 1           # 1 => all layers attention; 8 => 1:7 attn:mamba
@@ -66,6 +75,14 @@ class ArchConfig:
         return self.num_experts > 0
 
     @property
+    def expert_d_ff(self) -> int:
+        return self.moe_d_ff or self.d_ff
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
     def is_attention_free(self) -> bool:
         return self.family == "ssm"
 
@@ -75,9 +92,11 @@ class ArchConfig:
         return self.family in ("ssm", "hybrid")
 
     def layer_kind(self, i: int) -> str:
-        """Sequence-mixer kind of layer i: 'attn' | 'ssm' | 'rwkv'."""
+        """Sequence-mixer kind of layer i: 'attn' | 'mla' | 'ssm' | 'rwkv'."""
         if self.family == "ssm":
             return "rwkv"
+        if self.is_mla:
+            return "mla"
         if self.attn_period > 1:
             # jamba: one attention layer per attn_period block (position
             # attn_period-1 inside each block), rest mamba.
@@ -95,19 +114,19 @@ class ArchConfig:
     def param_count(self) -> int:
         """Total parameters (embedding included once if tied)."""
         D, F, V = self.d_model, self.d_ff, self.vocab_size
-        dh, Hkv = self.head_dim, self.num_kv_heads
+        Fe = self.expert_d_ff
         total = V * D                       # embedding
         if not self.tie_embeddings:
             total += V * D                  # lm head
         n_ffn_mats = 3 if self.act == "swiglu" else 2
-        layers = self.num_layers + self.encoder_layers
         for i in range(self.num_layers):
             total += self._mixer_params(self.layer_kind(i))
             if self.ffn_kind(i) == "moe":
-                total += self.num_experts * n_ffn_mats * D * F + D * self.num_experts
+                total += self.num_experts * n_ffn_mats * D * Fe \
+                    + D * self.num_experts
+                total += self.n_shared_experts * n_ffn_mats * D * Fe
             else:
-                f = F if not (self.is_moe and not self.first_layer_dense) else F
-                total += n_ffn_mats * D * f
+                total += n_ffn_mats * D * F
             total += 2 * D                  # norms
         for i in range(self.encoder_layers):
             total += self._mixer_params("attn") + n_ffn_mats * D * F + 2 * D
@@ -119,18 +138,21 @@ class ArchConfig:
         """Activated parameters per token (MoE: top-k experts only)."""
         if not self.is_moe:
             return self.param_count()
-        D, F = self.d_model, self.d_ff
+        D, Fe = self.d_model, self.expert_d_ff
         n_ffn_mats = 3 if self.act == "swiglu" else 2
         total = self.param_count()
         for i in range(self.num_layers):
             if self.ffn_kind(i) == "moe":
-                total -= (self.num_experts - self.experts_per_token) * n_ffn_mats * D * F
+                total -= (self.num_experts - self.experts_per_token) \
+                    * n_ffn_mats * D * Fe
         return total
 
     def _mixer_params(self, kind: str) -> int:
         D, dh, Hkv, H = self.d_model, self.head_dim, self.num_kv_heads, self.num_heads
         if kind == "attn":
             return D * (H * dh) + 2 * D * (Hkv * dh) + (H * dh) * D
+        if kind == "mla":
+            return self.mla_weights()
         if kind == "ssm":
             di, ds = self.ssm_expand * self.d_model, self.ssm_d_state
             dt_rank = max(1, self.d_model // 16)
@@ -141,6 +163,16 @@ class ArchConfig:
             # counted separately by the ffn entry (rwkv cmix uses d_ff).
             return 5 * D * D + 2 * D
         raise ValueError(kind)
+
+    def mla_weights(self) -> int:
+        """Latent attention parameters: the q and kv down-projections with
+        their latent norms, the up-projections to per-head q, k_nope and v,
+        and the output projection."""
+        D, H = self.d_model, self.num_heads
+        qr, kvr = self.q_lora_rank, self.kv_lora_rank
+        dn, dr, dv = self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim
+        return (D * qr + qr + qr * H * (dn + dr) + D * (kvr + dr) + kvr
+                + kvr * H * (dn + dv) + H * dv * D)
 
 
 @dataclass(frozen=True)
@@ -191,5 +223,10 @@ def reduced(arch: ArchConfig, **overrides) -> ArchConfig:
     )
     if arch.attn_period > 1:
         small["num_layers"] = 2 * arch.attn_period  # keep the interleave pattern
+    if arch.moe_d_ff:
+        small["moe_d_ff"] = 64
+    if arch.is_mla:
+        small.update(q_lora_rank=48, kv_lora_rank=32, qk_nope_head_dim=16,
+                     qk_rope_head_dim=8, v_head_dim=16)
     small.update(overrides)
     return dataclasses.replace(arch, **small)

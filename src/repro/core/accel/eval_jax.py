@@ -126,13 +126,17 @@ def _collective_bytes(static: StaticSpec, A: DeviceArrays,
     kv_div = jnp.where(A.kv_limit[None, :] > 0,
                        jnp.minimum(sof, kvlf[None, :]),
                        jnp.maximum(sof, 1.0))
-    dh = fmf / jnp.maximum(colsf, 1.0)
+    # latent attention combines its partials kv_lora_rank wide
+    dh = jnp.where(A.m_latent, A.latent_dim.astype(fdt),
+                   fmf / jnp.maximum(colsf, 1.0))
     total = _madd(total, A.internal,
                   (batchf[None, :] / kkf) * colsf[None, :]
                   / jnp.maximum(kv_div, 1.0) * ((dh + 2.0) * 4.0)[None, :]
                   * _frac(sif))
+    # the latent cache is whole on every head fold
+    ring_div = jnp.where(A.m_latent[None, :], jnp.ones_like(kv_div), kv_div)
     total = _madd(total, A.m_kv,
-                  A.kv_bytes[None, :] / (kv_div * kkf) * _frac(sif)
+                  A.kv_bytes[None, :] / (ring_div * kkf) * _frac(sif)
                   * train_mult)
     total = _madd(total, A.m_carry,
                   A.carry_bytes[None, :] / kkf * _frac(sif) * train_mult)
@@ -191,9 +195,11 @@ def _eval_core(static: StaticSpec, A: DeviceArrays,
     kvlf = A.kv_limit.astype(fdt)
     kv_div_a = jnp.where(A.kv_limit[None, :] > 0,
                          jnp.minimum(sof, kvlf[None, :]), sof)
+    # latent KV shards over (k, s_in) only: one vector per token
     state_div = jnp.where(A.m_attn[None, :],
                           kkf * jnp.maximum(kv_div_a, 1.0) * sif,
-                          kkf * sof)
+                          jnp.where(A.m_latent[None, :], kkf * sif,
+                                    kkf * sof))
     state_repl = jnp.where(
         A.m_attn[None, :] & (A.kv_limit[None, :] > 0)
         & (so > A.kv_limit[None, :]),
@@ -249,6 +255,12 @@ def _eval_core(static: StaticSpec, A: DeviceArrays,
     # padded columns are neutral everywhere EXCEPT the streaming chip
     # count (their fold product is 1, not 0) — zero them explicitly there
     c_eff = jnp.where(A.node_valid[None, :], c, jnp.zeros_like(c))
+    # resharding collectives at intra-partition layout changes (backends
+    # without inter matching pay them), per edge
+    if not static.inter_matching and n > 1:
+        edge_t = jnp.where(~cb & mism, A.reshard_full[:-1] / A.ici_bw, 0.0)
+    else:
+        edge_t = jnp.zeros((N, max(n - 1, 0)), fdt)
 
     if single_partition:
         # fast path (trace-time): every candidate is one partition — no
@@ -299,9 +311,6 @@ def _eval_core(static: StaticSpec, A: DeviceArrays,
 
         t_part = t_base
         if not static.inter_matching and n > 1:
-            # resharding collectives at intra-partition layout changes
-            edge_t = jnp.where(~cb & mism,
-                               A.reshard_full[:-1] / A.ici_bw, 0.0)
             reshard = jnp.einsum("rj,rjp->rp", edge_t, onehot_f[:, :-1, :])
             t_part = t_part + reshard
         t_part = jnp.where(part_valid, t_part, 0.0)
@@ -358,7 +367,10 @@ def _eval_core(static: StaticSpec, A: DeviceArrays,
         res_part = seg_sum(resident)
         multi = nparts > 1
         start = jnp.concatenate([jnp.ones((N, 1), bool), cb], axis=1)
-        end = jnp.concatenate([cb, jnp.ones((N, 1), bool)], axis=1)
+        # the last partition ends at the last REAL node (padded columns
+        # stage nothing)
+        end = jnp.concatenate([cb, jnp.zeros((N, 1), bool)], axis=1) \
+            | (iota_n == A.n_valid - 1)[None, :]
         d_io = seg_sum(A.node_d[None, :]
                        * (start.astype(fdt) + end.astype(fdt)))
         res_tot = res_part + jnp.where(multi[:, None],
@@ -379,6 +391,7 @@ def _eval_core(static: StaticSpec, A: DeviceArrays,
         "throughput": throughput, "part_times": t_part, "nparts": nparts,
         "reconf_time": reconf, "node_resident": resident,
         "node_times": node_time, "node_collective": coll,
+        "edge_times": edge_t,
     }
 
 

@@ -127,6 +127,7 @@ class DeviceArrays(NamedTuple):
     fm_width: "jax.Array"
     col_div: "jax.Array"
     kv_limit: "jax.Array"
+    latent_dim: "jax.Array"
     ep_topk: "jax.Array"
     scan_group: "jax.Array"
     internal: "jax.Array"
@@ -160,6 +161,7 @@ class DeviceArrays(NamedTuple):
     m_vhead: "jax.Array"
     m_kv: "jax.Array"
     m_carry: "jax.Array"
+    m_latent: "jax.Array"
     # scan-tying consecutive member pairs, padded with (0, 0) self-pairs
     pair_a: "jax.Array"             # [n_pairs_pad]
     pair_b: "jax.Array"
@@ -268,15 +270,15 @@ def build_static_spec(bev, *, use_pallas: bool = False,
 FINGERPRINT_ARRAYS: Tuple[str, ...] = (
     "flops", "weight_bytes", "act_bytes", "inner_bytes", "state_bytes",
     "kv_bytes", "carry_bytes", "node_d", "reshard_full", "batch", "rows",
-    "cols", "fm_width", "col_div", "kv_limit", "ep_topk", "scan_group",
-    "internal", "elementwise", "weight_stream", "cut_allowed",
+    "cols", "fm_width", "col_div", "kv_limit", "latent_dim", "ep_topk",
+    "scan_group", "internal", "elementwise", "weight_stream", "cut_allowed",
 )
 
 #: kind index sets covered by ``problem_fingerprint`` (the
 #: ``DeviceArrays.m_*`` mask sources).
 FINGERPRINT_INDEX_SETS: Tuple[str, ...] = (
     "i_attn", "i_head", "i_tp", "i_ep", "i_vocab", "i_vhead", "i_kv",
-    "i_carry",
+    "i_carry", "i_latent",
 )
 
 
@@ -431,6 +433,7 @@ def lower_program(bev, *, use_pallas: bool = False,
         fm_width=ei(bev.fm_width, 0),
         col_div=ei(bev.col_div, 1),
         kv_limit=ei(bev.kv_limit, 0),
+        latent_dim=ei(bev.latent_dim, 0),
         ep_topk=ei(bev.ep_topk, 0),
         scan_group=ei(bev.scan_group, -1),
         internal=eb(bev.internal),
@@ -458,6 +461,7 @@ def lower_program(bev, *, use_pallas: bool = False,
         m_vhead=km(bev.i_vhead),
         m_kv=km(bev.i_kv),
         m_carry=km(bev.i_carry),
+        m_latent=km(bev.i_latent),
         pair_a=jnp.asarray(pair_a, idt),
         pair_b=jnp.asarray(pair_b, idt),
         node_valid=jnp.asarray(node_valid),

@@ -1022,18 +1022,42 @@ def _rb_step(static: StaticSpec, gran: Tuple[str, str, str],
                      jnp.broadcast_to(cb_row[None, :], (B + 1, E)))
 
     # ---- decision quantities (the scalar b_cost / resource vector) ---
-    t_row = jnp.take(res["part_times"], pidx, axis=1)          # [B+1]
-    w = jnp.where(part_mask[None, :],
-                  A.weight_bytes[None, :] / SO.astype(fdt), 0.0).sum(axis=1)
-    tcost = A.reconf_fixed_s + w / A.dma_bw                    # t_conf(part)
-    cost = t_row + jnp.where(pidx > 0, amort * tcost,
-                             jnp.zeros((), fdt))
-    t_part = cost[0]
+    # A probe is compared with the incumbent (row 0) by the sum of its
+    # per-node (and per-edge) differences, not by the difference of two
+    # totals: a probe differs from the incumbent in a few nodes, and a
+    # float32 total would lose a change below its resolution that the
+    # float64 reference sees (a light node's move inside a heavy
+    # partition). It improves when its cost falls by more than the
+    # threshold plus the rounding of the terms that changed, so that a
+    # change the float64 reference finds to be nil is nil here too.
+    def delta(x, mask):
+        """(sum of row r's differences from row 0, their magnitude)."""
+        d = jnp.where(mask[None, :], x - x[0:1], 0.0)
+        mag = jnp.where(d != 0, jnp.abs(x) + jnp.abs(x[0:1]), 0.0)
+        return d.sum(axis=1), mag.sum(axis=1)
+
+    pid1 = jnp.concatenate(
+        [jnp.zeros((1,), idt), jnp.cumsum(cb_row.astype(idt))])
+    if static.exec_model == "spmd":
+        # partition ``pidx`` of the design (repair may have cut ``part``)
+        in_p = pid1 == pidx
+        d_n, m_n = delta(res["node_times"], in_p)
+        d_e, m_e = delta(res["edge_times"], in_p[:-1] & in_p[1:])
+        d_t, m_t = d_n + d_e, m_n + m_e
+    else:
+        d_t, m_t = delta(jnp.take(res["part_times"], pidx, axis=1)[:, None],
+                         jnp.ones((1,), bool))
+    d_w, m_w = delta(A.weight_bytes[None, :] / SO.astype(fdt), part_mask)
+    later = pidx > 0                                   # + t_conf(part)
+    zero = jnp.zeros((), fdt)
+    d_cost = d_t + jnp.where(later, amort * (d_w / A.dma_bw), zero)
+    mag = m_t + jnp.where(later, amort * (m_w / A.dma_bw), zero)
+    rounding = 16.0 * jnp.finfo(fdt).eps * mag
     coll = res["node_collective"].sum(axis=1)
     resd = res["node_resident"].sum(axis=1)
     dr0 = coll - coll[0]
     dr1 = resd - resd[0]
-    improving = res["feasible"] & (cost < t_part - 1e-15)
+    improving = res["feasible"] & (d_cost < -1e-15 - rounding)
     valid = improving & jnp.concatenate(
         [jnp.zeros((1,), bool), probe_ok])
     any_valid = valid.any()
@@ -1051,8 +1075,6 @@ def _rb_step(static: StaticSpec, gran: Tuple[str, str, str],
     si2 = jnp.where(any_valid, jnp.take(SI, sel, axis=0), si)
     so2 = jnp.where(any_valid, jnp.take(SO, sel, axis=0), so)
     kk2 = jnp.where(any_valid, jnp.take(KK, sel, axis=0), kk)
-    pid1 = jnp.concatenate(
-        [jnp.zeros((1,), idt), jnp.cumsum(cb_row.astype(idt))])
     same_part = pid1 == pid1[j]
     sg_j = A.scan_group[j]
     oh_j = iota_n == j

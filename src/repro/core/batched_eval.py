@@ -124,6 +124,7 @@ class BatchedEvaluator:
         self.fm_width = i("fm_width")
         self.col_div = np.array([x.col_div for x in nodes], np.int64)
         self.kv_limit = i("kv_limit")
+        self.latent_dim = i("latent_dim")
         self.ep_topk = i("ep_topk")
         self.scan_group = i("scan_group")
 
@@ -158,6 +159,7 @@ class BatchedEvaluator:
                          & (self.carry_bytes > 0))
         self.i_ew = w(self.elementwise)
         self.i_kvlim = w(self.kv_limit > 0)
+        self.i_latent = w(self.latent_dim > 0)
 
         # mesh-realisability lookup table over the platform fold menu (small
         # for real meshes: products of axis subsets). Falls back to the
@@ -312,6 +314,10 @@ class BatchedEvaluator:
                 * sif[:, ia]
             state_repl[:, ia] = np.where((kvl > 0) & (so[:, ia] > kvl),
                                          sof[:, ia] / kv_div_a, 1.0)
+        il = self.i_latent
+        if len(il):
+            # latent KV: whole on every head fold (perfmodel._state_sharding)
+            state_div[:, il] = kkf[:, il] * sif[:, il]
         state_per_chip = self.state_bytes * state_repl / state_div
 
         train_mult = 3.0 if train else 1.0
@@ -537,16 +543,17 @@ class BatchedEvaluator:
             kv_div = np.where(kvl > 0,
                               np.minimum(sof[:, ix], kvl.astype(np.float64)),
                               np.maximum(sof[:, ix], 1.0))
-            dh = self.fm_width[ix] / np.maximum(self.cols[ix], 1)
+            dh = np.where(self.latent_dim[ix] > 0, self.latent_dim[ix],
+                          self.fm_width[ix] / np.maximum(self.cols[ix], 1))
             total[:, ix] += (self.batch[ix] / kkf[:, ix]) * self.cols[ix] \
                 / np.maximum(kv_div, 1.0) * (dh + 2.0) * 4.0 \
                 * frac(sif[:, ix])
         if len(self.i_kv):
             ix = self.i_kv
             kvl = self.kv_limit[ix]
-            kv_div2 = np.where(kvl > 0,
-                               np.minimum(sof[:, ix], kvl.astype(np.float64)),
-                               np.maximum(sof[:, ix], 1.0)) * kkf[:, ix]
+            kv_div2 = np.where(self.latent_dim[ix] > 0, 1.0, np.where(
+                kvl > 0, np.minimum(sof[:, ix], kvl.astype(np.float64)),
+                np.maximum(sof[:, ix], 1.0))) * kkf[:, ix]
             total[:, ix] += self.kv_bytes[ix] / kv_div2 * frac(sif[:, ix]) \
                 * train_mult
         if len(self.i_carry):

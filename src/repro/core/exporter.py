@@ -109,7 +109,8 @@ class ShardingPlan:
 
     def _boundary_kind(self, partition: int) -> KindPlan:
         part = self.partitions[partition]
-        for kind in ("attn", "ssm", "rwkv_tmix", "ffn", "moe", "enc_attn"):
+        for kind in ("attn", "mla", "ssm", "rwkv_tmix", "ffn",
+                     "shared_expert", "moe", "enc_attn"):
             if kind in part.kinds:
                 return part.kinds[kind]
         return KindPlan("none", 1, 1, 1, (), (), ())
@@ -144,8 +145,13 @@ class ShardingPlan:
     def kv_cache_spec(self, partition: int = 0):
         """(batch, kv_len, kv_heads, head_dim) cache spec: batch over k axes,
         length over rows axes (split-KV), heads over cols axes (up to the
-        GQA limit — legalisation already clamped)."""
+        GQA limit — legalisation already clamped). A latent-attention
+        partition's cache is (batch, kv_len, latent): one vector per token
+        for all heads, so it never shards over the cols axes."""
         P = _pspec()
+        if "mla" in self.partitions[partition].kinds:
+            kp = self.kind_plan("mla", partition)
+            return P(_axes(kp.batch_axes), _axes(kp.rows_axes), None)
         kp = self.kind_plan("attn", partition)
         return P(_axes(kp.batch_axes), _axes(kp.rows_axes),
                  _axes(kp.cols_axes), None)

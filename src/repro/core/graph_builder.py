@@ -35,6 +35,8 @@ _SCAN_GROUP = {
     "cross_attn": 6,
     "enc_attn": 7,
     "enc_ffn": 8,
+    "mla": 9,
+    "shared_expert": 10,
 }
 
 
@@ -83,6 +85,8 @@ def build_hdgraph(arch: ArchConfig, shape: ShapeSpec) -> HDGraph:
                 Se = arch.num_frames or 1500
                 nodes.append(_attn_node(arch, f"l{i}.xattn", i, B, S, Se, tm,
                                         mode=mode, kind="cross_attn", causal=False))
+        elif mixer == "mla":
+            nodes.append(_mla_node(arch, f"l{i}.mla", i, B, S, L, tm, mode))
         elif mixer == "ssm":
             nodes.append(_ssm_node(arch, f"l{i}.ssm", i, B, S, tm, mode))
         elif mixer == "rwkv":
@@ -92,6 +96,11 @@ def build_hdgraph(arch: ArchConfig, shape: ShapeSpec) -> HDGraph:
         if mixer == "rwkv":
             nodes.append(_rwkv_cmix_node(arch, f"l{i}.cmix", i, B, S, tm, stream))
         elif fk == "moe":
+            if arch.n_shared_experts:
+                nodes.append(_ffn_node(
+                    arch, f"l{i}.shared", i, B, S, tm, stream,
+                    kind="shared_expert",
+                    width=arch.n_shared_experts * arch.expert_d_ff))
             nodes.append(_moe_node(arch, f"l{i}.moe", i, B, S, tm))
         else:
             nodes.append(_ffn_node(arch, f"l{i}.ffn", i, B, S, tm, stream))
@@ -165,9 +174,52 @@ def _attn_node(arch: ArchConfig, name: str, layer: int, B: int, S: int, L: int,
     )
 
 
+def _mla_node(arch: ArchConfig, name: str, layer: int, B: int, S: int,
+              L: int, tm: float, mode: str) -> Node:
+    """Multi-head latent attention. Train/prefill run the non-absorbed
+    form (the latent expanded to per-head k_nope and v); decode runs the
+    absorbed form against the latent cache (S = 1). The cache is one
+    ``kv_lora_rank + qk_rope_head_dim`` vector per token for all heads."""
+    D, H = arch.d_model, arch.num_heads
+    qr, kvr = arch.q_lora_rank, arch.kv_lora_rank
+    dn, dr, dv = arch.qk_nope_head_dim, arch.qk_rope_head_dim, arch.v_head_dim
+    W = arch.mla_weights()
+    decode = mode == "decode"
+    if decode:
+        proj = 2.0 * B * (D * qr + qr * H * (dn + dr) + D * (kvr + dr)
+                          + H * dn * kvr + H * kvr * dv + H * dv * D)
+        sdpa = 2.0 * B * H * L * (2 * kvr + dr)
+    else:
+        causal_f = 0.5 if S == L else 1.0
+        proj = 2.0 * B * S * (W - qr - kvr)
+        sdpa = 2.0 * B * H * S * L * (dn + dr + dv) * causal_f
+    kv_state = B * L * (kvr + dr) * BF16
+    return Node(
+        name=name, kind="mla", layer=layer,
+        rows=L if decode else S,              # decode: split-KV folding dim
+        cols=H, batch=B,
+        flops=(proj + sdpa) * tm,
+        weight_bytes=W * BF16,
+        act_bytes=4.0 * B * S * D * BF16,
+        # the q and kv latents, and the per-head q, k, v and output
+        inner_bytes=B * S * (qr + kvr + H * (2 * (dn + dr) + 2 * dv)) * BF16,
+        state_bytes=kv_state if mode != "train" else 0.0,
+        kv_bytes=kv_state,
+        col_divisor=H,
+        latent_dim=kvr,
+        scan_group=_SCAN_GROUP["mla"],
+        collective_kind="tp_allreduce",
+        train_multiplier=tm,
+        weight_stream=(mode != "train"),
+        internal_rows=decode,
+        fm_width=D,
+    )
+
+
 def _ffn_node(arch: ArchConfig, name: str, layer: int, B: int, S: int,
-              tm: float, stream: bool, kind: str = "ffn") -> Node:
-    D, F = arch.d_model, arch.d_ff
+              tm: float, stream: bool, kind: str = "ffn",
+              width: int = 0) -> Node:
+    D, F = arch.d_model, width or arch.d_ff
     n = _n_ffn_mats(arch)
     return Node(
         name=name, kind=kind, layer=layer,
@@ -187,7 +239,8 @@ def _ffn_node(arch: ArchConfig, name: str, layer: int, B: int, S: int,
 
 def _moe_node(arch: ArchConfig, name: str, layer: int, B: int, S: int,
               tm: float) -> Node:
-    D, F, E, K = arch.d_model, arch.d_ff, arch.num_experts, arch.experts_per_token
+    D, F = arch.d_model, arch.expert_d_ff
+    E, K = arch.num_experts, arch.experts_per_token
     n = _n_ffn_mats(arch)
     tokens = B * S
     router_flops = 2.0 * tokens * D * E

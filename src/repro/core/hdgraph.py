@@ -22,7 +22,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 @dataclass(frozen=True)
 class Node:
     name: str
-    kind: str                 # embed|attn|cross_attn|ffn|moe|ssm|rwkv_tmix|rwkv_cmix|norm|head
+    kind: str                 # embed|attn|mla|cross_attn|ffn|shared_expert|moe|ssm|rwkv_tmix|rwkv_cmix|norm|head
     layer: int                # layer index (-1 for embed/head/final norm)
     # Foldable dimensions.
     rows: int                 # sequence rows entering the node (or KV len in decode)
@@ -42,6 +42,9 @@ class Node:
                                   # passed between row-fold neighbours
     col_divisor: int = 0          # cols fold must divide this (0 => cols itself)
     kv_limit: int = 0             # GQA: folds beyond this replicate KV (spmd only)
+    latent_dim: int = 0           # MLA: latent KV width (kv_lora_rank); > 0
+                                  # means one cache vector per token shared by
+                                  # all heads: it shards over (k, s_I), never s_O
     ep_topk: int = 0              # MoE: experts per token (all-to-all fan-out)
     weight_stream: bool = False   # weights re-read from HBM every step (inference)
     internal_rows: bool = False   # rows dim is node-internal (decode split-KV):
@@ -54,6 +57,10 @@ class Node:
     @property
     def col_div(self) -> int:
         return self.col_divisor or self.cols
+
+    @property
+    def latent_kv(self) -> bool:
+        return self.latent_dim > 0
 
 
 @dataclass

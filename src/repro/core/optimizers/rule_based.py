@@ -69,7 +69,17 @@ def optimise_partition(problem: Problem, v: Variables, part: List[int],
     greedy walks the identical move sequence — the decision quantities
     (feasibility, partition time, collective-bytes/residency resource
     vector) agree with the scalar path to 1e-9 and ties are broken in the
-    same probe order."""
+    same probe order.
+
+    A probe improves here when its float64 partition cost falls by more
+    than 1e-15 s. The jax engine's device descent
+    (``search_loops._rb_step``) computes in float32 and sums the
+    per-node differences from the incumbent instead: it takes a probe only
+    when that sum falls by more than 1e-15 s plus ``16 * eps`` of the
+    magnitude of the terms that changed (about 1.9e-6 of them in
+    float32). A move that gains less than that margin is taken here and
+    refused there; the engines agree wherever every improvement clears
+    it."""
     graph, backend, platform = problem.graph, problem.backend, problem.platform
     points = 0
     blocked: set = set()

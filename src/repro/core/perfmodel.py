@@ -73,6 +73,10 @@ class ModelOptions:
 
 def _state_sharding(node: Node, s_in: int, s_out: int, kern: int):
     """(divisor, replication) for KV / recurrent state under the folding."""
+    if node.latent_kv:
+        # one latent vector per token, shared by all heads: it shards over
+        # batch (k) and the sequence or cache (s_in), never over heads
+        return kern * s_in, 1.0
     if node.kind in ("attn", "cross_attn", "enc_attn"):
         kv_div = min(s_out, node.kv_limit) if node.kv_limit else s_out
         # KV shards over batch (k), kv-heads (up to kv_limit) and — when the
@@ -197,12 +201,17 @@ def _collective_bytes(node: Node, s_in: int, s_out: int, kern: int,
             # group. Heads shard only up to the KV-head cap (GQA): beyond
             # kv_limit the partials replicate, so the combine traffic divides
             # by kv_div, not s_out.
+            # Latent attention combines per-head partials in the latent
+            # space: kv_lora_rank wide, not the head width.
             kv_div = min(s_out, node.kv_limit) if node.kv_limit else max(s_out, 1)
-            dh = node.fm_width / max(node.cols, 1)
+            dh = node.latent_dim if node.latent_kv \
+                else node.fm_width / max(node.cols, 1)
             total += (node.batch / kern) * node.cols / max(kv_div, 1) \
                 * (dh + 2.0) * 4.0 * (s_in - 1) / s_in
         elif node.kv_bytes:
-            kv_div = (min(s_out, node.kv_limit) if node.kv_limit
+            # the latent cache is whole on every head fold
+            kv_div = (1 if node.latent_kv
+                      else min(s_out, node.kv_limit) if node.kv_limit
                       else max(s_out, 1)) * kern
             total += node.kv_bytes / kv_div * (s_in - 1) / s_in * train_mult
         elif node.carry_bytes:

@@ -49,6 +49,7 @@ always re-derived through the float64 scalar reference.
 """
 from __future__ import annotations
 
+from collections import Counter
 from typing import List, Optional, Sequence
 
 from repro.configs.base import ArchConfig, ShapeSpec
@@ -75,7 +76,10 @@ def make_problem(arch: ArchConfig, shape: ShapeSpec,
     if opts is not None and model_opts:
         raise TypeError(f"pass either opts= or ModelOptions fields "
                         f"{sorted(model_opts)}, not both")
-    graph = build_hdgraph(arch, shape)
+    with _trace.span("graph.build", arch=arch.name, shape=shape.name):
+        graph = build_hdgraph(arch, shape)
+    for kind, count in Counter(n.kind for n in graph.nodes).items():
+        _metrics.counter(f"graph.nodes.{kind}").inc(count)
     return Problem(
         graph=graph,
         platform=platform,

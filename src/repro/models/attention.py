@@ -1,4 +1,5 @@
-"""GQA attention (self / cross / encoder) with optional KV cache.
+"""GQA attention (self / cross / encoder) with optional KV cache, and
+multi-head latent attention (MLA) with a latent cache.
 
 The scaled-dot-product core dispatches to the Pallas flash-attention kernel
 (kernels/ops.py) when enabled, else to the pure-jnp oracle (kernels/ref.py) —
@@ -11,7 +12,8 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.models.layers import apply_mrope, apply_rope, block_norm, dense_init, init_norm
+from repro.models.layers import (apply_mrope, apply_norm, apply_rope,
+                                 block_norm, dense_init, init_norm)
 
 
 def init_attention(key, d_model: int, num_heads: int, num_kv_heads: int,
@@ -110,4 +112,72 @@ def attend(x: jax.Array, p: Dict[str, jax.Array], *,
              impl=attn_impl)
     o = o.reshape(B, Sq, num_heads * head_dim)
     y = o @ p["wo"]
+    return x + shard_fn(y, role="boundary"), new_cache
+
+
+# ----------------------------------------------------------------------
+# multi-head latent attention (Kimi-K2 / DeepSeek-V3)
+# ----------------------------------------------------------------------
+
+def init_mla(key, d_model: int, num_heads: int, q_lora_rank: int,
+             kv_lora_rank: int, qk_nope_head_dim: int, qk_rope_head_dim: int,
+             v_head_dim: int, norm: str, dtype=jnp.bfloat16):
+    H, dn, dr = num_heads, qk_nope_head_dim, qk_rope_head_dim
+    ks = jax.random.split(key, 5)
+    p = {
+        "wq_a": dense_init(ks[0], d_model, q_lora_rank, dtype),
+        "q_norm": jnp.ones((q_lora_rank,), dtype),
+        "wq_b": dense_init(ks[1], q_lora_rank, H * (dn + dr), dtype),
+        "wkv_a": dense_init(ks[2], d_model, kv_lora_rank + dr, dtype),
+        "kv_norm": jnp.ones((kv_lora_rank,), dtype),
+        "wkv_b": dense_init(ks[3], kv_lora_rank, H * (dn + v_head_dim), dtype),
+        "wo": dense_init(ks[4], H * v_head_dim, d_model, dtype),
+    }
+    p.update({f"ln_{k}": v for k, v in init_norm(d_model, norm, dtype).items()})
+    return p
+
+
+def attend_mla(x: jax.Array, p: Dict[str, jax.Array], *, num_heads: int,
+               qk_nope_head_dim: int, qk_rope_head_dim: int, v_head_dim: int,
+               norm: str, positions: jax.Array, rope_theta: float = 10000.0,
+               cache: Optional[Dict[str, jax.Array]] = None,
+               cache_pos: Optional[jax.Array] = None,
+               attn_impl: str = "ref",
+               shard_fn=lambda a, role=None: a):
+    """One latent-attention block with pre-norm and residual, in the
+    non-absorbed form: queries come from the q latent; the kv latent
+    ``c_kv`` is expanded to per-head ``k_nope`` and ``v``, and one rotary
+    key ``k_rope`` is shared by all heads. The cache holds ``c_kv``
+    (B, L, kv_lora_rank) and ``k_rope`` (B, L, qk_rope_head_dim): with
+    ``cache_pos`` this step's latents are written there and attention
+    runs over the whole cache. Returns (y, new_cache)."""
+    B, S, _ = x.shape
+    H, dn, dr, dv = num_heads, qk_nope_head_dim, qk_rope_head_dim, v_head_dim
+    h = block_norm(x, p, norm)
+    c_q = apply_norm(h @ p["wq_a"], p["q_norm"], None, "rms")
+    q = (c_q @ p["wq_b"]).reshape(B, S, H, dn + dr)
+    q_rope = apply_rope(q[..., dn:], positions, rope_theta)
+    q = jnp.concatenate([q[..., :dn], q_rope], axis=-1)
+    kv_a = h @ p["wkv_a"]
+    c_kv = apply_norm(kv_a[..., :-dr], p["kv_norm"], None, "rms")
+    k_rope = apply_rope(kv_a[..., None, -dr:], positions, rope_theta)[:, :, 0]
+    new_cache = cache
+    if cache is not None and cache_pos is not None:
+        c_kv = jax.lax.dynamic_update_slice_in_dim(
+            cache["c_kv"], c_kv.astype(cache["c_kv"].dtype), cache_pos, axis=1)
+        k_rope = jax.lax.dynamic_update_slice_in_dim(
+            cache["k_rope"], k_rope.astype(cache["k_rope"].dtype), cache_pos,
+            axis=1)
+        new_cache = {"c_kv": c_kv, "k_rope": k_rope}
+    L = c_kv.shape[1]
+    kv = (c_kv.astype(x.dtype) @ p["wkv_b"]).reshape(B, L, H, dn + dv)
+    k = jnp.concatenate(
+        [kv[..., :dn], jnp.broadcast_to(k_rope.astype(x.dtype)[:, :, None],
+                                        (B, L, H, dr))], axis=-1)
+    v = kv[..., dn:]
+    q = shard_fn(q, role="heads")
+    o = sdpa(q, k, v, causal=True,
+             q_offset=cache_pos if cache_pos is not None else 0,
+             impl=attn_impl)
+    y = o.reshape(B, S, H * dv) @ p["wo"]
     return x + shard_fn(y, role="boundary"), new_cache

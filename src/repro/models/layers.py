@@ -44,6 +44,17 @@ PARAM_ROLES: Dict[str, Dict[str, str]] = {
         "router": "replicate",
         "w_gate": "expert", "w_up": "expert", "w_down": "expert",
     },
+    # the shared expert's weights live in the moe block's params (it reads
+    # the moe's normed input) and shard by the shared_expert node's plan
+    "shared_expert": {
+        "shared_w_gate": "col", "shared_w_up": "col", "shared_w_down": "row",
+    },
+    "mla": {
+        "ln_scale": "replicate", "ln_bias": "replicate",
+        "wq_a": "replicate", "q_norm": "replicate", "wq_b": "col",
+        "wkv_a": "replicate", "kv_norm": "replicate", "wkv_b": "col",
+        "wo": "row",
+    },
     "ssm": {
         "ln_scale": "replicate", "ln_bias": "replicate",
         "in_proj": "col", "conv_w": "expert", "conv_b": "expert",
@@ -155,16 +166,22 @@ def init_ffn(key, d_model: int, d_ff: int, act: str, norm: str,
     return p
 
 
+def ffn_inner(p: Dict[str, jax.Array], h: jax.Array, act: str, dtype,
+              shard_fn=lambda a, role=None: a) -> jax.Array:
+    """The FFN on an already-normed input, without the residual."""
+    up = h @ p["w_up"]
+    if act == "swiglu":
+        inner = jax.nn.silu((h @ p["w_gate"]).astype(jnp.float32)).astype(dtype) * up
+    elif act == "gelu":
+        inner = jax.nn.gelu(up.astype(jnp.float32)).astype(dtype)
+    else:  # relu_sq
+        inner = jnp.square(jax.nn.relu(up.astype(jnp.float32))).astype(dtype)
+    inner = shard_fn(inner, role="inner")
+    return inner @ p["w_down"]
+
+
 def apply_ffn(x: jax.Array, p: Dict[str, jax.Array], act: str, norm: str,
               shard_fn=lambda a, role=None: a) -> jax.Array:
     h = block_norm(x, p, norm)
-    up = h @ p["w_up"]
-    if act == "swiglu":
-        inner = jax.nn.silu((h @ p["w_gate"]).astype(jnp.float32)).astype(x.dtype) * up
-    elif act == "gelu":
-        inner = jax.nn.gelu(up.astype(jnp.float32)).astype(x.dtype)
-    else:  # relu_sq
-        inner = jnp.square(jax.nn.relu(up.astype(jnp.float32))).astype(x.dtype)
-    inner = shard_fn(inner, role="inner")
-    out = inner @ p["w_down"]
+    out = ffn_inner(p, h, act, x.dtype, shard_fn)
     return x + shard_fn(out, role="boundary")

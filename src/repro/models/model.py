@@ -7,7 +7,8 @@ A layer-group is a static *pattern* of block kinds, e.g.:
 
   dense llama     ("attn", "ffn") x num_layers
   jamba           ("ssm","ffn","ssm","moe",... ,"attn","moe") x 9   (1:7, MoE alt)
-  kimi-k2         ("attn","ffn") x 1  +  ("attn","moe") x 60
+  kimi-k2         ("mla","ffn") x 1  +  ("mla","moe") x 60  (moe with a
+                  shared expert)
   rwkv6           ("rwkv_tmix","rwkv_cmix") x 24
   whisper         enc: ("enc_attn","enc_ffn") x 12;
                   dec: ("attn","cross_attn","ffn") x 12
@@ -62,6 +63,8 @@ def build_segments(arch: ArchConfig,
             kinds.append("attn")
             if arch.cross_attention:
                 kinds.append("cross_attn")
+        elif mixer == "mla":
+            kinds.append("mla")
         elif mixer == "ssm":
             kinds.append("ssm")
         else:
@@ -120,8 +123,13 @@ _INIT = {
     "enc_ffn": lambda key, arch: L.init_ffn(key, arch.d_model, arch.d_ff,
                                             "gelu" if arch.act == "gelu" else arch.act,
                                             arch.norm),
-    "moe": lambda key, arch: M.init_moe(key, arch.d_model, arch.d_ff,
-                                        arch.num_experts, arch.act, arch.norm),
+    "mla": lambda key, arch: A.init_mla(
+        key, arch.d_model, arch.num_heads, arch.q_lora_rank,
+        arch.kv_lora_rank, arch.qk_nope_head_dim, arch.qk_rope_head_dim,
+        arch.v_head_dim, arch.norm),
+    "moe": lambda key, arch: M.init_moe(
+        key, arch.d_model, arch.expert_d_ff, arch.num_experts, arch.act,
+        arch.norm, shared_d_ff=arch.n_shared_experts * arch.expert_d_ff),
     "ssm": lambda key, arch: S.init_ssm(key, arch.d_model, arch.ssm_expand,
                                         arch.ssm_d_state, arch.ssm_conv,
                                         arch.norm),
@@ -200,6 +208,8 @@ class Model:
             if top == "final_norm":
                 return plan.spec_for_role("replicate", leaf.ndim, "norm", partition)
             kind = path[1].split("_", 1)[1]          # "p{j}_{kind}"
+            if name in L.PARAM_ROLES["shared_expert"]:
+                kind = "shared_expert"
             role = L.PARAM_ROLES[kind].get(name, "replicate")
             return plan.spec_for_role(role, leaf.ndim, kind, partition,
                                       stacked=1)
@@ -311,6 +321,15 @@ class Model:
                         mrope_positions=mrope if causal else None,
                         cache=c, cache_pos=cache_pos,
                         attn_impl=self.attn_impl, shard_fn=sfk)
+                elif kind == "mla":
+                    h, nc = A.attend_mla(
+                        h, p, num_heads=arch.num_heads,
+                        qk_nope_head_dim=arch.qk_nope_head_dim,
+                        qk_rope_head_dim=arch.qk_rope_head_dim,
+                        v_head_dim=arch.v_head_dim, norm=arch.norm,
+                        positions=positions, rope_theta=arch.rope_theta,
+                        cache=c, cache_pos=cache_pos,
+                        attn_impl=self.attn_impl, shard_fn=sfk)
                 elif kind == "cross_attn":
                     h, nc = A.attend(
                         h, p, num_heads=arch.num_heads,
@@ -396,6 +415,13 @@ class Model:
                                             arch.num_kv_heads, arch.head_dim),
                                            dtype)
                     seg_cache[pk] = {"k": kv(), "v": kv()}
+                elif kind == "mla":
+                    seg_cache[pk] = {
+                        "c_kv": jnp.zeros((seg.count, batch_size, max_len,
+                                           arch.kv_lora_rank), dtype),
+                        "k_rope": jnp.zeros((seg.count, batch_size, max_len,
+                                             arch.qk_rope_head_dim), dtype),
+                    }
                 elif kind == "cross_attn":
                     F = arch.num_frames or 1500
                     kv = lambda: jnp.zeros((seg.count, batch_size, F,
@@ -459,6 +485,11 @@ class Model:
                     kv = P(None, batch_ax, rows_ax if kind == "attn" else None,
                            kv_heads_ax, None)
                     seg_specs[pk] = {"k": kv, "v": kv}
+                elif kind == "mla":
+                    mkp = plan.kind_plan("mla", partition)
+                    lat = P(None, axes(mkp.batch_axes), axes(mkp.rows_axes),
+                            None)
+                    seg_specs[pk] = {"c_kv": lat, "k_rope": lat}
                 elif kind == "ssm":
                     skp = plan.kind_plan("ssm", partition)
                     seg_specs[pk] = {

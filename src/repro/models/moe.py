@@ -13,12 +13,16 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 
-from repro.models.layers import block_norm, dense_init, init_norm
+from repro.models.layers import (block_norm, dense_init, ffn_inner, init_ffn,
+                                 init_norm)
 
 
 def init_moe(key, d_model: int, d_ff: int, num_experts: int, act: str,
-             norm: str, dtype=jnp.bfloat16) -> Dict[str, jax.Array]:
-    ks = jax.random.split(key, 4)
+             norm: str, dtype=jnp.bfloat16,
+             shared_d_ff: int = 0) -> Dict[str, jax.Array]:
+    """``shared_d_ff`` > 0 adds an always-on shared expert of that width
+    (``n_shared_experts * moe_intermediate_size``)."""
+    ks = jax.random.split(key, 5)
     E = num_experts
     def ed(k, a, b):
         return jax.vmap(lambda kk: dense_init(kk, a, b, dtype))(
@@ -30,6 +34,10 @@ def init_moe(key, d_model: int, d_ff: int, num_experts: int, act: str,
     }
     if act == "swiglu":
         p["w_gate"] = ed(ks[3], d_model, d_ff)
+    if shared_d_ff:
+        p.update({f"shared_{k}": v for k, v in init_ffn(
+            ks[4], d_model, shared_d_ff, act, norm, dtype).items()
+            if not k.startswith("ln_")})
     p.update({f"ln_{k}": v for k, v in init_norm(d_model, norm, dtype).items()})
     return p
 
@@ -87,7 +95,12 @@ def apply_moe(x: jax.Array, p: Dict[str, jax.Array], *, top_k: int, act: str,
     contrib = ye_flat[jnp.where(keep, slot, E * cap)]          # (T*K, D)
     contrib = contrib * sorted_gate[:, None].astype(contrib.dtype)
     out = jnp.zeros((T, D), x.dtype).at[sorted_token].add(contrib)
-    return x + shard_fn(out.reshape(B, S, D), role="boundary")
+    out = out.reshape(B, S, D)
+    if "shared_w_up" in p:
+        # the shared expert sees every token, from the same normed input
+        out = out + ffn_inner({k[len("shared_"):]: v for k, v in p.items()
+                               if k.startswith("shared_")}, h, act, x.dtype)
+    return x + shard_fn(out, role="boundary")
 
 
 def _rank_in_group(sorted_ids: jax.Array, num_groups: int) -> jax.Array:

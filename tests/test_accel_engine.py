@@ -229,6 +229,33 @@ def test_padded_lowering_bitwise_identical(backend):
     np.testing.assert_array_equal(r0.node_collective, rp.node_collective)
 
 
+def test_padded_lowering_stages_the_last_real_node():
+    """The last partition stages its last REAL node's featuremap (Eq. 7
+    boundary bytes), not a padded column's. Here the head is a partition
+    of its own whose staged featuremap, in and out, just exceeds the
+    platform's HBM bandwidth over its (compute-bound) time: padded and
+    unpadded lowerings must both find the design infeasible, as the
+    scalar reference does."""
+    from repro.core.hdgraph import HDGraph, Node, Variables
+    common = dict(rows=64, cols=64, batch=8, fm_width=64,
+                  weight_bytes=8192.0, act_bytes=1024.0)
+    graph = HDGraph([Node("l0.ffn", "ffn", 0, flops=1e13, **common),
+                     Node("head", "head", 1, flops=1e12, **common)],
+                    "two", "t", "prefill")
+    plat = Platform(name="t-4x4-slow-hbm", hbm_bw=1e6,
+                    mesh_axes=(("data", 4), ("model", 4)))
+    prob = Problem(graph=graph, platform=plat, backend=BACKENDS["spmd"],
+                   objective="throughput", exec_model="streaming",
+                   opts=ModelOptions())
+    v = Variables((0,), (1, 1), (1, 1), (1, 1))
+    assert any("bandwidth" in x for x in prob.check(v).violations)
+    bev = prob.batched()
+    packed = bev.pack([v])
+    for pad in (None, 5):
+        res = JaxEvaluator(bev, pad_nodes=pad).evaluate_batch(*packed)
+        assert not res.feasible[0], pad
+
+
 # ----------------------------------------------------------------------
 # fleet sweeps (core/accel/fleet.py): vmapped multi-problem search
 # ----------------------------------------------------------------------

@@ -245,7 +245,8 @@ def _span(sid, name, dur, parent=-1):
 
 ROUND = {
     "designs": 4, "points": 100, "trace": None,
-    "counters": {"optim.host_evals": 600, "accel.dispatches.rb_descend": 8},
+    "counters": {"optim.host_evals": 600, "accel.dispatches.rb_descend": 8,
+                 "graph.nodes.mla": 244},
     "spans": [
         _span(0, "optim.rb.host", 0.010),
         _span(1, "optim.repair", 0.004, parent=0),
@@ -259,6 +260,7 @@ ROUND = {
         _span(9, "accel.build_sa_tables", 0.020),
         _span(10, "accel.build_sa_tables", 0.012),
         _span(11, "accel.dispatch.rb_descend", 0.5),
+        _span(12, "graph.build", 0.008),
     ],
 }
 
@@ -270,6 +272,8 @@ ROUND = {
     ("h2d_ms_per_design", 2.0),
     ("readback_ms_per_design", 76.0),
     ("tables_ms_per_design", 8.0),
+    ("parse_ms_per_design", 2.0),
+    ("latent_nodes_per_design", 61.0),
 ])
 def test_reader_on_a_hand_built_round(monkeypatch, name, expected):
     reader = _bench_module(monkeypatch, f"metrics.{name}")
