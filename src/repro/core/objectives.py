@@ -29,6 +29,7 @@ from repro.core.perfmodel import (
     ModelOptions,
     NodeEval,
     eval_nodes,
+    node_eval,
     partition_time,
     t_conf,
 )
@@ -79,18 +80,18 @@ class Problem:
             self._cache[("check", v)] = rep
         return rep
 
-    def _eval_nodes(self, v: Variables):
+    def _eval_nodes(self, v: Variables, nodes: Optional[Sequence[int]] = None):
         """eval_nodes with per-(node, fold-triple) memoisation — probes
-        change one scope at a time, so most triples repeat."""
+        change one scope at a time, so most triples repeat. ``nodes``
+        picks the node indices to evaluate (default: every node)."""
         memo = self._cache.setdefault("node_memo", {})
         out = []
-        for i, n in enumerate(self.graph.nodes):
+        for i in range(len(self.graph.nodes)) if nodes is None else nodes:
             key = (i, v.s_in[i], v.s_out[i], v.kern[i])
             e = memo.get(key)
             if e is None:
-                from repro.core.perfmodel import node_eval
-                e = node_eval(n, key[1], key[2], key[3], self.platform,
-                              self.graph.mode, self.opts)
+                e = node_eval(self.graph.nodes[i], key[1], key[2], key[3],
+                              self.platform, self.graph.mode, self.opts)
                 memo[key] = e
             out.append(e)
         return out

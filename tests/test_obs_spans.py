@@ -111,6 +111,44 @@ def test_host_evals_counts_memo_misses(tiny_arch, small_platform,
     assert metrics.snapshot()["counters"]["optim.host_evals"] == sum(misses)
 
 
+def test_repair_candidates_counts_every_fold_move(tiny_arch, monkeypatch):
+    """``optim.repair.candidates`` grows by one for each fold move that
+    ``repair`` hands to ``set_fold``, and not for a design already
+    feasible."""
+    from conftest import TINY_SHAPE
+    from repro.core.backends import BACKENDS
+    from repro.core.graph_builder import build_hdgraph
+    from repro.core.objectives import Problem
+    from repro.core.optimizers.common import repair
+    from repro.core.platform import Platform
+
+    graph = build_hdgraph(tiny_arch, TINY_SHAPE)
+    backend = BACKENDS["spmd"]
+    calls = []
+    real = type(backend).set_fold
+
+    def spy(self, *a, **kw):
+        calls.append(a)
+        return real(self, *a, **kw)
+
+    monkeypatch.setattr(type(backend), "set_fold", spy)
+    v0 = backend.initial(graph)
+    roomy = Problem(graph=graph, platform=Platform(
+        name="roomy", mesh_axes=(("data", 4), ("model", 4))),
+        backend=backend, objective="latency", exec_model="spmd")
+    fat = max(e.hbm_resident for e in roomy.evaluate(v0).node_evals)
+    assert repair(roomy, v0) == v0
+    assert "optim.repair.candidates" not in metrics.snapshot()["counters"]
+    tight = Problem(graph=graph, platform=Platform(
+        name="tight", mesh_axes=(("data", 4), ("model", 4)),
+        hbm_bytes=fat * 0.3), backend=backend, objective="latency",
+        exec_model="spmd")
+    repair(tight, v0)
+    assert len(calls) > 0
+    assert metrics.snapshot()["counters"]["optim.repair.candidates"] == \
+        len(calls)
+
+
 # ----------------------------------------------------------------------
 # the mirror onto the profiler's clock
 # ----------------------------------------------------------------------
@@ -246,7 +284,7 @@ def _span(sid, name, dur, parent=-1):
 ROUND = {
     "designs": 4, "points": 100, "trace": None,
     "counters": {"optim.host_evals": 600, "accel.dispatches.rb_descend": 8,
-                 "graph.nodes.mla": 244},
+                 "graph.nodes.mla": 244, "optim.repair.candidates": 1000},
     "spans": [
         _span(0, "optim.rb.host", 0.010),
         _span(1, "optim.repair", 0.004, parent=0),
@@ -274,6 +312,7 @@ ROUND = {
     ("tables_ms_per_design", 8.0),
     ("parse_ms_per_design", 2.0),
     ("latent_nodes_per_design", 61.0),
+    ("repair_candidates_per_design", 250.0),
 ])
 def test_reader_on_a_hand_built_round(monkeypatch, name, expected):
     reader = _bench_module(monkeypatch, f"metrics.{name}")
