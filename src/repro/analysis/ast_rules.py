@@ -7,7 +7,7 @@ matrix cell:
   ast/eager-jax-import     modules the ``REPRO_NO_JAX`` import matrix must
                            be able to import (``repro.core.*``,
                            ``repro.configs.*``, ``repro.data.*`` — minus
-                           the four jax-subject accel modules) must not
+                           the three jax-subject accel modules) must not
                            import jax at module scope. A violation here is
                            exactly the failure mode the no-jax CI job
                            exists to catch, surfaced at lint time instead
@@ -77,14 +77,22 @@ NO_JAX_EXCEPTIONS: Tuple[str, ...] = (
     "repro/core/accel/eval_jax.py",
     "repro/core/accel/search_loops.py",
     "repro/core/accel/fleet.py",
-    "repro/core/accel/pallas_segred.py",
     "repro/analysis/jaxpr_audit.py",
+)
+
+#: src/-relative prefixes of the code that jitted programs trace: the
+#: accel package and the array program it shares with the numpy engine
+TRACED_SCOPES: Tuple[str, ...] = (
+    "repro/core/accel/",
+    "repro/core/array_program.py",
 )
 
 #: helpers called from inside jitted programs that are not themselves
 #: decorated: function name -> parameter names that are trace-static
-#: (everything else is traced). Keyed by bare name; scoped to core/accel/.
+#: (everything else is traced). Keyed by bare name; scoped to
+#: ``TRACED_SCOPES``.
 TRACED_HELPERS: Dict[str, Set[str]] = {
+    "eval_core": {"static", "single_partition"},
     "_eval_core": {"static", "single_partition"},
     "_collective_bytes": {"static"},
     "_realizable": {"static"},
@@ -268,11 +276,11 @@ _CASTS = ("bool", "float", "int")
 def check_traced_python_branch(tree: ast.Module,
                                rel_src: str) -> List[Violation]:
     """Inside jitted bodies (and registered traced helpers) in
-    ``core/accel/``: flag ``if``/``while`` tests, ``assert`` tests and
+    ``TRACED_SCOPES``: flag ``if``/``while`` tests, ``assert`` tests and
     ``bool()``/``float()``/``int()`` casts that reference a traced
     parameter by name. Conservative by construction — locals derived from
     traced values are not tracked — so every hit is a real one."""
-    if not rel_src.startswith("repro/core/accel/"):
+    if not rel_src.startswith(TRACED_SCOPES):
         return []
     out: List[Violation] = []
 

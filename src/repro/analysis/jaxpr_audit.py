@@ -239,18 +239,6 @@ def build_entry_points(problems: Optional[Sequence] = None
             jev.static, jev.arrays, ones, ones, ones, cb), \
             jev.arrays.flops.dtype
 
-    def eval_batch_pallas():
-        # the TPU segmented-reduction route, traced in interpret mode so
-        # the audit sees the same program the pallas tests exercise
-        jev = JaxEvaluator.from_problem(p, use_pallas=True,
-                                        pallas_interpret=True)
-        n = jev.n_pad
-        ones = np.ones((4, n), np.int64)
-        cb = np.zeros((4, max(n - 1, 0)), bool)
-        return jax.make_jaxpr(evaluate_batch_jax, static_argnums=(0,))(
-            jev.static, jev.arrays, ones, ones, ones, cb), \
-            jev.arrays.flops.dtype
-
     def bf_chunk():
         from repro.core.optimizers.brute_force import (
             _clamp_tables,
@@ -391,7 +379,6 @@ def build_entry_points(problems: Optional[Sequence] = None
 
     return [
         EntryPoint("eval_batch", eval_batch),
-        EntryPoint("eval_batch_pallas", eval_batch_pallas),
         EntryPoint("bf_chunk", bf_chunk),
         EntryPoint("sa_sweeps", sa_sweeps),
         EntryPoint("rb_descend", rb_descend, allow_while=True),

@@ -1,20 +1,21 @@
 """Accelerator-resident design-space search (the Table-IV hot path on JAX).
 
-``core/batched_eval.py`` laid the evaluation out as pure elementwise ops plus
-segment reductions over a static node axis precisely so it could be jitted;
-this package is that jit. It holds four layers:
+The cost model's batched form is one array program
+(``core/array_program.py``) over a lowered record; the numpy engine runs it
+on the host, this package jits it onto the device and builds candidates
+there. It holds four layers:
 
-  lowering.py      BatchedEvaluator flat numpy arrays -> a pytree of device
-                   constants (``DeviceArrays``) + a hashable ``StaticSpec``
+  lowering.py      BatchedEvaluator flat numpy arrays -> the program's
+                   record (``DeviceArrays``: float64 on the host, padded
+                   device constants for jax) + a hashable ``StaticSpec``
                    so the jitted programs cache across Problem instances.
                    Architecture structure (kind columns, scan groups) is
                    array data, not trace structure, and the node axis can
                    be padded bit-neutrally — which is what lets fleet.py
                    vmap one executable over many problems.
-  eval_jax.py      the jitted ``evaluate_batch`` array program (dense
-                   one-hot segment reductions for partition times,
-                   optionally a Pallas segmented-reduction kernel with an
-                   interpret-mode fallback on CPU).
+  eval_jax.py      the array program on jnp (dense one-hot segment
+                   reductions for partition times) and the jitted
+                   ``evaluate_batch``.
   search_loops.py  on-device candidate *construction*: mixed-radix digit
                    decode for brute-force chunks, a ``jax.random``-driven
                    multi-chain simulated-annealing sweep on ``lax.scan``
@@ -37,7 +38,7 @@ Engine registry
 The optimisers select an evaluation engine by name:
 
   scalar   the original one-design-at-a-time reference (perfmodel.py)
-  numpy    the vectorised host array program (batched_eval.py)
+  numpy    the array program on the host (batched_eval.py)
   jax      this package: jitted, accelerator-resident construction + eval
 
 ``resolve_engine`` maps names (plus the aliases ``auto`` and the legacy

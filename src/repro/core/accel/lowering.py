@@ -1,15 +1,12 @@
-"""Lowering: BatchedEvaluator flat numpy arrays -> JAX device constants.
+"""Lowering: a BatchedEvaluator's node arrays -> the array program's inputs.
 
-The host lowering (``core/batched_eval.py``) already flattens an HDGraph +
-Platform + ModelOptions into per-node numpy arrays; this module converts that
-result into the two halves a jitted program needs:
+The array program (``core/array_program.py``) reads two things:
 
   ``StaticSpec``    an immutable, hashable bundle of everything that shapes
                     the traced program: mode/backend flags, ModelOptions,
-                    and the (padded) node count. Since PR 3 the spec
-                    carries NO per-architecture structure, since PR 4 NO
-                    platform identity, and since PR 5 NO objective
-                    configuration either — kind columns, scan groups,
+                    and the (padded) node count. The spec carries NO
+                    per-architecture structure, NO platform identity and
+                    NO objective configuration — kind columns, scan groups,
                     tying pairs, resource limits, bandwidth scalars, the
                     fold-realisability cube, the Eq. 5 objective selector
                     and the Eq. 4 batch-amortisation factor all live in
@@ -20,11 +17,15 @@ result into the two halves a jitted program needs:
                     executable, and the fleet engine (``fleet.py``) can
                     ``vmap`` the program across a stacked
                     (model, platform, objective) problem axis.
-  ``DeviceArrays``  a NamedTuple pytree of ``jnp`` arrays: per-node
-                    workload quantities, kind masks, scan-tying pairs,
-                    validity masks, the per-problem platform scalars
-                    (``peak_flops`` .. ``chips``) and the
-                    mesh-realisability lookup tables.
+  ``DeviceArrays``  a NamedTuple record of per-node workload quantities,
+                    kind masks, scan-tying pairs, validity masks, the
+                    per-problem platform scalars (``peak_flops`` ..
+                    ``chips``) and the mesh-realisability lookup tables.
+
+``host_arrays`` builds the record once per problem as float64/int64 numpy
+arrays, unpadded; the numpy engine evaluates on it directly.
+``lower_program`` is that record padded and moved to the jax device at the
+device dtype — nothing is built twice.
 
 Padding: ``lower_program(..., pad_nodes=N)`` pads every per-node array to N
 columns with *neutral* nodes (zero work, fold menus pinned to 1, no cuts
@@ -55,7 +56,7 @@ import numpy as np
 from repro.core.accel import EngineUnavailable, require_jax
 from repro.obs import trace as _trace
 
-#: realisability tables are built by calling ``platform.folds_realizable``
+#: realisability cubes are built by calling ``platform.folds_realizable``
 #: over the fold-value cube; above this menu size the cube is too expensive
 #: to enumerate scalar-by-scalar for platforms without a product rule.
 MAX_TABLE_VALUES = 64
@@ -63,15 +64,15 @@ MAX_TABLE_VALUES = 64
 
 @dataclass(frozen=True)
 class StaticSpec:
-    """Hashable trace-shaping configuration for the jitted array program.
+    """Hashable trace-shaping configuration for the array program.
 
     Deliberately architecture-free AND platform-free: everything that
     differs between two graphs, or between two target platforms, is array
     *data* (``DeviceArrays``), not trace structure. Only mode/backend
     rule flags, ModelOptions and the padded node count remain — the things
     that genuinely change which operations the traced program performs.
-    Since PR 5 the per-problem objective (``latency`` vs ``throughput``)
-    and ``batch_amortisation`` are data too (``DeviceArrays.obj_latency``
+    The per-problem objective (``latency`` vs ``throughput``) and
+    ``batch_amortisation`` are data too (``DeviceArrays.obj_latency``
     / ``.batch_amortisation``): Eq. 5 selects the objective with a traced
     ``where`` over both computed branches, so a mixed-objective fleet
     bucket shares one executable. ``n_nodes`` is the PADDED node count
@@ -91,8 +92,6 @@ class StaticSpec:
     grad_compression: float
     mxu_efficiency: float
     overlap_collectives: float
-    use_pallas: bool = False        # Pallas segmented reduction for T(P_i)
-    pallas_interpret: bool = False  # interpret-mode fallback (CPU)
 
     @property
     def train(self) -> bool:
@@ -104,7 +103,8 @@ class StaticSpec:
 
 
 class DeviceArrays(NamedTuple):
-    """Per-node device constants (a pytree; all leaves are jnp arrays).
+    """Per-problem inputs of the array program: numpy arrays on the host
+    (``host_arrays``), jnp arrays on the device (``lower_program``).
 
     The fleet engine stacks several problems' ``DeviceArrays`` along a new
     leading axis and ``vmap``s the evaluation over it, so every
@@ -146,10 +146,10 @@ class DeviceArrays(NamedTuple):
     dma_bw: "jax.Array"
     reconf_fixed_s: "jax.Array"
     chips: "jax.Array"              # scalar, float (exact: chips <= 2**24)
-    # per-problem objective configuration — DATA since PR 5, so a fleet
-    # bucket may mix objectives and amortisation factors without splitting
-    # the cached executable (Eq. 5 selects via a traced where, Eq. 4's B
-    # is a runtime scalar)
+    # per-problem objective configuration — DATA, so a fleet bucket may
+    # mix objectives and amortisation factors without splitting the
+    # cached executable (Eq. 5 selects via a traced where, Eq. 4's B is a
+    # runtime scalar)
     obj_latency: "jax.Array"        # scalar bool: True => Eq. 3 latency
     batch_amortisation: "jax.Array"  # scalar, float (B in Eq. 4; exact)
     # kind-specific column masks (see batched_eval._lower's index sets)
@@ -162,7 +162,8 @@ class DeviceArrays(NamedTuple):
     m_kv: "jax.Array"
     m_carry: "jax.Array"
     m_latent: "jax.Array"
-    # scan-tying consecutive member pairs, padded with (0, 0) self-pairs
+    # scan-tying consecutive member pairs; the device record pads them
+    # with (0, 0) self-pairs
     pair_a: "jax.Array"             # [n_pairs_pad]
     pair_b: "jax.Array"
     # padding bookkeeping
@@ -170,64 +171,89 @@ class DeviceArrays(NamedTuple):
     n_valid: "jax.Array"            # scalar: count of real nodes
 
 
-def _realizability_table(bev) -> Tuple[np.ndarray, np.ndarray, int]:
-    """(table, lut, cap) — reuse the host evaluator's table, or build one.
+def realizability_table(platform
+                        ) -> Optional[Tuple[np.ndarray, np.ndarray, int]]:
+    """(table, lut, cap) over the platform's fold menu, or None.
 
-    ``batched_eval`` builds the cube only for menus of <= 24 values; the jax
-    engine needs it always (the memoised unique-triple fallback is a host
-    loop). AbstractPlatform realisability is a pure product rule, so its
-    cube vectorises at any size; generic platforms are enumerated up to
-    ``MAX_TABLE_VALUES`` menu entries.
+    ``table[a, b, c]`` says whether the fold triple (menu[a], menu[b],
+    menu[c]) is mesh-realisable; ``lut`` maps a fold value to its menu
+    index (-1: not a menu value, so never realisable) and ``cap`` is the
+    lut's sentinel slot for values past the menu. AbstractPlatform
+    realisability is a pure product rule, so its cube vectorises at any
+    size; other platforms are enumerated up to ``MAX_TABLE_VALUES`` menu
+    entries. Past that there is no cube (None): the jax engine refuses
+    the platform and the numpy engine tests triples one by one.
     """
-    if getattr(bev, "_real_table", None) is not None:
-        return bev._real_table, bev._val_lut, bev._val_max + 1
-
-    plat = bev.platform
-    vals = np.asarray(plat.fold_values(), np.int64)
+    vals = np.asarray(platform.fold_values(), np.int64)
     nv = len(vals)
-    # duck-typed product rule (AbstractPlatform): realisable iff the product
-    # of folds fits the mesh — vectorise instead of nv^3 scalar calls.
     from repro.core.platform import AbstractPlatform
-    if isinstance(plat, AbstractPlatform):
-        prod = vals[:, None, None] * vals[None, :, None] * vals[None, None, :]
-        table = prod <= plat.chips
+    if isinstance(platform, AbstractPlatform):
+        # a*b*c <= chips  <=>  a*b <= chips // c, for positive folds
+        ab = vals[:, None] * vals[None, :]
+        table = ab[:, :, None] <= (platform.chips // vals)[None, None, :]
     elif nv <= MAX_TABLE_VALUES:
         table = np.zeros((nv, nv, nv), bool)
         for a, fa in enumerate(vals):
             for b, fb in enumerate(vals):
                 for d, fd in enumerate(vals):
-                    table[a, b, d] = plat.folds_realizable((fa, fb, fd))
+                    table[a, b, d] = platform.folds_realizable((fa, fb, fd))
     else:
-        raise EngineUnavailable(
-            f"platform {plat.name!r} has {nv} fold values; the jax engine "
-            f"needs a dense realisability table (<= {MAX_TABLE_VALUES} "
-            f"values) or an AbstractPlatform product rule. Use "
-            f"engine='numpy' for this platform.")
+        return None
     val_max = int(vals[-1])
     lut = np.full(val_max + 2, -1, np.int64)
     lut[vals] = np.arange(nv)
     return table, lut, val_max + 1
 
 
-def _pad1(a: np.ndarray, n_pad: int, fill) -> np.ndarray:
-    """Pad a per-node (or per-edge) 1-D array to ``n_pad`` with ``fill``."""
-    if len(a) >= n_pad:
-        return a
-    out = np.full(n_pad, fill, a.dtype)
-    out[:len(a)] = a
-    return out
-
-
-def _mask(index_set, n: int, n_pad: int) -> np.ndarray:
-    m = np.zeros(n_pad, bool)
+def _mask(index_set, n: int) -> np.ndarray:
+    m = np.zeros(n, bool)
     m[np.asarray(index_set, np.int64)] = True
     return m
 
 
+def host_arrays(bev) -> DeviceArrays:
+    """The array program's record for a BatchedEvaluator, on the host:
+    float64/int64/bool numpy arrays, unpadded. Derived from the
+    evaluator's attributes as ``_lower`` left them. Without a
+    realisability cube (``realizability_table`` is None) the three table
+    fields are None."""
+    n = bev.n_nodes
+    f = lambda a: np.asarray(a, np.float64)
+    i = lambda a: np.asarray(a, np.int64)
+    b = lambda a: np.asarray(a, bool)
+    table, lut, cap = realizability_table(bev.platform) or (None,) * 3
+    pf, hbw, hby, ibw, dbw, rfs, chips = bev.platform_scalars()
+    return DeviceArrays(
+        flops=f(bev.flops), weight_bytes=f(bev.weight_bytes),
+        act_bytes=f(bev.act_bytes), inner_bytes=f(bev.inner_bytes),
+        state_bytes=f(bev.state_bytes), kv_bytes=f(bev.kv_bytes),
+        carry_bytes=f(bev.carry_bytes), node_d=f(bev.node_d),
+        reshard_full=f(bev.reshard_full),
+        batch=i(bev.batch), rows=i(bev.rows), cols=i(bev.cols),
+        fm_width=i(bev.fm_width), col_div=i(bev.col_div),
+        kv_limit=i(bev.kv_limit), latent_dim=i(bev.latent_dim),
+        ep_topk=i(bev.ep_topk), scan_group=i(bev.scan_group),
+        internal=b(bev.internal), elementwise=b(bev.elementwise),
+        weight_stream=b(bev.weight_stream), cut_allowed=b(bev.cut_allowed),
+        real_table=table, val_lut=lut,
+        val_cap=None if cap is None else np.asarray(cap, np.int64),
+        peak_flops=pf, hbm_bw=hbw, hbm_bytes=hby, ici_bw=ibw, dma_bw=dbw,
+        reconf_fixed_s=rfs, chips=chips,
+        obj_latency=np.asarray(bev.objective == "latency"),
+        batch_amortisation=np.asarray(float(bev.batch_amortisation)),
+        m_attn=_mask(bev.i_attn, n), m_head=_mask(bev.i_head, n),
+        m_tp=_mask(bev.i_tp, n), m_ep=_mask(bev.i_ep, n),
+        m_vocab=_mask(bev.i_vocab, n), m_vhead=_mask(bev.i_vhead, n),
+        m_kv=_mask(bev.i_kv, n), m_carry=_mask(bev.i_carry, n),
+        m_latent=_mask(bev.i_latent, n),
+        pair_a=i(bev.scan_pairs[:, 0]), pair_b=i(bev.scan_pairs[:, 1]),
+        node_valid=np.ones(n, bool), n_valid=np.asarray(n, np.int64),
+    )
+
+
 @_trace.traced("accel.build_static_spec")
-def build_static_spec(bev, *, use_pallas: bool = False,
-                      pallas_interpret: bool = False,
-                      pad_nodes: Optional[int] = None) -> StaticSpec:
+def build_static_spec(bev, *, pad_nodes: Optional[int] = None
+                      ) -> StaticSpec:
     """Pure-host construction of the trace-shaping spec (no jax needed).
 
     This is the static-analysis hook: ``repro.analysis.recompile_lint``
@@ -236,8 +262,6 @@ def build_static_spec(bev, *, use_pallas: bool = False,
     across the grid, i.e. data that should have been a ``DeviceArrays``
     leaf. ``lower_program`` routes through here so the linted spec and
     the spec that actually keys the XLA executable cache can never drift.
-    Unlike ``lower_program``, ``pallas_interpret`` has no backend-probing
-    default — callers without jax must pick explicitly.
     """
     n = bev.n_nodes
     np_ = n if pad_nodes is None else int(pad_nodes)
@@ -257,29 +281,7 @@ def build_static_spec(bev, *, use_pallas: bool = False,
         grad_compression=opts.grad_compression,
         mxu_efficiency=opts.mxu_efficiency,
         overlap_collectives=opts.overlap_collectives,
-        use_pallas=use_pallas,
-        pallas_interpret=pallas_interpret,
     )
-
-
-#: BatchedEvaluator arrays covered by ``problem_fingerprint``, in
-#: ``DeviceArrays`` field order — exactly the per-node/per-edge content
-#: ``lower_program`` ships to the device. Extending ``DeviceArrays`` with
-#: a new lowered array means extending this tuple too (the fingerprint
-#: must keep covering everything that shapes engine results).
-FINGERPRINT_ARRAYS: Tuple[str, ...] = (
-    "flops", "weight_bytes", "act_bytes", "inner_bytes", "state_bytes",
-    "kv_bytes", "carry_bytes", "node_d", "reshard_full", "batch", "rows",
-    "cols", "fm_width", "col_div", "kv_limit", "latent_dim", "ep_topk",
-    "scan_group", "internal", "elementwise", "weight_stream", "cut_allowed",
-)
-
-#: kind index sets covered by ``problem_fingerprint`` (the
-#: ``DeviceArrays.m_*`` mask sources).
-FINGERPRINT_INDEX_SETS: Tuple[str, ...] = (
-    "i_attn", "i_head", "i_tp", "i_ep", "i_vocab", "i_vhead", "i_kv",
-    "i_carry", "i_latent",
-)
 
 
 @_trace.traced("accel.problem_fingerprint")
@@ -288,77 +290,68 @@ def problem_fingerprint(problem) -> str:
 
     Routes through ``build_static_spec`` — the same keying path that
     shapes the XLA executable cache and that ``recompile_lint`` audits —
-    and then hashes every array ``lower_program`` would ship to the
-    device: the per-node workload quantities, kind index sets, scan
-    pairs, platform scalar vector, fold-realisability cube/lut, plus the
-    Eq. 5 objective flag and Eq. 4 amortisation factor. Two problems
-    with equal fingerprints lower to bit-identical device programs (at
-    any shared padding — padding is excluded on purpose: it is
-    bit-neutral by the lowering contract, so it cannot change results),
-    and therefore every deterministic engine returns identical designs,
-    objectives and histories for them. This is the keying contract the
-    service cache (``repro/service/cache.py``) and the
-    ``optimise_portfolio`` duplicate-coalescing fix rely on
-    (docs/service.md documents it).
+    and then hashes the host record ``lower_program`` ships to the
+    device: the per-node workload quantities, kind masks, scan pairs,
+    platform scalars, fold-realisability cube/lut, plus the Eq. 5
+    objective flag and Eq. 4 amortisation factor. Two problems with
+    equal fingerprints lower to bit-identical device programs (at any
+    shared padding — padding is excluded on purpose: it is bit-neutral by
+    the lowering contract, so it cannot change results), and therefore
+    every deterministic engine returns identical designs, objectives and
+    histories for them. This is the keying contract the service cache
+    (``repro/service/cache.py``) and the ``optimise_portfolio``
+    duplicate-coalescing fix rely on (docs/service.md documents it).
 
     Accepts a ``Problem`` (lowers via its cached ``batched()``) or a
     ``BatchedEvaluator`` directly. Pure host, jax-free.
     """
     bev = problem.batched() if hasattr(problem, "batched") else problem
-    # engine knobs (use_pallas / interpret mode) change the kernel route,
-    # not the computed design — pin them so the fingerprint is a problem
-    # identity, not an engine configuration
-    static = build_static_spec(bev, use_pallas=False,
-                               pallas_interpret=False)
-    h = hashlib.sha256(b"repro.problem_fingerprint.v1")
-    h.update(repr(dataclasses.astuple(static)).encode())
-
-    def feed(name: str, a: np.ndarray) -> None:
+    h = hashlib.sha256(b"repro.problem_fingerprint.v2")
+    h.update(repr(dataclasses.astuple(build_static_spec(bev))).encode())
+    for name, a in bev.arrays._asdict().items():
+        if a is None:
+            continue
         a = np.ascontiguousarray(a)
         h.update(f"|{name}:{a.dtype.str}:{a.shape}|".encode())
         h.update(a.tobytes())
-
-    for name in FINGERPRINT_ARRAYS:
-        feed(name, np.asarray(getattr(bev, name)))
-    for name in FINGERPRINT_INDEX_SETS:
-        feed(name, np.asarray(sorted(getattr(bev, name)), np.int64))
-    feed("scan_pairs", np.asarray(bev.scan_pairs, np.int64))
-    feed("platform_scalars", np.asarray(bev.platform_scalars(),
-                                        np.float64))
-    try:
-        table, lut, cap = _realizability_table(bev)
-        feed("real_table", table.astype(np.uint8))
-        feed("val_lut", np.asarray(lut, np.int64))
-        h.update(f"|cap:{int(cap)}|".encode())
-    except EngineUnavailable:
+    if bev.arrays.real_table is None:
         # menus too large for a dense cube (numpy-engine-only platforms):
         # the fold menu plus the platform name pins the candidate space —
         # a false MISS is possible across renamed-but-identical platforms,
         # a false HIT is not
-        feed("fold_values", np.asarray(bev.platform.fold_values(),
-                                       np.int64))
+        h.update(np.asarray(bev.platform.fold_values(), np.int64).tobytes())
         h.update(f"|platform:{bev.platform.name}|".encode())
-    h.update(f"|objective:{bev.objective}"
-             f"|amort:{float(bev.batch_amortisation)!r}|".encode())
     return h.hexdigest()
 
 
+#: per-node fields padded with something other than 0 / False (the fold
+#: divisors and counts of a neutral node are 1; scan group -1 is "none")
+_PAD_FILL = {"batch": 1, "rows": 1, "cols": 1, "col_div": 1,
+             "scan_group": -1, "val_lut": -1}
+
+
+def _pad1(a: np.ndarray, n_pad: int, fill) -> np.ndarray:
+    """Pad a per-node (or per-edge) 1-D array to ``n_pad`` with ``fill``."""
+    if len(a) >= n_pad:
+        return a
+    out = np.full(n_pad, fill, a.dtype)
+    out[:len(a)] = a
+    return out
+
+
 @_trace.traced("accel.lower_program")
-def lower_program(bev, *, use_pallas: bool = False,
-                  pallas_interpret: bool | None = None,
-                  pad_nodes: Optional[int] = None,
+def lower_program(bev, *, pad_nodes: Optional[int] = None,
                   pad_pairs: Optional[int] = None,
                   pad_vals: Optional[int] = None,
                   pad_lut: Optional[int] = None
                   ) -> Tuple[StaticSpec, DeviceArrays]:
-    """Lower a host ``BatchedEvaluator`` onto the default jax device.
+    """The host record (``bev.arrays``) padded and moved onto the default
+    jax device at its dtype (float32/int32, or float64/int64 under x64).
 
-    ``use_pallas`` routes the partition-time segmented reduction through the
-    Pallas kernel (the TPU hot path); ``pallas_interpret`` forces interpret
-    mode (defaults to True off-TPU so the kernel stays runnable on CPU).
     ``pad_nodes``/``pad_pairs`` pad the node axis / scan-pair list so
     problems of different sizes can share one StaticSpec (fleet sweeps);
     padded columns are neutral and provably cannot change any result.
+    The pair list is padded to at least one (0, 0) self-pair.
     ``pad_vals``/``pad_lut`` pad the fold-realisability cube and the
     value->index lut the same way (False / -1 fill: a padded slot is
     "unknown value" and unknown values were already infeasible), so
@@ -372,99 +365,39 @@ def lower_program(bev, *, use_pallas: bool = False,
     fdt = jnp.float64 if x64 else jnp.float32
     idt = jnp.int64 if x64 else jnp.int32
 
-    table, lut, cap = _realizability_table(bev)
-    nv = table.shape[0]
-    pv = nv if pad_vals is None else int(pad_vals)
-    if pv < nv:
-        raise ValueError(f"pad_vals={pv} < fold menu size {nv}")
-    if pv > nv:
-        t2 = np.zeros((pv, pv, pv), bool)
-        t2[:nv, :nv, :nv] = table
-        table = t2
-    pl = len(lut) if pad_lut is None else int(pad_lut)
-    if pl < len(lut):
-        raise ValueError(f"pad_lut={pl} < lut length {len(lut)}")
-    lut = _pad1(lut, pl, -1)
-    if pallas_interpret is None:
-        pallas_interpret = jax.default_backend() != "tpu"
-
-    static = build_static_spec(bev, use_pallas=use_pallas,
-                               pallas_interpret=pallas_interpret,
-                               pad_nodes=pad_nodes)
-    n = bev.n_nodes
+    host = bev.arrays
+    if host.real_table is None:
+        raise EngineUnavailable(
+            f"platform {bev.platform.name!r} has "
+            f"{len(bev.platform.fold_values())} fold values; the jax "
+            f"engine needs a dense realisability table (<= "
+            f"{MAX_TABLE_VALUES} values) or an AbstractPlatform product "
+            f"rule. Use engine='numpy' for this platform.")
+    static = build_static_spec(bev, pad_nodes=pad_nodes)
     np_ = static.n_nodes
-    # the platform scalar vector (batched_eval.PLATFORM_SCALAR_FIELDS
-    # order) becomes per-problem device data — never trace structure
-    pf, hbw, hby, ibw, dbw, rfs, chips = bev.platform_scalars()
 
-    # scan-tying pairs padded with (0, 0): a self-pair can never "differ"
-    pairs = bev.scan_pairs
-    pp = max(pairs.shape[0], 1) if pad_pairs is None else int(pad_pairs)
-    if pp < pairs.shape[0]:
-        raise ValueError(f"pad_pairs={pp} < pair count {pairs.shape[0]}")
-    pair_a = np.zeros(pp, np.int64)
-    pair_b = np.zeros(pp, np.int64)
-    pair_a[:pairs.shape[0]] = pairs[:, 0]
-    pair_b[:pairs.shape[0]] = pairs[:, 1]
+    def pad_to(given, have: int, what: str) -> int:
+        want = have if given is None else int(given)
+        if want < have:
+            raise ValueError(f"{what}={want} < {have}")
+        return want
 
-    node_valid = np.zeros(np_, bool)
-    node_valid[:n] = True
+    nv = host.real_table.shape[0]
+    pv = pad_to(pad_vals, nv, "pad_vals")
+    table = np.zeros((pv, pv, pv), bool)
+    table[:nv, :nv, :nv] = host.real_table
+    n_pairs = len(host.pair_a)
+    pp = pad_to(max(n_pairs, 1) if pad_pairs is None else pad_pairs,
+                n_pairs, "pad_pairs")
+    length = {"cut_allowed": max(np_ - 1, 0), "pair_a": pp, "pair_b": pp,
+              "val_lut": pad_to(pad_lut, len(host.val_lut), "pad_lut")}
 
-    ef = lambda a, fill: jnp.asarray(_pad1(np.asarray(a, np.float64),
-                                           np_, fill), fdt)
-    ei = lambda a, fill: jnp.asarray(_pad1(np.asarray(a, np.int64),
-                                           np_, fill), idt)
-    eb = lambda a: jnp.asarray(_pad1(np.asarray(a, bool), np_, False))
-    km = lambda ix: jnp.asarray(_mask(ix, n, np_))
-
-    arrays = DeviceArrays(
-        flops=ef(bev.flops, 0.0),
-        weight_bytes=ef(bev.weight_bytes, 0.0),
-        act_bytes=ef(bev.act_bytes, 0.0),
-        inner_bytes=ef(bev.inner_bytes, 0.0),
-        state_bytes=ef(bev.state_bytes, 0.0),
-        kv_bytes=ef(bev.kv_bytes, 0.0),
-        carry_bytes=ef(bev.carry_bytes, 0.0),
-        node_d=ef(bev.node_d, 0.0),
-        reshard_full=ef(bev.reshard_full, 0.0),
-        batch=ei(bev.batch, 1),
-        rows=ei(bev.rows, 1),
-        cols=ei(bev.cols, 1),
-        fm_width=ei(bev.fm_width, 0),
-        col_div=ei(bev.col_div, 1),
-        kv_limit=ei(bev.kv_limit, 0),
-        latent_dim=ei(bev.latent_dim, 0),
-        ep_topk=ei(bev.ep_topk, 0),
-        scan_group=ei(bev.scan_group, -1),
-        internal=eb(bev.internal),
-        elementwise=eb(bev.elementwise),
-        weight_stream=eb(bev.weight_stream),
-        cut_allowed=jnp.asarray(_pad1(np.asarray(bev.cut_allowed, bool),
-                                      max(np_ - 1, 0), False)),
-        real_table=jnp.asarray(table),
-        val_lut=jnp.asarray(lut, idt),
-        val_cap=jnp.asarray(cap, idt),
-        peak_flops=jnp.asarray(pf, fdt),
-        hbm_bw=jnp.asarray(hbw, fdt),
-        hbm_bytes=jnp.asarray(hby, fdt),
-        ici_bw=jnp.asarray(ibw, fdt),
-        dma_bw=jnp.asarray(dbw, fdt),
-        reconf_fixed_s=jnp.asarray(rfs, fdt),
-        chips=jnp.asarray(chips, fdt),
-        obj_latency=jnp.asarray(bev.objective == "latency"),
-        batch_amortisation=jnp.asarray(float(bev.batch_amortisation), fdt),
-        m_attn=km(bev.i_attn),
-        m_head=km(bev.i_head),
-        m_tp=km(bev.i_tp),
-        m_ep=km(bev.i_ep),
-        m_vocab=km(bev.i_vocab),
-        m_vhead=km(bev.i_vhead),
-        m_kv=km(bev.i_kv),
-        m_carry=km(bev.i_carry),
-        m_latent=km(bev.i_latent),
-        pair_a=jnp.asarray(pair_a, idt),
-        pair_b=jnp.asarray(pair_b, idt),
-        node_valid=jnp.asarray(node_valid),
-        n_valid=jnp.asarray(n, idt),
-    )
-    return static, arrays
+    out = {}
+    for name, a in host._asdict().items():
+        if name == "real_table":
+            a = table
+        elif a.ndim == 1:
+            a = _pad1(a, length.get(name, np_), _PAD_FILL.get(name, 0))
+        dt = {"f": fdt, "i": idt}.get(a.dtype.kind)
+        out[name] = jnp.asarray(a, dt)
+    return static, DeviceArrays(**out)

@@ -1,40 +1,34 @@
-"""Batched design-space evaluation engine (Table-IV throughput path).
+"""Batched design-space evaluation engine (the numpy engine).
 
 The optimisers' wall-clock is dominated by ``Problem.evaluate`` — scalar
-Python over dataclasses, one candidate at a time. This module lowers an
-``HDGraph`` + ``Platform`` + ``ModelOptions`` ONCE into flat numpy arrays
-(per-node flops, weight/act/inner/state/kv/carry bytes, kind masks,
-collective-kind one-hots) and then evaluates a *batch* of candidate designs
-``(s_in, s_out, kern)[N, nodes]`` plus a cut bitmask ``[N, edges]`` as one
-vectorised array program: roofline terms, collective bytes, Eq. 6 residency,
-constraint masks, partition times via segmented max/sum, and the Eq. 5
-objective.
+Python over dataclasses, one candidate at a time. ``BatchedEvaluator``
+lowers an ``HDGraph`` + ``Platform`` + ``ModelOptions`` ONCE into flat
+per-node arrays (flops, weight/act/inner/state/kv/carry bytes, kind index
+sets, scan pairs) and evaluates a *batch* of candidate designs
+``(s_in, s_out, kern)[N, nodes]`` plus a cut bitmask ``[N, edges]`` by
+running the array program of ``core/array_program.py`` on numpy, over the
+float64 record ``core/accel/lowering.host_arrays`` derives from those
+arrays. The jax engine (``core/accel/``) runs the same program on the
+device record, so the cost model has one batched source.
 
 The scalar path (core/perfmodel.py + core/objectives.py) stays the reference
 implementation; tests/test_batched_eval.py asserts batched == scalar within
 1e-9 on objective, feasibility, partition times and residency. All arrays are
 float64 and the per-element operation order mirrors the scalar code, so the
 agreement is near-bit-exact (only reduction orders differ).
-
-The array layout is deliberately JAX-compatible (pure elementwise ops +
-segment reductions over a static node axis) so a future PR can jit the hot
-loop onto an accelerator for GPU/TPU-resident search.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Dict, List, Sequence, Tuple
 
 import numpy as np
 
+from repro.core.accel import lowering
+from repro.core.array_program import eval_core
 from repro.core.hdgraph import HDGraph, Variables
-from repro.core.perfmodel import (
-    BF16,
-    ModelOptions,
-    TRAIN_STATE_MULT,
-    ZERO1_RESIDENT,
-    ZERO1_SHARDED,
-)
+from repro.core.perfmodel import ModelOptions
 from repro.core.platform import Platform
 
 _ATTN_KINDS = ("attn", "cross_attn", "enc_attn")
@@ -65,6 +59,38 @@ class BatchResult:
 
     def __len__(self) -> int:
         return int(self.objective.shape[0])
+
+
+class NumpySegments:
+    """The numpy engine's reductions: ``np.add.at`` / ``np.maximum.at`` into
+    flat (row, partition) slots, whose cost is linear in the batch (a dense
+    one-hot would be an [N, n, n] float64 array on the host). Row sums are
+    sequential too, so a one-partition batch gives the same bits through
+    either path of ``eval_core``."""
+
+    @staticmethod
+    def reducers(pid, iota_n, fdt):
+        N, n = pid.shape
+        flat = np.arange(N)[:, None] * n + pid
+
+        def scatter(ufunc, init, idx, vals):
+            out = np.full(N * n, init, fdt)
+            ufunc.at(out, idx.ravel(), vals.ravel())
+            return out.reshape(N, n)
+
+        return (lambda v: scatter(np.add, 0.0, flat, v),
+                lambda v: scatter(np.maximum, -np.inf, flat, v),
+                lambda v: scatter(np.add, 0.0, flat[:, :-1], v))
+
+    @staticmethod
+    def row_sum(x):
+        return np.add.accumulate(x, axis=1)[:, -1]
+
+    @staticmethod
+    def first_column(t0, n):
+        out = np.zeros((t0.shape[0], n), t0.dtype)
+        out[:, 0] = t0
+        return out
 
 
 class BatchedEvaluator:
@@ -131,56 +157,27 @@ class BatchedEvaluator:
         self.internal = m("internal_rows")
         self.elementwise = m("elementwise")
         self.weight_stream = m("weight_stream")
-        self.attnlike = np.array([x.kind in _ATTN_KINDS for x in nodes])
-        self.is_head = np.array([x.kind == "head" for x in nodes])
-        ck = lambda kind: np.array([x.collective_kind == kind for x in nodes])
-        self.c_tp = ck("tp_allreduce")
-        self.c_ep = ck("ep_alltoall")
-        self.c_vocab = ck("vocab_allreduce")
-        self.c_vhead = ck("vocab_head")
 
         allowed = np.zeros(max(n - 1, 0), bool)
         for e in self.graph.cut_edges:
             allowed[e] = True
         self.cut_allowed = allowed
 
-        # static column index sets — kind-specific terms run on slices, not
-        # full-width masked arrays (most kinds touch a handful of nodes)
+        # kind column index sets: the sources of the array program's kind
+        # masks (lowering.host_arrays)
         w = lambda mask: np.nonzero(mask)[0]
-        self.i_attn = w(self.attnlike)
-        self.i_head = w(self.is_head)
-        self.i_tp = w(self.c_tp)
-        self.i_ep = w(self.c_ep)
-        self.i_vocab = w(self.c_vocab)
-        self.i_vhead = w(self.c_vhead)
-        self.i_int = w(self.internal)
+        kind = lambda k: w([x.kind in k for x in nodes])
+        coll = lambda k: w([x.collective_kind == k for x in nodes])
+        self.i_attn = kind(_ATTN_KINDS)
+        self.i_head = kind(("head",))
+        self.i_tp = coll("tp_allreduce")
+        self.i_ep = coll("ep_alltoall")
+        self.i_vocab = coll("vocab_allreduce")
+        self.i_vhead = coll("vocab_head")
         self.i_kv = w(~self.internal & (self.kv_bytes > 0))
         self.i_carry = w(~self.internal & (self.kv_bytes == 0)
                          & (self.carry_bytes > 0))
-        self.i_ew = w(self.elementwise)
-        self.i_kvlim = w(self.kv_limit > 0)
         self.i_latent = w(self.latent_dim > 0)
-
-        # mesh-realisability lookup table over the platform fold menu (small
-        # for real meshes: products of axis subsets). Falls back to the
-        # memoised unique-triple path for very rich menus.
-        vals = self.platform.fold_values()
-        if len(vals) <= 24:
-            nv = len(vals)
-            table = np.zeros((nv, nv, nv), bool)
-            for a, fa in enumerate(vals):
-                for b, fb in enumerate(vals):
-                    for d, fd in enumerate(vals):
-                        table[a, b, d] = self.platform.folds_realizable(
-                            (fa, fb, fd))
-            self._real_table = table
-            # value -> menu index (-1 = not a platform fold value)
-            self._val_max = vals[-1]
-            lut = np.full(self._val_max + 2, -1, np.int64)
-            lut[np.array(vals)] = np.arange(nv)
-            self._val_lut = lut
-        else:
-            self._real_table = None
 
         # Boundary featuremap bytes (Eq. 7 convention: full rows, bf16).
         self.node_d = (self.batch * self.rows * self.fm_width).astype(
@@ -241,19 +238,20 @@ class BatchedEvaluator:
                          tuple(int(x) for x in kk[row]))
 
     # ------------------------------------------------------------------
-    # mesh realisability over unique fold triples (memoised)
+    # the array program's inputs (built after ``_lower``, on first use)
     # ------------------------------------------------------------------
+    @functools.cached_property
+    def arrays(self) -> "lowering.DeviceArrays":
+        """The float64/int64 host record of the array program."""
+        return lowering.host_arrays(self)
+
+    @functools.cached_property
+    def static(self) -> "lowering.StaticSpec":
+        return lowering.build_static_spec(self)
+
     def _realizable(self, si, so, kk) -> np.ndarray:
-        if self._real_table is not None:
-            cap = self._val_max + 1               # sentinel lut slot (-1)
-            lut = self._val_lut
-            ia = lut[np.minimum(si, cap)]
-            ib = lut[np.minimum(so, cap)]
-            ic = lut[np.minimum(kk, cap)]
-            known = (ia >= 0) & (ib >= 0) & (ic >= 0)
-            return known & self._real_table[np.maximum(ia, 0),
-                                            np.maximum(ib, 0),
-                                            np.maximum(ic, 0)]
+        """Mesh realisability over unique fold triples (memoised): the
+        test for fold menus too large for a realisability cube."""
         enc = (si.astype(np.int64) << 40) | (so << 20) | kk
         uniq, inv = np.unique(enc, return_inverse=True)
         ok = np.empty(len(uniq), bool)
@@ -267,9 +265,6 @@ class BatchedEvaluator:
             ok[u] = r
         return ok[inv].reshape(si.shape)
 
-    # ------------------------------------------------------------------
-    # the batched array program
-    # ------------------------------------------------------------------
     def evaluate_batch(self, s_in, s_out, kern, cuts) -> BatchResult:
         """Evaluate N candidates. ``s_in/s_out/kern``: int arrays [N, nodes];
         ``cuts``: bool bitmask [N, nodes-1] over chain edges."""
@@ -284,288 +279,19 @@ class BatchedEvaluator:
                 f"expected fold arrays [N, {self.n_nodes}] and cut mask "
                 f"[N, {self.n_nodes - 1}]; got s_in {si.shape}, s_out "
                 f"{so.shape}, kern {kk.shape}, cuts {cb.shape}")
-        plat, opts, mode = self.platform, self.opts, self.mode
-        train = mode == "train"
-        decode = mode == "decode"
-
-        sif = si.astype(np.float64)
-        sof = so.astype(np.float64)
-        kkf = kk.astype(np.float64)
-
-        # ---------------- node roofline (perfmodel.node_eval) ----------
-        c = sif * sof * kkf
-        b_in = np.where(self.internal, 1.0, sif)
-        compute_s = (self.flops / c) / (plat.peak_flops * opts.mxu_efficiency)
-
-        w_per_chip = self.weight_bytes / sof
-        act_per_chip = self.act_bytes / (b_in * kkf)
-        inner_per_chip = self.inner_bytes / c
-
-        # _state_sharding (KV sharding applies on attention-kind columns)
-        state_div = kkf * sof
-        state_repl = np.ones_like(sof)
-        ia = self.i_attn
-        if len(ia):
-            kvl = self.kv_limit[ia]
-            kv_div_a = np.where(kvl > 0,
-                                np.minimum(sof[:, ia], kvl.astype(np.float64)),
-                                sof[:, ia])
-            state_div[:, ia] = kkf[:, ia] * np.maximum(kv_div_a, 1.0) \
-                * sif[:, ia]
-            state_repl[:, ia] = np.where((kvl > 0) & (so[:, ia] > kvl),
-                                         sof[:, ia] / kv_div_a, 1.0)
-        il = self.i_latent
-        if len(il):
-            # latent KV: whole on every head fold (perfmodel._state_sharding)
-            state_div[:, il] = kkf[:, il] * sif[:, il]
-        state_per_chip = self.state_bytes * state_repl / state_div
-
-        train_mult = 3.0 if train else 1.0
-        hbm_bytes = (act_per_chip + inner_per_chip) * train_mult
-        if train:
-            hbm_bytes = hbm_bytes + 2.0 * w_per_chip
-        else:
-            hbm_bytes = hbm_bytes + np.where(self.weight_stream, w_per_chip, 0.0)
-            hbm_bytes = hbm_bytes + state_per_chip
-        memory_s = hbm_bytes / plat.hbm_bw
-
-        coll = self._collective_bytes(si, so, kk, sif, sof, kkf, b_in)
-        collective_s = coll / plat.ici_bw * (1.0 - opts.overlap_collectives)
-
-        # ---------------- residency (Eq. 6) ----------------------------
-        if train:
-            if opts.zero1:
-                resident = w_per_chip * ZERO1_RESIDENT \
-                    + w_per_chip * ZERO1_SHARDED / kkf
-            else:
-                resident = w_per_chip * TRAIN_STATE_MULT
-            stash_div = sif * kkf
-            if opts.seq_parallel_stash:
-                stash_div = stash_div * np.maximum(sof, 1.0)
-            fm = (self.batch * self.rows * self.fm_width).astype(np.float64)
-            resident = resident + fm * BF16 / stash_div
-            ih = self.i_head
-            if len(ih):
-                resident[:, ih] += 3.0 * self.inner_bytes[ih] \
-                    / (b_in[:, ih] * kkf[:, ih] * np.maximum(sof[:, ih], 1.0))
-        else:
-            rows = np.where(decode, 1, self.rows).astype(np.float64)
-            resident = w_per_chip + state_per_chip \
-                + 2.0 * self.batch * rows * self.fm_width * BF16 / (b_in * kkf)
-
-        node_time = np.maximum(np.maximum(compute_s, memory_s), collective_s)
-
-        # ---------------- partition structure ---------------------------
-        any_cut = n > 1 and bool(cb.any())
-        if n > 1 and self.n_nodes > 1:
-            mism = (b_in[:, :-1] != b_in[:, 1:]) | (kk[:, :-1] != kk[:, 1:])
-        else:
-            mism = np.zeros((N, max(n - 1, 0)), bool)
-
-        if not any_cut:
-            # fast path: every candidate is one partition — no segment
-            # scatter, no reconfiguration, no boundary staging/bandwidth
-            nparts = np.ones(N, np.int64)
-            pid = None
-            part_valid = np.zeros((N, n), bool)
-            part_valid[:, 0] = True
-            t_part = np.zeros((N, n))
-            if self.exec_model == "streaming":
-                t_part[:, 0] = node_time.max(axis=1)
-            else:
-                t_part[:, 0] = node_time.sum(axis=1)
-            t_base = t_part
-            if not self.inter_matching and n > 1:
-                edge_t = np.where(mism, self.reshard_full[:-1] / plat.ici_bw,
-                                  0.0)
-                t_part = t_part.copy()
-                t_part[:, 0] += edge_t.sum(axis=1)
-            reconf = np.zeros(N)
-            sum_t = t_part[:, 0]
-        else:
-            pid = np.zeros((N, n), np.int64)
-            pid[:, 1:] = np.cumsum(cb, axis=1)
-            nparts = pid[:, -1] + 1
-            part_valid = np.arange(n)[None, :] < nparts[:, None]
-            flat = (np.arange(N)[:, None] * n + pid)
-
-            def seg_sum(vals: np.ndarray) -> np.ndarray:
-                out = np.zeros(N * n)
-                np.add.at(out, flat.ravel(), vals.ravel())
-                return out.reshape(N, n)
-
-            def seg_max(vals: np.ndarray) -> np.ndarray:
-                out = np.full(N * n, -np.inf)
-                np.maximum.at(out, flat.ravel(), vals.ravel())
-                return out.reshape(N, n)
-
-            if self.exec_model == "streaming":
-                t_base = np.where(part_valid, seg_max(node_time), 0.0)
-            else:
-                t_base = seg_sum(node_time)
-
-            t_part = t_base
-            if not self.inter_matching:
-                # resharding collectives at intra-partition layout changes
-                edge_t = np.where(~cb & mism,
-                                  self.reshard_full[:-1] / plat.ici_bw, 0.0)
-                reshard = np.zeros(N * n)
-                np.add.at(reshard, flat[:, :-1].ravel(), edge_t.ravel())
-                t_part = t_part + reshard.reshape(N, n)
-            t_part = np.where(part_valid, t_part, 0.0)
-
-            # reconfiguration (Eq. 3): first configuration is pre-loaded
-            w_part = seg_sum(w_per_chip)
-            t_conf_part = plat.reconf_fixed_s + w_part / plat.dma_bw
-            later = part_valid & (np.arange(n)[None, :] >= 1)
-            reconf = np.where(later, t_conf_part, 0.0).sum(axis=1)
-
-            sum_t = t_part.sum(axis=1)
-        latency = sum_t + reconf
-        Bam = self.batch_amortisation
-        thr_time = Bam * sum_t + reconf
-        throughput = np.where(thr_time > 0, Bam / np.where(thr_time > 0,
-                                                           thr_time, 1.0), 0.0)
-        obj = latency if self.objective == "latency" else -throughput
-
-        # ---------------- constraints ----------------------------------
-        bad = np.zeros(N, bool)
-        # channel factor (Eq. 8) + cut legality + mesh realisability
-        if any_cut:
-            bad |= (cb & ~self.cut_allowed[None, :]).any(axis=1)
-        bad |= (self.rows % si != 0).any(axis=1)
-        bad |= (self.col_div % so != 0).any(axis=1)
-        bad |= (self.batch % kk != 0).any(axis=1)
-        if self.strict_kv:
-            bad |= ((self.kv_limit > 0) & (so > self.kv_limit)).any(axis=1)
-        bad |= ~self._realizable(si, so, kk).all(axis=1)
-        # intra matching (Eq. 9)
-        if self.intra_matching:
-            bad |= (self.elementwise & (si != so)).any(axis=1)
-        # inter matching (Eq. 10), partition-local
-        if self.inter_matching and n > 1:
-            bad |= ((~cb & mism).any(axis=1) if any_cut else mism.any(axis=1))
-        # scan tying, partition-local
-        if self.scan_tying and len(self.scan_pairs):
-            a = self.scan_pairs[:, 0]
-            b = self.scan_pairs[:, 1]
-            differ = (si[:, a] != si[:, b]) | (so[:, a] != so[:, b]) \
-                | (kk[:, a] != kk[:, b])
-            if any_cut:
-                differ &= pid[:, a] == pid[:, b]
-            bad |= differ.any(axis=1)
-        # resource (Eq. 6) + streaming chip budget + bandwidth (Eq. 7)
-        if not any_cut:
-            bad |= resident.sum(axis=1) > plat.hbm_bytes
-            if self.exec_model == "streaming":
-                bad |= c.sum(axis=1) > plat.chips
-            # single partition: no boundary staging, bandwidth never binds
-        else:
-            res_part = seg_sum(resident)
-            multi = nparts > 1
-            start = np.zeros((N, n), bool)
-            start[:, 0] = True
-            start[:, 1:] = cb
-            end = np.zeros((N, n), bool)
-            end[:, -1] = True
-            end[:, :-1] = cb
-            d_io = seg_sum(self.node_d[None, :] * (start.astype(np.float64)
-                                                   + end.astype(np.float64)))
-            res_tot = res_part \
-                + np.where(multi[:, None], d_io / plat.chips, 0.0)
-            bad |= (part_valid & (res_tot > plat.hbm_bytes)).any(axis=1)
-            if self.exec_model == "streaming":
-                chips_part = seg_sum(c)
-                bad |= (part_valid & (chips_part > plat.chips)).any(axis=1)
-            # bandwidth uses the pre-resharding partition interval, exactly
-            # like constraints.check_bandwidth
-            bw = plat.hbm_bw * plat.chips
-            bw_bad = multi[:, None] & part_valid & (t_base > 0) \
-                & (d_io / np.where(t_base > 0, t_base, 1.0) > bw)
-            bad |= bw_bad.any(axis=1)
-
+        A = self.arrays
+        out = eval_core(
+            self.static, A, si, so, kk, cb, single_partition=not cb.any(),
+            xp=np, seg=NumpySegments,
+            realizable=None if A.real_table is not None else self._realizable)
         return BatchResult(
-            objective=obj, feasible=~bad, latency=latency,
-            throughput=throughput, part_times=t_part, nparts=nparts,
-            reconf_time=reconf, node_resident=resident, node_times=node_time,
-            node_collective=coll)
-
-    # ------------------------------------------------------------------
-    def _collective_bytes(self, si, so, kk, sif, sof, kkf, b_in
-                          ) -> np.ndarray:
-        """Vectorised perfmodel._collective_bytes."""
-        mode, opts = self.mode, self.opts
-        train = mode == "train"
-        train_mult = 2.0 if train else 1.0
-        total = np.zeros_like(sif)
-
-        # The (s-1)/s ring fractions vanish at fold 1, so each term can be
-        # added unconditionally on its column slice: adding 0.0 is exact.
-        def frac(x):
-            return (x - 1.0) / x
-
-        def fm_shard(ix):
-            rows = self.rows[ix] if mode != "decode" else 1
-            return (self.batch[ix] * rows * self.fm_width[ix]) * BF16 \
-                / (b_in[:, ix] * kkf[:, ix])
-
-        if len(self.i_tp):
-            ix = self.i_tp
-            total[:, ix] += 2.0 * frac(sof[:, ix]) * fm_shard(ix) * train_mult
-        if len(self.i_ep):
-            ix = self.i_ep
-            rows = self.rows[ix] if mode != "decode" else 1
-            tokens_shard = (self.batch[ix] * rows) / (b_in[:, ix] * kkf[:, ix])
-            fanout = np.maximum(self.ep_topk[ix], 1)
-            total[:, ix] += (2.0 * tokens_shard * fanout * self.fm_width[ix]
-                             * BF16 * frac(sof[:, ix]) * train_mult)
-        if len(self.i_vocab):
-            ix = self.i_vocab
-            total[:, ix] += 2.0 * frac(sof[:, ix]) * fm_shard(ix) * train_mult
-        if len(self.i_vhead):
-            ix = self.i_vhead
-            if mode == "decode":
-                total[:, ix] += self.cols[ix] * BF16 * self.batch[ix] \
-                    / kkf[:, ix] * frac(sof[:, ix])
-            else:
-                # distributed softmax stats: constant in s_out, so the scalar
-                # path's s_out > 1 guard must be kept explicitly
-                rows = self.rows[ix]
-                vh = 2.0 * 8.0 * (self.batch[ix] * rows) \
-                    / (b_in[:, ix] * kkf[:, ix])
-                total[:, ix] += np.where(so[:, ix] > 1, vh, 0.0)
-
-        # sequence/context parallelism (s_in > 1): all terms carry the
-        # (s_in-1)/s_in factor, vanishing at s_in = 1
-        if len(self.i_int):
-            ix = self.i_int
-            kvl = self.kv_limit[ix]
-            kv_div = np.where(kvl > 0,
-                              np.minimum(sof[:, ix], kvl.astype(np.float64)),
-                              np.maximum(sof[:, ix], 1.0))
-            dh = np.where(self.latent_dim[ix] > 0, self.latent_dim[ix],
-                          self.fm_width[ix] / np.maximum(self.cols[ix], 1))
-            total[:, ix] += (self.batch[ix] / kkf[:, ix]) * self.cols[ix] \
-                / np.maximum(kv_div, 1.0) * (dh + 2.0) * 4.0 \
-                * frac(sif[:, ix])
-        if len(self.i_kv):
-            ix = self.i_kv
-            kvl = self.kv_limit[ix]
-            kv_div2 = np.where(self.latent_dim[ix] > 0, 1.0, np.where(
-                kvl > 0, np.minimum(sof[:, ix], kvl.astype(np.float64)),
-                np.maximum(sof[:, ix], 1.0))) * kkf[:, ix]
-            total[:, ix] += self.kv_bytes[ix] / kv_div2 * frac(sif[:, ix]) \
-                * train_mult
-        if len(self.i_carry):
-            ix = self.i_carry
-            total[:, ix] += self.carry_bytes[ix] / kkf[:, ix] \
-                * frac(sif[:, ix]) * train_mult
-
-        # data-parallel gradient all-reduce (per step, ring over k)
-        if train:
-            grad = self.weight_bytes / sof * 2.0 * opts.grad_compression
-            total += 2.0 * frac(kkf) * grad
-        return total
+            objective=out["objective"], feasible=out["feasible"],
+            latency=out["latency"], throughput=out["throughput"],
+            part_times=out["part_times"], nparts=out["nparts"],
+            reconf_time=out["reconf_time"],
+            node_resident=out["node_resident"],
+            node_times=out["node_times"],
+            node_collective=out["node_collective"])
 
 
 # ----------------------------------------------------------------------

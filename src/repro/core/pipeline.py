@@ -40,9 +40,8 @@ fold-realisability tables enter the program as device data
 (``core/accel/lowering.py``), so switching platforms, or mixing them in
 one ``optimise_portfolio`` call, reuses the cached XLA executable. It
 runs on whatever ``jax.default_backend()`` provides (CPU jit included;
-TPU/GPU when present — the partition-time segmented reduction can route
-through the Pallas kernel in ``core/accel/pallas_segred.py`` on TPU).
-Device arrays are float32 unless ``jax_enable_x64`` is on; the
+TPU/GPU when present). The numpy and jax engines evaluate with one array
+program (``core/array_program.py``). Device arrays are float32 unless ``jax_enable_x64`` is on; the
 scalar/numpy engines are float64 throughout. All engines agree on
 feasibility and the returned design; returned ``Evaluation`` objects are
 always re-derived through the float64 scalar reference.

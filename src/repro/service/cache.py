@@ -6,7 +6,7 @@ The key contract (docs/service.md) has two layers:
         program: StaticSpec (built through ``build_static_spec``, the
         same path that keys the XLA executable cache and that
         ``recompile_lint`` audits) plus every array ``lower_program``
-        ships to the device — per-node workloads, kind index sets,
+        ships to the device — per-node workloads, kind masks,
         platform scalars, fold-realisability cube, objective flag,
         amortisation factor.
   ``request_key``  sha256 over that fingerprint PLUS the optimiser

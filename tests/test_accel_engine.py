@@ -775,45 +775,6 @@ def test_repair_jax_clamps_strict_kv():
 
 
 # ----------------------------------------------------------------------
-# pallas segmented reduction (interpret mode on CPU)
-# ----------------------------------------------------------------------
-
-def test_pallas_segred_matches_numpy():
-    import jax.numpy as jnp
-    from repro.core.accel.pallas_segred import segmented_reduce
-
-    rng = np.random.default_rng(0)
-    N, n = 64, 7
-    vals = rng.random((N, n))
-    cuts = rng.random((N, n - 1)) < 0.3
-    pid = np.concatenate([np.zeros((N, 1), np.int64),
-                          np.cumsum(cuts, axis=1)], axis=1)
-    for op, red, ident in (("max", np.maximum, -np.inf), ("sum", np.add, 0.0)):
-        want = np.full((N, n), ident)
-        for r in range(N):
-            for j in range(n):
-                p = pid[r, j]
-                want[r, p] = red(want[r, p], vals[r, j])
-        got = segmented_reduce(jnp.asarray(vals, jnp.float32),
-                               jnp.asarray(pid), op, interpret=True)
-        np.testing.assert_allclose(np.asarray(got), want, rtol=1e-6)
-
-
-def test_pallas_eval_path_matches():
-    """The use_pallas partition-time route agrees with the jnp route."""
-    prob = _problem("tinyllama-1.1b", TRAIN)
-    designs = _random_designs(prob, 8, seed=6)
-    bev = prob.batched()
-    packed = bev.pack(designs)
-    rn = bev.evaluate_batch(*packed)
-    rp = JaxEvaluator(bev, use_pallas=True,
-                      pallas_interpret=True).evaluate_batch(*packed)
-    np.testing.assert_array_equal(rp.feasible, rn.feasible)
-    np.testing.assert_allclose(rp.part_times, rn.part_times,
-                               rtol=F32_RTOL, atol=1e-12)
-
-
-# ----------------------------------------------------------------------
 # engine registry
 # ----------------------------------------------------------------------
 

@@ -7,20 +7,23 @@ architecture, mode, backend and objective, over randomly sampled fold/cut
 designs. Also covers batched brute-force == scalar brute-force and
 multi-chain annealing determinism.
 """
+import itertools
 import random
 
 import numpy as np
 import pytest
 
-from repro.configs import ARCHS, get_arch, reduced
+from repro.configs import ARCHS, SHAPES_BY_NAME, get_arch, reduced
 from repro.configs.base import ShapeSpec
+from repro.core.array_program import _realizable, eval_core
 from repro.core.backends import BACKENDS
+from repro.core.batched_eval import NumpySegments
 from repro.core.graph_builder import build_hdgraph
 from repro.core.hdgraph import Variables
 from repro.core.objectives import Problem
 from repro.core.optimizers import brute_force, simulated_annealing
 from repro.core.perfmodel import ModelOptions
-from repro.core.platform import Platform
+from repro.core.platform import V5E_POD, Platform
 
 PLAT = Platform(name="t-4x4", mesh_axes=(("data", 4), ("model", 4)))
 
@@ -107,6 +110,75 @@ def test_batched_matches_scalar_moe_and_rwkv():
             prob = _problem(name, shape, backend="spmd",
                             objective="throughput")
             _assert_match(prob, _random_designs(prob, 25, seed=4))
+
+
+#: the benchmark's configurations at full depth, each at its cells' shapes
+BENCHMARK_GRAPHS = [("stablelm-3b", "train_4k"), ("stablelm-3b", "prefill_32k"),
+                    ("jamba-1.5-large-398b", "train_4k"),
+                    ("jamba-1.5-large-398b", "prefill_32k"),
+                    ("kimi-k2-1t-a32b", "prefill_32k"),
+                    ("kimi-k2-1t-a32b", "decode_32k")]
+
+
+@pytest.mark.parametrize("arch_name,shape_name", BENCHMARK_GRAPHS)
+def test_batched_matches_scalar_benchmark_graphs(arch_name, shape_name):
+    """The numpy engine at the benchmark's own sizes: the published
+    widths and depths on the V5E pod, random designs with cuts."""
+    graph = build_hdgraph(get_arch(arch_name), SHAPES_BY_NAME[shape_name])
+    prob = Problem(graph=graph, platform=V5E_POD, backend=BACKENDS["spmd"],
+                   objective="latency", exec_model="spmd")
+    designs = _random_designs(prob, 30, seed=9)
+    assert sum(bool(v.cuts) for v in designs) >= 5
+    _assert_match(prob, designs)
+
+
+def _program(bev, designs, single_partition, **kw):
+    si, so, kk, cb = bev.pack(designs)
+    return eval_core(bev.static, bev.arrays, si, so, kk, cb,
+                     single_partition, xp=np, seg=NumpySegments, **kw)
+
+
+def _assert_bitwise(a, b):
+    assert a.keys() == b.keys()
+    for key in a:
+        x, y = np.asarray(a[key]), np.asarray(b[key])
+        assert x.dtype == y.dtype and x.shape == y.shape, key
+        assert x.tobytes() == y.tobytes(), key
+
+
+@pytest.mark.parametrize("exec_model", ["streaming", "spmd"])
+@pytest.mark.parametrize("backend", sorted(BACKENDS))
+@pytest.mark.parametrize("shape", [TRAIN, PREFILL, DECODE],
+                         ids=lambda s: s.mode)
+def test_single_partition_path_is_bitwise_the_general_path(
+        shape, backend, exec_model):
+    """On a cut-free batch the numpy engine's one-partition shortcut
+    (``single_partition=True``, what ``evaluate_batch`` takes for such a
+    batch) gives the same bits as the general segmented path."""
+    prob = _problem("jamba-1.5-large-398b", shape, backend=backend,
+                    exec_model=exec_model)
+    designs = [v.with_cuts(()) for v in _random_designs(prob, 40, seed=10)]
+    bev = prob.batched()
+    _assert_bitwise(_program(bev, designs, True),
+                    _program(bev, designs, False))
+
+
+def test_memoised_realisability_matches_the_cube():
+    """The per-triple test the numpy engine takes for fold menus too large
+    for a cube gives the cube's answers, on every triple over the menu and
+    values off it (3, 5) or past it (32), and so the same evaluation."""
+    prob = _problem("tinyllama-1.1b", TRAIN)
+    bev = prob.batched()
+    vals = sorted(set(PLAT.fold_values()) | {3, 5, 32})
+    grid = np.array(list(itertools.product(vals, repeat=3)), np.int64)
+    si, so, kk = (grid[:, [i]] for i in range(3))
+    cube = _realizable(bev.static, bev.arrays, si, so, kk, xp=np)
+    assert cube.any() and not cube.all()
+    np.testing.assert_array_equal(bev._realizable(si, so, kk), cube)
+    designs = _random_designs(prob, 40, seed=11)
+    _assert_bitwise(_program(bev, designs, False),
+                    _program(bev, designs, False,
+                             realizable=bev._realizable))
 
 
 def test_batched_flags_illegal_cut():

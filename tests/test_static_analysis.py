@@ -121,6 +121,31 @@ def test_traced_python_branch_fires_exactly_once():
     assert "x" in vs[0].message
 
 
+def test_traced_python_branch_covers_the_array_program():
+    """The array program the numpy engine shares with the jitted programs
+    is traced code: the rule reads ``core/array_program.py`` as it reads
+    ``core/accel/``, and nothing else in ``core/``."""
+    src = """
+        def eval_core(static, A, si, so, kk, cb, single_partition=False,
+                      *, xp, seg, realizable=None):
+            if single_partition and realizable is None:
+                return A
+            if cb.any():
+                return si
+            return so
+    """
+    vs = ast_rules.check_traced_python_branch(
+        _tree(src), "repro/core/array_program.py")
+    assert [(v.where, v.line) for v in vs] == [
+        ("src/repro/core/array_program.py:eval_core", 6)]
+    assert "cb" in vs[0].message
+    assert ast_rules.check_traced_python_branch(
+        _tree(src), "repro/core/batched_eval.py") == []
+    # and it imports no jax: the no-jax matrix holds it
+    assert len(ast_rules.check_eager_jax_import(
+        ast.parse("import jax\n"), "repro/core/array_program.py")) == 1
+
+
 def test_traced_python_branch_static_args_are_legal():
     src = """
         import functools, jax
@@ -239,14 +264,11 @@ def test_build_static_spec_matches_lower_program():
     build_static_spec, so checking one problem locks the contract."""
     if not jax_available():
         pytest.skip("lower_program requires jax")
-    import jax
-
     from repro.core.accel.lowering import lower_program
     p = recompile_lint.example_grid()[0]
     bev = p.batched()
     static, _ = lower_program(bev)
-    assert static == build_static_spec(
-        bev, pallas_interpret=jax.default_backend() != "tpu")
+    assert static == build_static_spec(bev)
 
 
 # ----------------------------------------------------------------------

@@ -2,7 +2,7 @@
 
 The TPU compiler is installed with jax: it compiles for a topology that is
 described rather than attached, and refuses what the chip would refuse
-(layouts, memory, Mosaic kernels) at no chip time. Each test compiles one
+(layouts, memory) at no chip time. Each test compiles one
 jitted entry point exactly as the engine dispatches it — the arguments are
 captured at the engine's own call site and replaced by shapes placed on a
 described v5e chip — at real node counts: tinyllama-1.1b train_4k (47
@@ -14,13 +14,11 @@ at a time may load the TPU library, and every xdist worker imports this
 file. Keep every such compile in this one file, so that one worker loads
 the library.
 """
-import functools
 
 import numpy as np
 import pytest
 
 jax = pytest.importorskip("jax")
-import jax.numpy as jnp  # noqa: E402
 from jax.sharding import (  # noqa: E402
     Mesh,
     NamedSharding,
@@ -31,7 +29,6 @@ from jax.sharding import (  # noqa: E402
 from repro.configs import SHAPES_BY_NAME, get_arch  # noqa: E402
 from repro.core.accel import eval_jax, search_loops  # noqa: E402
 from repro.core.accel.eval_jax import JaxEvaluator  # noqa: E402
-from repro.core.accel.pallas_segred import segmented_reduce  # noqa: E402
 from repro.core.optimizers import OPTIMIZERS  # noqa: E402
 from repro.core.pipeline import make_problem  # noqa: E402
 
@@ -143,18 +140,6 @@ def test_rb_descend_compiles_for_v5e(arch, problems, one_chip,
     args = _capture(monkeypatch, "_rb_descend", lambda: OPTIMIZERS[
         "rule_based"](problems[arch], engine="jax"))
     _compile(search_loops._rb_descend, _on(one_chip, 2, args))
-
-
-def test_pallas_segmented_reduce_compiles_for_v5e(problems, one_chip,
-                                                  no_persistent_cache):
-    """Mosaic accepts the kernel at a real chunk: (4096, 47) float32."""
-    n = len(problems["tinyllama-1.1b"].graph.nodes)
-    assert n == 47
-    fn = jax.jit(functools.partial(segmented_reduce, op="max"))
-    compiled = _compile(fn, (
-        jax.ShapeDtypeStruct((BATCH, n), jnp.float32, sharding=one_chip),
-        jax.ShapeDtypeStruct((BATCH, n), jnp.int32, sharding=one_chip)))
-    assert "tpu_custom_call" in compiled.as_text()
 
 
 def test_bf_chunk_shard_compiles_for_v5e_2x2(topo, problems,
