@@ -674,6 +674,14 @@ def build_sa_tables(problem, *, pad_nodes: Optional[int] = None,
     divisor walk-down is pure node arithmetic, so the extra entries are
     exact (and unreachable: this problem's menus never draw them), which
     lets heterogeneous-platform buckets stack their clamp tables.
+
+    ``clamp[var, node, v]`` is the largest divisor of the node's dimension
+    for ``var`` that is at most ``v`` (0 at ``v = 0``; padded nodes are all
+    ones). It is computed once per distinct dimension of the graph — a few
+    against 3 x n rows — as a divisibility mask over ``v = 0..max_val``
+    followed by a running maximum, then gathered back to the rows; the
+    counter ``accel.tables.clamp_dims`` adds the number of distinct
+    dimensions each build computes.
     """
     graph, backend, platform = \
         problem.graph, problem.backend, problem.platform
@@ -701,16 +709,18 @@ def build_sa_tables(problem, *, pad_nodes: Optional[int] = None,
     for (vi, j), cands in menu_lists.items():
         menus[vi, j, :len(cands)] = cands
         menu_sizes[vi, j] = len(cands)
-    # clamp[var, node, v] = set_fold's divisor walk-down of value v
+    # clamp[var, node, v] = set_fold's divisor walk-down of value v, one
+    # row per distinct dimension: keep v where it divides, then a running
+    # maximum along the value axis
+    dims = np.array([[getattr(node, _DIMS[var]) for node in graph.nodes]
+                     for var in VARS], np.int64)
+    uniq, inv = np.unique(dims, return_inverse=True)
+    _metrics.counter("accel.tables.clamp_dims").inc(len(uniq))
+    vals = np.arange(max_val + 1, dtype=np.int64)
+    divides = uniq[:, None] % np.maximum(vals, 1)[None, :] == 0
+    rows = np.maximum.accumulate(np.where(divides, vals, 0), axis=1)
     clamp = np.ones((3, n_pad, max_val + 1), np.int64)
-    for vi, var in enumerate(VARS):
-        for j in range(n):
-            dim = getattr(graph.nodes[j], _DIMS[var])
-            for v in range(max_val + 1):
-                val = v
-                while val > 1 and dim % val != 0:
-                    val -= 1
-                clamp[vi, j, v] = val
+    clamp[:, :n] = rows[inv.reshape(3, n)]
     # kv_fix[j]: largest s_out menu value within the node's KV limit — the
     # on-device repair target for strict-KV violations (see repair_jax)
     kv_fix = np.ones(n_pad, np.int64)
