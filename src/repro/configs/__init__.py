@@ -20,6 +20,7 @@ from repro.configs.qwen2_vl_72b import CONFIG as _qwen2vl
 from repro.configs.whisper_small import CONFIG as _whisper
 from repro.configs.kimi_k2_1t_a32b import CONFIG as _kimi
 from repro.configs.granite_moe_1b_a400m import CONFIG as _granite
+from repro.configs.deepseek_v3_2_671b_a37b import CONFIG as _deepseek32
 
 ARCHS = {
     c.name: c
@@ -34,6 +35,7 @@ ARCHS = {
         _whisper,
         _kimi,
         _granite,
+        _deepseek32,
     )
 }
 

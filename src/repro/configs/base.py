@@ -29,7 +29,7 @@ class ArchConfig:
     num_experts: int = 0           # 0 => dense FFN
     experts_per_token: int = 0
     moe_period: int = 1            # every `moe_period`-th FFN is MoE (jamba: 2)
-    first_layer_dense: bool = False  # kimi-k2 style: layer 0 dense FFN
+    first_k_dense: int = 0         # leading dense-FFN layers (kimi 1, v3.2 3)
     moe_d_ff: int = 0              # routed-expert width (0 => d_ff)
     n_shared_experts: int = 0      # always-on experts beside the routed ones
 
@@ -39,6 +39,12 @@ class ArchConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+
+    # --- DeepSeek sparse attention (deepseek-v3.2): a lightning indexer
+    # picks ``index_topk`` cache positions a query; 0 => dense latent ---
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
 
     # --- hybrid (jamba): one attention layer per `attn_period` layers ---
     attn_period: int = 1           # 1 => all layers attention; 8 => 1:7 attn:mamba
@@ -83,6 +89,10 @@ class ArchConfig:
         return self.kv_lora_rank > 0
 
     @property
+    def is_dsa(self) -> bool:
+        return self.is_mla and self.index_topk > 0
+
+    @property
     def is_attention_free(self) -> bool:
         return self.family == "ssm"
 
@@ -92,11 +102,12 @@ class ArchConfig:
         return self.family in ("ssm", "hybrid")
 
     def layer_kind(self, i: int) -> str:
-        """Sequence-mixer kind of layer i: 'attn' | 'mla' | 'ssm' | 'rwkv'."""
+        """Sequence-mixer kind of layer i: 'attn' | 'mla' | 'dsa' | 'ssm' |
+        'rwkv'."""
         if self.family == "ssm":
             return "rwkv"
         if self.is_mla:
-            return "mla"
+            return "dsa" if self.is_dsa else "mla"
         if self.attn_period > 1:
             # jamba: one attention layer per attn_period block (position
             # attn_period-1 inside each block), rest mamba.
@@ -107,7 +118,7 @@ class ArchConfig:
         """Channel-mixer kind of layer i: 'moe' | 'ffn'."""
         if not self.is_moe:
             return "ffn"
-        if self.first_layer_dense and i == 0:
+        if i < self.first_k_dense:
             return "ffn"
         return "moe" if (i % self.moe_period) == (self.moe_period - 1) else "ffn"
 
@@ -153,6 +164,8 @@ class ArchConfig:
             return D * (H * dh) + 2 * D * (Hkv * dh) + (H * dh) * D
         if kind == "mla":
             return self.mla_weights()
+        if kind == "dsa":
+            return self.mla_weights() + self.indexer_weights()
         if kind == "ssm":
             di, ds = self.ssm_expand * self.d_model, self.ssm_d_state
             dt_rank = max(1, self.d_model // 16)
@@ -173,6 +186,16 @@ class ArchConfig:
         dn, dr, dv = self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim
         return (D * qr + qr + qr * H * (dn + dr) + D * (kvr + dr) + kvr
                 + kvr * H * (dn + dv) + H * dv * D)
+
+    def indexer_weights(self) -> int:
+        """Lightning-indexer parameters of one DSA layer: the query
+        projection from the q latent (``q_lora_rank -> index_n_heads x
+        index_head_dim``), the key projection (one ``index_head_dim``
+        vector a token for all indexer heads) with its LayerNorm (scale
+        and bias), and the per-head weights (``d_model -> index_n_heads``)."""
+        D, qr = self.d_model, self.q_lora_rank
+        HI, dI = self.index_n_heads, self.index_head_dim
+        return qr * HI * dI + D * dI + 2 * dI + D * HI
 
 
 @dataclass(frozen=True)
@@ -209,7 +232,9 @@ def shape_applicable(arch: ArchConfig, shape: ShapeSpec) -> bool:
 def reduced(arch: ArchConfig, **overrides) -> ArchConfig:
     """A tiny same-family config for CPU smoke tests."""
     small = dict(
-        num_layers=min(arch.num_layers, 4 if arch.attn_period <= 1 else arch.attn_period),
+        # at least four layers: the leading dense ones and two after them
+        num_layers=min(arch.num_layers, max(4, arch.first_k_dense + 2)
+                       if arch.attn_period <= 1 else arch.attn_period),
         d_model=128,
         num_heads=4,
         num_kv_heads=min(arch.num_kv_heads, 2) if arch.num_kv_heads < arch.num_heads else 4,
@@ -228,5 +253,7 @@ def reduced(arch: ArchConfig, **overrides) -> ArchConfig:
     if arch.is_mla:
         small.update(q_lora_rank=48, kv_lora_rank=32, qk_nope_head_dim=16,
                      qk_rope_head_dim=8, v_head_dim=16)
+    if arch.is_dsa:
+        small.update(index_n_heads=8, index_head_dim=16, index_topk=8)
     small.update(overrides)
     return dataclasses.replace(arch, **small)

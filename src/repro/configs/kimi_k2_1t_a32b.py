@@ -18,7 +18,7 @@ CONFIG = ArchConfig(
     vocab_size=163840,
     num_experts=384,
     experts_per_token=8,
-    first_layer_dense=True,
+    first_k_dense=1,
     moe_d_ff=2048,                 # moe_intermediate_size
     n_shared_experts=1,
     q_lora_rank=1536,

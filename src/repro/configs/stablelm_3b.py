@@ -1,4 +1,5 @@
-"""stablelm-3b — [hf:stabilityai/stablelm-2-1_6b; unverified]."""
+"""stablelm-3b — StableLM-3B-4E1T: a dense decoder with LayerNorm, 32 layers
+[https://huggingface.co/stabilityai/stablelm-3b-4e1t/blob/main/config.json]."""
 from repro.configs.base import ArchConfig
 
 CONFIG = ArchConfig(

@@ -131,11 +131,11 @@ class JaxEvaluator:
     """
 
     def __init__(self, bev, *, pad_nodes=None, pad_pairs=None,
-                 pad_vals=None, pad_lut=None):
+                 pad_vals=None, pad_lut=None, pad_dsa=False):
         self.bev = bev
         self.static, self.arrays = lower_program(
             bev, pad_nodes=pad_nodes, pad_pairs=pad_pairs,
-            pad_vals=pad_vals, pad_lut=pad_lut)
+            pad_vals=pad_vals, pad_lut=pad_lut, pad_dsa=pad_dsa)
         self.n_pad = self.static.n_nodes
 
     @classmethod

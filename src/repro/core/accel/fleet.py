@@ -59,7 +59,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.accel.eval_jax import JaxEvaluator
-from repro.core.accel.lowering import StaticSpec
+from repro.core.accel.lowering import StaticSpec, any_dsa
 from repro.core.accel.search_loops import (
     TRACE_COUNTS,
     DeviceRuleBased,
@@ -475,7 +475,9 @@ def fleet_brute_force(problems: Sequence, include_cuts: bool = False,
         jevs = [JaxEvaluator.from_problem(m.problem, pad_nodes=n_pad,
                                           pad_pairs=pairs_pad,
                                           pad_vals=vals_pad,
-                                          pad_lut=lut_pad)
+                                          pad_lut=lut_pad,
+                                          pad_dsa=any_dsa(
+                                              m.problem for m in members))
                 for m in members]
         static = jevs[0].static
         assert all(j.static == static for j in jevs), \
@@ -640,8 +642,9 @@ def fleet_annealing(problems: Sequence, seed: int = 0,
         bucket_sp.__enter__()
         members = [problems[i] for i in idxs]
         n_pad, pairs_pad, vals_pad, lut_pad, tabs = _bucket_tables(members)
+        dsa = any_dsa(members)
         sas = [DeviceSA(p, pad_nodes=n_pad, pad_pairs=pairs_pad,
-                        pad_vals=vals_pad, pad_lut=lut_pad,
+                        pad_vals=vals_pad, pad_lut=lut_pad, pad_dsa=dsa,
                         tables=t) for p, t in zip(members, tabs)]
         static = sas[0].static
         assert all(s.static == static and s.gran == sas[0].gran
@@ -825,9 +828,11 @@ def fleet_rule_based(problems: Sequence,
         P_pad = _pad_lanes(P, D)
         pad = P_pad - P
         n_pad, pairs_pad, vals_pad, lut_pad, tabs = _bucket_tables(members)
+        dsa = any_dsa(members)
         rbs = [DeviceRuleBased(p, pad_nodes=n_pad, pad_pairs=pairs_pad,
                                pad_vals=vals_pad, pad_lut=lut_pad,
-                               tables=t) for p, t in zip(members, tabs)]
+                               pad_dsa=dsa, tables=t)
+               for p, t in zip(members, tabs)]
         static = rbs[0].static
         assert all(r.static == static and r.gran == rbs[0].gran
                    for r in rbs), \

@@ -92,6 +92,9 @@ class StaticSpec:
     grad_compression: float
     mxu_efficiency: float
     overlap_collectives: float
+    # the sparse-attention terms (a graph with ``dsa`` nodes, or a fleet
+    # stack holding one): off, the program traces none of them
+    dsa: bool = False
 
     @property
     def train(self) -> bool:
@@ -169,6 +172,24 @@ class DeviceArrays(NamedTuple):
     # padding bookkeeping
     node_valid: "jax.Array"         # [n] bool; False on padded columns
     n_valid: "jax.Array"            # scalar: count of real nodes
+    # sparse attention (``StaticSpec.dsa``); None where the spec has it
+    # off, so a graph without ``dsa`` nodes carries no such leaves
+    m_dsa: "jax.Array" = None
+    indexer_flops: "jax.Array" = None
+    indexer_weight_bytes: "jax.Array" = None
+    state_io_bytes: "jax.Array" = None
+    index_topk: "jax.Array" = None
+
+
+def has_dsa(bev) -> bool:
+    """True when the lowered graph has sparse-attention nodes."""
+    return len(bev.i_dsa) > 0
+
+
+def any_dsa(problems) -> bool:
+    """True when any problem's graph has sparse-attention nodes: a fleet
+    stack that holds one lowers every member with the terms on."""
+    return any(any(n.sparse for n in p.graph.nodes) for p in problems)
 
 
 def realizability_table(platform
@@ -248,12 +269,25 @@ def host_arrays(bev) -> DeviceArrays:
         m_latent=_mask(bev.i_latent, n),
         pair_a=i(bev.scan_pairs[:, 0]), pair_b=i(bev.scan_pairs[:, 1]),
         node_valid=np.ones(n, bool), n_valid=np.asarray(n, np.int64),
+        **(_dsa_arrays(bev) if has_dsa(bev) else {}),
     )
 
 
+def _dsa_arrays(bev) -> dict:
+    """The sparse-attention leaves of the host record: all zero (and
+    changing nothing) for a graph without ``dsa`` nodes."""
+    n = bev.n_nodes
+    return {"m_dsa": _mask(bev.i_dsa, n),
+            "indexer_flops": np.asarray(bev.indexer_flops, np.float64),
+            "indexer_weight_bytes": np.asarray(bev.indexer_weight_bytes,
+                                               np.float64),
+            "state_io_bytes": np.asarray(bev.state_io_bytes, np.float64),
+            "index_topk": np.asarray(bev.index_topk, np.int64)}
+
+
 @_trace.traced("accel.build_static_spec")
-def build_static_spec(bev, *, pad_nodes: Optional[int] = None
-                      ) -> StaticSpec:
+def build_static_spec(bev, *, pad_nodes: Optional[int] = None,
+                      pad_dsa: bool = False) -> StaticSpec:
     """Pure-host construction of the trace-shaping spec (no jax needed).
 
     This is the static-analysis hook: ``repro.analysis.recompile_lint``
@@ -262,6 +296,8 @@ def build_static_spec(bev, *, pad_nodes: Optional[int] = None
     across the grid, i.e. data that should have been a ``DeviceArrays``
     leaf. ``lower_program`` routes through here so the linted spec and
     the spec that actually keys the XLA executable cache can never drift.
+    ``pad_dsa`` turns the sparse-attention terms on for a graph without
+    ``dsa`` nodes (a fleet stack that holds one).
     """
     n = bev.n_nodes
     np_ = n if pad_nodes is None else int(pad_nodes)
@@ -281,6 +317,7 @@ def build_static_spec(bev, *, pad_nodes: Optional[int] = None
         grad_compression=opts.grad_compression,
         mxu_efficiency=opts.mxu_efficiency,
         overlap_collectives=opts.overlap_collectives,
+        dsa=has_dsa(bev) or bool(pad_dsa),
     )
 
 
@@ -343,7 +380,8 @@ def _pad1(a: np.ndarray, n_pad: int, fill) -> np.ndarray:
 def lower_program(bev, *, pad_nodes: Optional[int] = None,
                   pad_pairs: Optional[int] = None,
                   pad_vals: Optional[int] = None,
-                  pad_lut: Optional[int] = None
+                  pad_lut: Optional[int] = None,
+                  pad_dsa: bool = False
                   ) -> Tuple[StaticSpec, DeviceArrays]:
     """The host record (``bev.arrays``) padded and moved onto the default
     jax device at its dtype (float32/int32, or float64/int64 under x64).
@@ -357,6 +395,8 @@ def lower_program(bev, *, pad_nodes: Optional[int] = None,
     "unknown value" and unknown values were already infeasible), so
     problems on *different platforms* — whose fold menus differ in size —
     can also share one StaticSpec and hence one executable.
+    ``pad_dsa`` gives a graph without ``dsa`` nodes zero sparse-attention
+    leaves and the spec's terms, so it stacks with one that has them.
     """
     jax = require_jax()
     import jax.numpy as jnp
@@ -373,8 +413,10 @@ def lower_program(bev, *, pad_nodes: Optional[int] = None,
             f"engine needs a dense realisability table (<= "
             f"{MAX_TABLE_VALUES} values) or an AbstractPlatform product "
             f"rule. Use engine='numpy' for this platform.")
-    static = build_static_spec(bev, pad_nodes=pad_nodes)
+    static = build_static_spec(bev, pad_nodes=pad_nodes, pad_dsa=pad_dsa)
     np_ = static.n_nodes
+    if static.dsa and host.m_dsa is None:
+        host = host._replace(**_dsa_arrays(bev))
 
     def pad_to(given, have: int, what: str) -> int:
         want = have if given is None else int(given)
@@ -394,6 +436,9 @@ def lower_program(bev, *, pad_nodes: Optional[int] = None,
 
     out = {}
     for name, a in host._asdict().items():
+        if a is None:
+            out[name] = None
+            continue
         if name == "real_table":
             a = table
         elif a.ndim == 1:

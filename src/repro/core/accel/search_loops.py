@@ -76,6 +76,7 @@ from repro.core.accel.eval_jax import (
 from repro.obs import metrics as _metrics
 from repro.obs import trace as _trace
 from repro.core.accel.lowering import DeviceArrays, StaticSpec
+from repro.core.array_program import weight_per_chip
 from repro.core.hdgraph import Variables
 from repro.core.optimizers.common import OptimResult
 
@@ -749,12 +750,14 @@ class DeviceSA:
                  pad_menu: Optional[int] = None,
                  pad_pairs: Optional[int] = None,
                  pad_vals: Optional[int] = None,
-                 pad_lut: Optional[int] = None, tables=None):
+                 pad_lut: Optional[int] = None, pad_dsa: bool = False,
+                 tables=None):
         self.problem = problem
         self.jev = JaxEvaluator.from_problem(problem, pad_nodes=pad_nodes,
                                              pad_pairs=pad_pairs,
                                              pad_vals=pad_vals,
-                                             pad_lut=pad_lut)
+                                             pad_lut=pad_lut,
+                                             pad_dsa=pad_dsa)
         self.static, self.A = self.jev.static, self.jev.arrays
         self.n_real = len(problem.graph.nodes)
         idt = np.int64 if self.A.batch.dtype == jnp.int64 else np.int32
@@ -1057,7 +1060,11 @@ def _rb_step(static: StaticSpec, gran: Tuple[str, str, str],
     else:
         d_t, m_t = delta(jnp.take(res["part_times"], pidx, axis=1)[:, None],
                          jnp.ones((1,), bool))
-    d_w, m_w = delta(A.weight_bytes[None, :] / SO.astype(fdt), part_mask)
+    if static.dsa:
+        w_chip = weight_per_chip(static, A, SO.astype(fdt))
+    else:                     # the parent's trace, op for op
+        w_chip = A.weight_bytes[None, :] / SO.astype(fdt)
+    d_w, m_w = delta(w_chip, part_mask)
     later = pidx > 0                                   # + t_conf(part)
     zero = jnp.zeros((), fdt)
     d_cost = d_t + jnp.where(later, amort * (d_w / A.dma_bw), zero)
@@ -1174,12 +1181,14 @@ class DeviceRuleBased:
                  pad_menu: Optional[int] = None,
                  pad_pairs: Optional[int] = None,
                  pad_vals: Optional[int] = None,
-                 pad_lut: Optional[int] = None, tables=None):
+                 pad_lut: Optional[int] = None, pad_dsa: bool = False,
+                 tables=None):
         self.problem = problem
         self.jev = JaxEvaluator.from_problem(problem, pad_nodes=pad_nodes,
                                              pad_pairs=pad_pairs,
                                              pad_vals=pad_vals,
-                                             pad_lut=pad_lut)
+                                             pad_lut=pad_lut,
+                                             pad_dsa=pad_dsa)
         self.static, self.A = self.jev.static, self.jev.arrays
         self.n_real = len(problem.graph.nodes)
         idt = np.int64 if self.A.batch.dtype == jnp.int64 else np.int32

@@ -49,6 +49,16 @@ def _madd(total, mask, term, *, xp):
     return total + xp.where(mask[None, :], term, xp.zeros_like(term))
 
 
+def weight_per_chip(static, A, sof):
+    """Weight bytes a chip holds of each node (``perfmodel.weight_per_chip``):
+    the head fold divides them, except a sparse-attention node's indexer
+    weights, whole on every head shard (0 on other columns: exact)."""
+    if not static.dsa:
+        return A.weight_bytes / sof
+    return (A.weight_bytes - A.indexer_weight_bytes) / sof \
+        + A.indexer_weight_bytes
+
+
 def _collective_bytes(static, A, si, so, kk, sif, sof, kkf, b_in, *, xp):
     """Per-chip collective bytes of every node (perfmodel._collective_bytes,
     mask-driven)."""
@@ -97,6 +107,13 @@ def _collective_bytes(static, A, si, so, kk, sif, sof, kkf, b_in, *, xp):
                   (batchf[None, :] / kkf) * colsf[None, :]
                   / xp.maximum(kv_div, 1.0) * ((dh + 2.0) * 4.0)[None, :]
                   * _frac(sif), xp=xp)
+    if static.dsa and static.decode:
+        # sparse attention's split-KV top-K merge: an all-gather of
+        # (score, position) candidates (rule c)
+        total = _madd(total, A.m_dsa,
+                      batchf[None, :] / kkf
+                      * A.index_topk.astype(fdt)[None, :] * 8.0
+                      * (sif - 1.0), xp=xp)
     # the latent cache is whole on every head fold
     ring_div = xp.where(A.m_latent[None, :], xp.ones_like(kv_div), kv_div)
     total = _madd(total, A.m_kv,
@@ -108,7 +125,8 @@ def _collective_bytes(static, A, si, so, kk, sif, sof, kkf, b_in, *, xp):
 
     # data-parallel gradient all-reduce (per step, ring over k)
     if static.train:
-        grad = A.weight_bytes / sof * 2.0 * static.grad_compression
+        grad = weight_per_chip(static, A, sof) * 2.0 \
+            * static.grad_compression
         total = total + 2.0 * _frac(kkf) * grad
     return total
 
@@ -154,9 +172,16 @@ def eval_core(static, A, si, so, kk, cb, single_partition: bool = False,
     # ---------------- node roofline (perfmodel.node_eval) ----------
     c = sif * sof * kkf
     b_in = xp.where(A.internal[None, :], xp.ones((), fdt), sif)
-    compute_s = (A.flops / c) / (A.peak_flops * static.mxu_efficiency)
+    if static.dsa:
+        # the sparse-attention indexer is replicated over the head fold
+        # (rule a; 0 on other columns: exact)
+        flops_per_chip = (A.flops - A.indexer_flops) / c \
+            + A.indexer_flops / (sif * kkf)
+    else:
+        flops_per_chip = A.flops / c
+    compute_s = flops_per_chip / (A.peak_flops * static.mxu_efficiency)
 
-    w_per_chip = A.weight_bytes / sof
+    w_per_chip = weight_per_chip(static, A, sof)
     act_per_chip = A.act_bytes / (b_in * kkf)
     inner_per_chip = A.inner_bytes / c
 
@@ -182,7 +207,15 @@ def eval_core(static, A, si, so, kk, cb, single_partition: bool = False,
     else:
         hbm = hbm + xp.where(A.weight_stream, w_per_chip,
                              xp.zeros_like(w_per_chip))
-        hbm = hbm + state_per_chip
+        if static.dsa:
+            # sparse attention moves the caches written (prefill), the
+            # index keys and the selected latent rows read (decode)
+            # (rule b); it holds both whole caches (``state_per_chip``)
+            hbm = hbm + xp.where(
+                A.m_dsa[None, :],
+                A.state_io_bytes * state_repl / state_div, state_per_chip)
+        else:
+            hbm = hbm + state_per_chip
     memory_s = hbm / A.hbm_bw
 
     coll = _collective_bytes(static, A, si, so, kk, sif, sof, kkf, b_in,

@@ -144,6 +144,10 @@ class BatchedEvaluator:
         self.state_bytes = f("state_bytes")
         self.kv_bytes = f("kv_bytes")
         self.carry_bytes = f("carry_bytes")
+        self.indexer_flops = f("indexer_flops")
+        self.indexer_weight_bytes = f("indexer_weight_bytes")
+        self.state_io_bytes = f("state_io_bytes")
+        self.index_topk = i("index_topk")
         self.batch = i("batch")
         self.rows = i("rows")
         self.cols = i("cols")
@@ -178,6 +182,7 @@ class BatchedEvaluator:
         self.i_carry = w(~self.internal & (self.kv_bytes == 0)
                          & (self.carry_bytes > 0))
         self.i_latent = w(self.latent_dim > 0)
+        self.i_dsa = kind(("dsa",))
 
         # Boundary featuremap bytes (Eq. 7 convention: full rows, bf16).
         self.node_d = (self.batch * self.rows * self.fm_width).astype(

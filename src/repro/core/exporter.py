@@ -109,7 +109,7 @@ class ShardingPlan:
 
     def _boundary_kind(self, partition: int) -> KindPlan:
         part = self.partitions[partition]
-        for kind in ("attn", "mla", "ssm", "rwkv_tmix", "ffn",
+        for kind in ("attn", "mla", "dsa", "ssm", "rwkv_tmix", "ffn",
                      "shared_expert", "moe", "enc_attn"):
             if kind in part.kinds:
                 return part.kinds[kind]
@@ -147,11 +147,15 @@ class ShardingPlan:
         length over rows axes (split-KV), heads over cols axes (up to the
         GQA limit — legalisation already clamped). A latent-attention
         partition's cache is (batch, kv_len, latent): one vector per token
-        for all heads, so it never shards over the cols axes."""
+        for all heads, so it never shards over the cols axes. A sparse-
+        attention partition holds two such caches, the latent and the
+        index keys (batch, kv_len, index_head_dim), under this one spec."""
         P = _pspec()
-        if "mla" in self.partitions[partition].kinds:
-            kp = self.kind_plan("mla", partition)
-            return P(_axes(kp.batch_axes), _axes(kp.rows_axes), None)
+        kinds = self.partitions[partition].kinds
+        for kind in ("mla", "dsa"):
+            if kind in kinds:
+                kp = self.kind_plan(kind, partition)
+                return P(_axes(kp.batch_axes), _axes(kp.rows_axes), None)
         kp = self.kind_plan("attn", partition)
         return P(_axes(kp.batch_axes), _axes(kp.rows_axes),
                  _axes(kp.cols_axes), None)

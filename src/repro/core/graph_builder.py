@@ -37,6 +37,7 @@ _SCAN_GROUP = {
     "enc_ffn": 8,
     "mla": 9,
     "shared_expert": 10,
+    "dsa": 11,
 }
 
 
@@ -87,6 +88,8 @@ def build_hdgraph(arch: ArchConfig, shape: ShapeSpec) -> HDGraph:
                                         mode=mode, kind="cross_attn", causal=False))
         elif mixer == "mla":
             nodes.append(_mla_node(arch, f"l{i}.mla", i, B, S, L, tm, mode))
+        elif mixer == "dsa":
+            nodes.append(_dsa_node(arch, f"l{i}.dsa", i, B, S, L, tm, mode))
         elif mixer == "ssm":
             nodes.append(_ssm_node(arch, f"l{i}.ssm", i, B, S, tm, mode))
         elif mixer == "rwkv":
@@ -213,6 +216,110 @@ def _mla_node(arch: ArchConfig, name: str, layer: int, B: int, S: int,
         weight_stream=(mode != "train"),
         internal_rows=decode,
         fm_width=D,
+    )
+
+
+def sparse_selected(topk: int, S: int, L: int, decode: bool) -> int:
+    """Positions the sparse core attends to, summed over one sequence's
+    queries: min(K, L) for the one decode query; sum over t < S of
+    min(K, t + 1) in prefill, where query t sees positions s <= t."""
+    if decode:
+        return min(topk, L)
+    t = min(topk, S)
+    return t * (t + 1) // 2 + (S - t) * topk
+
+
+def _dsa_node(arch: ArchConfig, name: str, layer: int, B: int, S: int,
+              L: int, tm: float, mode: str) -> Node:
+    """DeepSeek sparse attention (DeepSeek-V3.2): latent attention over
+    the positions a lightning indexer selects.
+
+    Symbols: D d_model, H heads, qr / kvr the q / kv latent ranks, dn / dr
+    / dv the nope / rope / value head sizes, HI / dI the indexer's heads
+    and head size, K ``index_topk``; batch B, query rows S, context L.
+
+    Indexer: I(t, s) = sum_j w(t, j) * ReLU(qI(t, j) . kI(s)), with qI
+    from the query latent c_q (qr -> HI*dI), kI from h (D -> dI, one
+    LayerNorm'd vector a token for all indexer heads) and w from h
+    (D -> HI). Weights qr*HI*dI + D*dI + 2*dI + D*HI. FLOPs
+    2*B*S*(qr*HI*dI + D*dI + D*HI) + 2*B*HI*dI*P, with P = S(S+1)/2
+    score pairs in prefill and S*L in decode. The ReLU, the head
+    weighting and the top-k are elementwise or sorting work beside these
+    matrix products and are not counted.
+
+    Selection: for query t the top min(K, t+1) positions s <= t by
+    I(t, s), ties to the lower position. The int32 positions are written
+    by the indexer and read by the core: inner bytes.
+
+    Core: latent attention in the absorbed MQA form, prefill and decode
+    alike, over the selected positions only; each (kvr + dr)-wide latent
+    row serves all H heads. FLOPs 2*B*S*(D*qr + qr*H*(dn+dr) + D*(kvr+dr)
+    + H*dn*kvr + H*kvr*dv + H*dv*D) + 2*B*H*Ksel*(2*kvr + dr), Ksel
+    counted per sequence (``sparse_selected``).
+
+    Caches: the latent cache B*L*(kvr+dr)*2 bytes and the index-key cache
+    B*L*(dI+4) bytes (FP8 keys and one float32 scale a token). Both shard
+    over (k, s_I), never over the head fold (``latent_dim``).
+
+    The rules the cost model adds for this kind (``perfmodel.node_eval``,
+    ``array_program.eval_core``): a. the indexer is replicated over the
+    head fold, its FLOPs divide by s_I*k and its weights are whole on
+    every head shard (``indexer_flops``, ``indexer_weight_bytes``); b. a
+    decode step reads the index keys and the selected latent rows
+    B*min(K,L)*(kvr+dr)*2, both over (k, s_I), while both whole caches
+    stay resident; a prefill writes both (``state_io_bytes``); c. a
+    split-KV decode (s_I > 1) merges the top-K candidates with an
+    all-gather of (score, position) pairs, B/k * min(K,L) * 8 * (s_I-1)
+    bytes a chip, before the latent-width partial combine; a prefill
+    ring exchange (s_I > 1) carries the index keys beside the latent
+    cache (``kv_bytes``)."""
+    D, H = arch.d_model, arch.num_heads
+    qr, kvr = arch.q_lora_rank, arch.kv_lora_rank
+    dn, dr, dv = arch.qk_nope_head_dim, arch.qk_rope_head_dim, arch.v_head_dim
+    HI, dI, K = arch.index_n_heads, arch.index_head_dim, arch.index_topk
+    decode = mode == "decode"
+    pairs = S * L if decode else S * (S + 1) // 2
+    indexer = 2.0 * B * S * (qr * HI * dI + D * dI + D * HI) \
+        + 2.0 * B * HI * dI * pairs
+    ksel = sparse_selected(K, S, L, decode)
+    core = 2.0 * B * S * (D * qr + qr * H * (dn + dr) + D * (kvr + dr)
+                          + H * dn * kvr + H * kvr * dv + H * dv * D) \
+        + 2.0 * B * H * ksel * (2 * kvr + dr)
+    w_idx = arch.indexer_weights() * BF16
+    latent = B * L * (kvr + dr) * BF16
+    keys = B * L * (dI + FP32)
+    caches = latent + keys
+    if decode:
+        io = keys + B * min(K, L) * (kvr + dr) * BF16
+    else:
+        io = caches if mode != "train" else 0.0
+    return Node(
+        name=name, kind="dsa", layer=layer,
+        rows=L if decode else S,              # decode: split-KV folding dim
+        cols=H, batch=B,
+        flops=(core + indexer) * tm,
+        weight_bytes=arch.mla_weights() * BF16 + w_idx,
+        act_bytes=4.0 * B * S * D * BF16,
+        # the q and kv latents; per head the query, its latent form, the
+        # latent output and the output; the selected positions (int32,
+        # written and read)
+        inner_bytes=B * S * (qr + kvr + dr + H * (dn + dr + kvr + dr + kvr
+                                                  + dv)) * BF16
+        + 2.0 * B * ksel * 4.0,
+        state_bytes=caches if mode != "train" else 0.0,
+        kv_bytes=caches,
+        col_divisor=H,
+        latent_dim=kvr,
+        scan_group=_SCAN_GROUP["dsa"],
+        collective_kind="tp_allreduce",
+        train_multiplier=tm,
+        weight_stream=(mode != "train"),
+        internal_rows=decode,
+        fm_width=D,
+        indexer_flops=indexer * tm,
+        indexer_weight_bytes=w_idx,
+        state_io_bytes=io,
+        index_topk=min(K, L),
     )
 
 

@@ -22,7 +22,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 @dataclass(frozen=True)
 class Node:
     name: str
-    kind: str                 # embed|attn|mla|cross_attn|ffn|shared_expert|moe|ssm|rwkv_tmix|rwkv_cmix|norm|head
+    kind: str                 # embed|attn|mla|dsa|cross_attn|ffn|shared_expert|moe|ssm|rwkv_tmix|rwkv_cmix|norm|head
     layer: int                # layer index (-1 for embed/head/final norm)
     # Foldable dimensions.
     rows: int                 # sequence rows entering the node (or KV len in decode)
@@ -53,6 +53,16 @@ class Node:
     collective_kind: str = "none" # none|tp_allreduce|ep_alltoall|vocab_allreduce
     train_multiplier: float = 1.0 # 3.0 when backward pass included
     fm_width: int = 0             # featuremap channel width at the node boundary (d_model)
+    # DeepSeek sparse attention (kind "dsa"; zero on every other kind)
+    indexer_flops: float = 0.0    # the part of ``flops`` replicated over the
+                                  # head fold: folds by (k, s_I) only
+    indexer_weight_bytes: float = 0.0  # the part of ``weight_bytes`` whole
+                                  # on every head shard
+    state_io_bytes: float = 0.0   # state bytes a step moves: the caches
+                                  # written (prefill), the index keys and
+                                  # the selected latent rows read (decode)
+    index_topk: int = 0           # positions a decode query selects,
+                                  # min(index_topk, L): the split-KV merge
 
     @property
     def col_div(self) -> int:
@@ -61,6 +71,11 @@ class Node:
     @property
     def latent_kv(self) -> bool:
         return self.latent_dim > 0
+
+    @property
+    def sparse(self) -> bool:
+        """DeepSeek sparse attention: an indexer selects the positions."""
+        return self.kind == "dsa"
 
 
 @dataclass

@@ -89,6 +89,16 @@ def _state_sharding(node: Node, s_in: int, s_out: int, kern: int):
     return kern * s_out, 1.0
 
 
+def weight_per_chip(node: Node, s_out: int) -> float:
+    """A node's weight bytes on one chip. The head fold divides them; a
+    sparse-attention node's indexer weights stay whole on every head
+    shard (``graph_builder._dsa_node``, rule a)."""
+    if node.sparse:
+        return (node.weight_bytes - node.indexer_weight_bytes) / s_out \
+            + node.indexer_weight_bytes
+    return node.weight_bytes / s_out
+
+
 def node_eval(node: Node, s_in: int, s_out: int, kern: int,
               platform: Platform, mode: str,
               opts: ModelOptions = ModelOptions()) -> NodeEval:
@@ -96,11 +106,16 @@ def node_eval(node: Node, s_in: int, s_out: int, kern: int,
     b_in = 1 if node.internal_rows else s_in   # boundary-layout row fold
 
     # ---------------- compute term ----------------
-    flops_per_chip = node.flops / c
+    if node.sparse:
+        # the indexer is replicated over the head fold (rule a)
+        flops_per_chip = (node.flops - node.indexer_flops) / c \
+            + node.indexer_flops / (s_in * kern)
+    else:
+        flops_per_chip = node.flops / c
     compute_s = flops_per_chip / (platform.peak_flops * opts.mxu_efficiency)
 
     # ---------------- memory term ----------------
-    w_per_chip = node.weight_bytes / s_out
+    w_per_chip = weight_per_chip(node, s_out)
     act_per_chip = node.act_bytes / (b_in * kern)
     inner_per_chip = node.inner_bytes / c
     state_div, state_repl = _state_sharding(node, s_in, s_out, kern)
@@ -114,7 +129,12 @@ def node_eval(node: Node, s_in: int, s_out: int, kern: int,
     else:
         if node.weight_stream:
             hbm_bytes += w_per_chip
-        hbm_bytes += state_per_chip        # KV/state read (decode) or write (prefill)
+        if node.sparse:
+            # the caches written (prefill); the index keys and the
+            # selected latent rows read (decode) (rule b)
+            hbm_bytes += node.state_io_bytes * state_repl / state_div
+        else:
+            hbm_bytes += state_per_chip    # KV/state read (decode) or write (prefill)
     memory_s = hbm_bytes / platform.hbm_bw
 
     # ---------------- collective term ----------------
@@ -208,6 +228,11 @@ def _collective_bytes(node: Node, s_in: int, s_out: int, kern: int,
                 else node.fm_width / max(node.cols, 1)
             total += (node.batch / kern) * node.cols / max(kv_div, 1) \
                 * (dh + 2.0) * 4.0 * (s_in - 1) / s_in
+            if node.sparse:
+                # the global top-K: an all-gather of every chip's
+                # (score, position) candidates (rule c)
+                total += node.batch / kern * node.index_topk * 8.0 \
+                    * (s_in - 1)
         elif node.kv_bytes:
             # the latent cache is whole on every head fold
             kv_div = (1 if node.latent_kv
@@ -219,7 +244,8 @@ def _collective_bytes(node: Node, s_in: int, s_out: int, kern: int,
 
     # data-parallel gradient all-reduce (per step, ring over k)
     if mode == "train" and kern > 1 and node.weight_bytes:
-        grad_bytes = node.weight_bytes / s_out * 2.0 * opts.grad_compression
+        grad_bytes = weight_per_chip(node, s_out) * 2.0 \
+            * opts.grad_compression
         total += 2.0 * (kern - 1) / kern * grad_bytes
 
     return total
@@ -249,7 +275,7 @@ def partition_weight_bytes_per_chip(graph: HDGraph, part: Sequence[int],
                                     variables: Variables) -> float:
     total = 0.0
     for i in part:
-        total += graph.nodes[i].weight_bytes / variables.s_out[i]
+        total += weight_per_chip(graph.nodes[i], variables.s_out[i])
     return total
 
 

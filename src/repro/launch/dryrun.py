@@ -404,8 +404,8 @@ def _compile_partition(arch: ArchConfig, shape: ShapeSpec, plan, mesh, pi,
     t1 = time.time()
     try:
         period = _period(arch)
-        lead = 1 if (arch.first_layer_dense and part.layer_start == 0
-                     and n_layers > 0) else 0
+        lead = min(arch.first_k_dense, n_layers) \
+            if part.layer_start == 0 else 0
         n_periods = max((n_layers - lead) // period, 0)
         if multi:
             def small(k):

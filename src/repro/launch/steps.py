@@ -75,8 +75,8 @@ def shard_fns_from_plan(plan: ShardingPlan, mesh: Mesh,
 
         return fn
 
-    kinds = ("embed", "attn", "mla", "cross_attn", "enc_attn", "ffn",
-             "enc_ffn", "shared_expert", "moe", "ssm", "rwkv_tmix",
+    kinds = ("embed", "attn", "mla", "dsa", "cross_attn", "enc_attn",
+             "ffn", "enc_ffn", "shared_expert", "moe", "ssm", "rwkv_tmix",
              "rwkv_cmix", "head", "norm")
     return {k: fns_for(k) for k in kinds}
 

@@ -1,5 +1,7 @@
-"""GQA attention (self / cross / encoder) with optional KV cache, and
-multi-head latent attention (MLA) with a latent cache.
+"""GQA attention (self / cross / encoder) with optional KV cache,
+multi-head latent attention (MLA) with a latent cache, and DeepSeek sparse
+attention (DSA): latent attention over the positions a lightning indexer
+selects, with a latent cache and an index-key cache.
 
 The scaled-dot-product core dispatches to the Pallas flash-attention kernel
 (kernels/ops.py) when enabled, else to the pure-jnp oracle (kernels/ref.py) —
@@ -7,6 +9,7 @@ the oracle is what XLA compiles in the CPU dry-run.
 """
 from __future__ import annotations
 
+import math
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -179,5 +182,240 @@ def attend_mla(x: jax.Array, p: Dict[str, jax.Array], *, num_heads: int,
     o = sdpa(q, k, v, causal=True,
              q_offset=cache_pos if cache_pos is not None else 0,
              impl=attn_impl)
+    y = o.reshape(B, S, H * dv) @ p["wo"]
+    return x + shard_fn(y, role="boundary"), new_cache
+
+
+# ----------------------------------------------------------------------
+# DeepSeek sparse attention (DeepSeek-V3.2)
+# ----------------------------------------------------------------------
+
+def init_dsa(key, d_model: int, num_heads: int, q_lora_rank: int,
+             kv_lora_rank: int, qk_nope_head_dim: int, qk_rope_head_dim: int,
+             v_head_dim: int, index_n_heads: int, index_head_dim: int,
+             norm: str, dtype=jnp.bfloat16):
+    """Latent attention's parameters and the lightning indexer's: the
+    query projection from the q latent, one key projection with its
+    LayerNorm, and the per-head weights (``ArchConfig.indexer_weights``)."""
+    k_mla, k_idx = jax.random.split(key)
+    p = init_mla(k_mla, d_model, num_heads, q_lora_rank, kv_lora_rank,
+                 qk_nope_head_dim, qk_rope_head_dim, v_head_dim, norm, dtype)
+    ks = jax.random.split(k_idx, 3)
+    p.update({
+        "wq_idx": dense_init(ks[0], q_lora_rank,
+                             index_n_heads * index_head_dim, dtype),
+        "wk_idx": dense_init(ks[1], d_model, index_head_dim, dtype),
+        "k_idx_scale": jnp.ones((index_head_dim,), dtype),
+        "k_idx_bias": jnp.zeros((index_head_dim,), dtype),
+        "w_idx": dense_init(ks[2], d_model, index_n_heads, dtype),
+    })
+    return p
+
+
+def _pow2(n: int) -> bool:
+    return n > 0 and n & (n - 1) == 0
+
+
+def _causal_scores(q, k):
+    """s[b, t, h, u] = q[b, t, h] . k[b, u] for u <= t, 0 above.
+
+    For n a power of two only the lower triangle is computed: the
+    diagonal, then at each level the n / 2m squares of side m that lie
+    just below it (queries [(2g+1)m, (2g+2)m) against keys [2gm,
+    (2g+1)m)), n (n + 1) / 2 products a head in all. Otherwise the whole
+    square (the caller masks it)."""
+    B, n, Hh, d = q.shape
+    if not _pow2(n):
+        return jnp.einsum("bthd,bud->bthu", q, k)
+    i = jnp.arange(n)
+    s = jnp.zeros((B, n, Hh, n), jnp.result_type(q, k))
+    s = s.at[:, i, :, i].set(jnp.einsum("bthd,btd->tbh", q, k))
+    m = n // 2
+    while m:
+        g = n // (2 * m)
+        gi = jnp.arange(g)
+        blk = jnp.einsum("bgqhd,bgkd->gbqhk",
+                         q.reshape(B, g, 2, m, Hh, d)[:, :, 1],
+                         k.reshape(B, g, 2, m, d)[:, :, 0])
+        s = s.reshape(B, g, 2, m, Hh, g, 2, m).at[
+            :, gi, 1, :, :, gi, 0, :].set(blk).reshape(B, n, Hh, n)
+        m //= 2
+    return s
+
+
+def _causal_weighted_sum(a, v):
+    """o[b, t, h] = sum over u <= t of a[b, t, h, u] v[b, u], over the
+    blocks ``_causal_scores`` computes (``a`` is 0 above the diagonal)."""
+    B, n, Hh, _ = a.shape
+    d = v.shape[-1]
+    if not _pow2(n):
+        return jnp.einsum("bthu,bud->bthd", a, v)
+    i = jnp.arange(n)
+    diag = a[:, i, :, i].transpose(1, 0, 2)[..., None]        # (B, n, Hh, 1)
+    # the diagonal's outer product, as a contraction of length one
+    o = jax.lax.dot_general(diag, v[:, :, None, :].astype(a.dtype),
+                            (((3,), (2,)), ((0, 1), (0, 1))))
+    m = n // 2
+    while m:
+        g = n // (2 * m)
+        gi = jnp.arange(g)
+        blk = a.reshape(B, g, 2, m, Hh, g, 2, m)[:, gi, 1, :, :, gi, 0, :]
+        ob = jnp.einsum("gbqhk,bgkd->bgqhd", blk,
+                        v.reshape(B, g, 2, m, d)[:, :, 0])
+        o = o.reshape(B, g, 2, m, Hh, d).at[:, :, 1].add(ob).reshape(
+            B, n, Hh, d)
+        m //= 2
+    return o
+
+
+def _softmax(s, keep):
+    p = jax.nn.softmax(jnp.where(keep, s.astype(jnp.float32), -jnp.inf),
+                       axis=-1)
+    return p.astype(s.dtype)
+
+
+def _dsa_inputs(x, p, *, num_heads, qk_nope_head_dim, qk_rope_head_dim,
+                index_n_heads, index_head_dim, norm, positions, rope_theta):
+    """The block's projections: latent attention's query (absorbed into
+    the latent), latents and rotary parts, and the indexer's query, key
+    and head weights."""
+    B, S, _ = x.shape
+    H, dn, dr = num_heads, qk_nope_head_dim, qk_rope_head_dim
+    h = block_norm(x, p, norm)
+    c_q = apply_norm(h @ p["wq_a"], p["q_norm"], None, "rms")
+    q = (c_q @ p["wq_b"]).reshape(B, S, H, dn + dr)
+    kv_a = h @ p["wkv_a"]
+    c_kv = apply_norm(kv_a[..., :-dr], p["kv_norm"], None, "rms")
+    wkv_b = p["wkv_b"].reshape(c_kv.shape[-1], H, -1)
+    return {
+        "q_lat": jnp.einsum("bshd,khd->bshk", q[..., :dn], wkv_b[..., :dn]),
+        "q_rope": apply_rope(q[..., dn:], positions, rope_theta),
+        "c_kv": c_kv,
+        "k_rope": apply_rope(kv_a[..., None, -dr:], positions,
+                             rope_theta)[:, :, 0],
+        "q_idx": (c_q @ p["wq_idx"]).reshape(B, S, index_n_heads,
+                                             index_head_dim),
+        "k_idx": apply_norm(h @ p["wk_idx"], p["k_idx_scale"],
+                            p["k_idx_bias"], "ln"),
+        "w_idx": h @ p["w_idx"],                                # (B, S, HI)
+        "w_uv": wkv_b[..., dn:],
+    }
+
+
+def _index_scores(w_idx, s):
+    """I(t, s) = sum_j w(t, j) ReLU(s_j): the indexer's head weighting of
+    its per-head dot products ``s`` (B, S, HI, L)."""
+    return jnp.sum(w_idx[..., None] * jax.nn.relu(s), axis=2)
+
+
+def _prefill_index(z):
+    """A prefill's indexer scores over the causal triangle, -inf above."""
+    S = z["k_idx"].shape[1]
+    causal = jnp.tril(jnp.ones((S, S), bool))
+    return jnp.where(causal, _index_scores(
+        z["w_idx"], _causal_scores(z["q_idx"], z["k_idx"])), -jnp.inf)
+
+
+def dsa_prefill_selection(x, p, *, index_topk: int, **kw):
+    """The positions each query of a prefill from position 0 attends to
+    (``attend_dsa`` without a cache): (B, S, min(K, S)) int32, query t's
+    min(K, t + 1) positions first and -1 after them."""
+    z = _dsa_inputs(x, p, **kw)
+    S = x.shape[1]
+    T = min(index_topk, S)
+    t = jnp.arange(S)[:, None]
+    first = jnp.where(jnp.arange(T)[None, :] <= t, jnp.arange(T)[None, :],
+                      -1)
+    sel = jnp.broadcast_to(first, (x.shape[0], S, T))
+    if S > T:
+        _, top = jax.lax.top_k(_prefill_index(z)[:, T:], index_topk)
+        sel = jnp.concatenate([sel[:, :T], top], axis=1)
+    return sel
+
+
+def attend_dsa(x: jax.Array, p: Dict[str, jax.Array], *, num_heads: int,
+               qk_nope_head_dim: int, qk_rope_head_dim: int, v_head_dim: int,
+               index_n_heads: int, index_head_dim: int, index_topk: int,
+               norm: str, positions: jax.Array, rope_theta: float = 10000.0,
+               cache: Optional[Dict[str, jax.Array]] = None,
+               cache_pos: Optional[jax.Array] = None,
+               shard_fn=lambda a, role=None: a):
+    """One DeepSeek-sparse-attention block with pre-norm and residual
+    (``graph_builder._dsa_node`` prices it).
+
+    Indexer: I(t, s) = sum_j w(t, j) ReLU(qI(t, j) . kI(s)), with qI from
+    the q latent, kI a LayerNorm'd projection of the normed input (one
+    vector a token for all indexer heads) and w a projection of it. Query
+    t attends to the top min(K, t + 1) positions s <= t by I(t, s), ties
+    to the lower position (``lax.top_k``), K = ``index_topk``. The core is
+    latent attention in the absorbed form: q_nope folds into the latent
+    through the key half of ``wkv_b``, scores and the weighted sum run
+    over the selected latent rows (c_kv and k_rope, shared by all heads),
+    and the value half of ``wkv_b`` maps the result out of the latent.
+
+    Without a cache the step is a prefill from position 0: the indexer
+    scores the causal triangle and queries t < K attend to all of s <= t,
+    both over the triangle only (``_causal_scores``); queries t >= K
+    gather their K selected rows (``dsa_prefill_selection``). With
+    ``cache`` and ``cache_pos`` this step's latents and index keys
+    (``c_kv``, ``k_rope``, ``index_keys``) are written there, and every
+    query scores the whole cache (positions past its own masked) and
+    gathers min(K, cache length) rows.
+
+    Departures from the published model, all elementwise: the indexer's
+    query and key carry no rotary part, no Hadamard rotation, no FP8
+    rounding and no scale on w. Returns (y, new_cache)."""
+    B, S, _ = x.shape
+    H, dv, K = num_heads, v_head_dim, index_topk
+    z = _dsa_inputs(x, p, num_heads=H, qk_nope_head_dim=qk_nope_head_dim,
+                    qk_rope_head_dim=qk_rope_head_dim,
+                    index_n_heads=index_n_heads,
+                    index_head_dim=index_head_dim, norm=norm,
+                    positions=positions, rope_theta=rope_theta)
+    q_lat, q_rope = shard_fn(z["q_lat"], role="heads"), z["q_rope"]
+    scale = 1.0 / math.sqrt(qk_nope_head_dim + qk_rope_head_dim)
+
+    def attend_selected(q_l, q_r, sel, ok, rows, rope_rows):
+        """The core over the gathered rows ``sel`` (B, T, k) of
+        ``rows``/``rope_rows``; ``ok`` masks selections past the query."""
+        c_sel = jax.vmap(lambda r, i: r[i])(rows, sel)         # (B, T, k, kvr)
+        r_sel = jax.vmap(lambda r, i: r[i])(rope_rows, sel)
+        s = (jnp.einsum("bthk,btjk->bthj", q_l, c_sel)
+             + jnp.einsum("bthr,btjr->bthj", q_r, r_sel)) * scale
+        a = _softmax(s, ok[:, :, None, :])
+        return jnp.einsum("bthj,btjk->bthk", a, c_sel)
+
+    new_cache = cache
+    if cache is not None and cache_pos is not None:
+        def put(name, val):
+            return jax.lax.dynamic_update_slice_in_dim(
+                cache[name], val.astype(cache[name].dtype), cache_pos, axis=1)
+        new_cache = {"c_kv": put("c_kv", z["c_kv"]),
+                     "k_rope": put("k_rope", z["k_rope"]),
+                     "index_keys": put("index_keys", z["k_idx"])}
+        rows = new_cache["c_kv"].astype(x.dtype)
+        L = rows.shape[1]
+        valid = jnp.arange(L)[None, None, :] <= positions[:, :, None]
+        I = jnp.where(valid, _index_scores(z["w_idx"], jnp.einsum(
+            "bshd,bld->bshl", z["q_idx"],
+            new_cache["index_keys"].astype(x.dtype))), -jnp.inf)
+        _, sel = jax.lax.top_k(I, min(K, L))
+        o_lat = attend_selected(q_lat, q_rope, sel,
+                                jnp.take_along_axis(valid, sel, axis=-1),
+                                rows, new_cache["k_rope"].astype(x.dtype))
+    else:
+        c_kv, k_rope = z["c_kv"], z["k_rope"]
+        T = min(K, S)
+        # queries t < K attend to every position s <= t
+        s = (_causal_scores(q_lat[:, :T], c_kv[:, :T])
+             + _causal_scores(q_rope[:, :T], k_rope[:, :T])) * scale
+        a = _softmax(s, jnp.tril(jnp.ones((T, T), bool))[:, None, :])
+        o_lat = _causal_weighted_sum(a, c_kv[:, :T])
+        if S > T:
+            _, sel = jax.lax.top_k(_prefill_index(z)[:, T:], K)
+            o_lat = jnp.concatenate([o_lat, attend_selected(
+                q_lat[:, T:], q_rope[:, T:], sel, jnp.ones(sel.shape, bool),
+                c_kv, k_rope)], axis=1)
+    o = jnp.einsum("bshk,khd->bshd", o_lat, z["w_uv"])
     y = o.reshape(B, S, H * dv) @ p["wo"]
     return x + shard_fn(y, role="boundary"), new_cache

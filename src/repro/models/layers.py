@@ -55,6 +55,15 @@ PARAM_ROLES: Dict[str, Dict[str, str]] = {
         "wkv_a": "replicate", "kv_norm": "replicate", "wkv_b": "col",
         "wo": "row",
     },
+    # the indexer is whole on every head shard (graph_builder._dsa_node)
+    "dsa": {
+        "ln_scale": "replicate", "ln_bias": "replicate",
+        "wq_a": "replicate", "q_norm": "replicate", "wq_b": "col",
+        "wkv_a": "replicate", "kv_norm": "replicate", "wkv_b": "col",
+        "wo": "row", "wq_idx": "replicate", "wk_idx": "replicate",
+        "k_idx_scale": "replicate", "k_idx_bias": "replicate",
+        "w_idx": "replicate",
+    },
     "ssm": {
         "ln_scale": "replicate", "ln_bias": "replicate",
         "in_proj": "col", "conv_w": "expert", "conv_b": "expert",

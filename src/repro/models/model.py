@@ -1,4 +1,4 @@
-"""Composable model zoo: one builder for all ten assigned architectures.
+"""Composable model zoo: one builder for every registered architecture.
 
 A model is a chain of *segments*; each segment is a homogeneous stack of
 layer-groups executed with ``lax.scan`` over stacked parameters (keeps the
@@ -9,6 +9,7 @@ A layer-group is a static *pattern* of block kinds, e.g.:
   jamba           ("ssm","ffn","ssm","moe",... ,"attn","moe") x 9   (1:7, MoE alt)
   kimi-k2         ("mla","ffn") x 1  +  ("mla","moe") x 60  (moe with a
                   shared expert)
+  deepseek-v3.2   ("dsa","ffn") x 1 (three times)  +  ("dsa","moe") x 58
   rwkv6           ("rwkv_tmix","rwkv_cmix") x 24
   whisper         enc: ("enc_attn","enc_ffn") x 12;
                   dec: ("attn","cross_attn","ffn") x 12
@@ -63,8 +64,8 @@ def build_segments(arch: ArchConfig,
             kinds.append("attn")
             if arch.cross_attention:
                 kinds.append("cross_attn")
-        elif mixer == "mla":
-            kinds.append("mla")
+        elif mixer in ("mla", "dsa"):
+            kinds.append(mixer)
         elif mixer == "ssm":
             kinds.append("ssm")
         else:
@@ -80,8 +81,8 @@ def build_segments(arch: ArchConfig,
     period = max(arch.attn_period, 1)
     i = lo
     while i < hi:
-        if arch.first_layer_dense and i == 0:
-            segs.append(Segment("dec0", block_pattern(0), 1, (0,)))
+        if i < arch.first_k_dense:
+            segs.append(Segment(f"dec{i}", block_pattern(i), 1, (0,)))
             i += 1
             continue
         # find the maximal run starting at i where pattern repeats with
@@ -127,6 +128,10 @@ _INIT = {
         key, arch.d_model, arch.num_heads, arch.q_lora_rank,
         arch.kv_lora_rank, arch.qk_nope_head_dim, arch.qk_rope_head_dim,
         arch.v_head_dim, arch.norm),
+    "dsa": lambda key, arch: A.init_dsa(
+        key, arch.d_model, arch.num_heads, arch.q_lora_rank,
+        arch.kv_lora_rank, arch.qk_nope_head_dim, arch.qk_rope_head_dim,
+        arch.v_head_dim, arch.index_n_heads, arch.index_head_dim, arch.norm),
     "moe": lambda key, arch: M.init_moe(
         key, arch.d_model, arch.expert_d_ff, arch.num_experts, arch.act,
         arch.norm, shared_d_ff=arch.n_shared_experts * arch.expert_d_ff),
@@ -330,6 +335,17 @@ class Model:
                         positions=positions, rope_theta=arch.rope_theta,
                         cache=c, cache_pos=cache_pos,
                         attn_impl=self.attn_impl, shard_fn=sfk)
+                elif kind == "dsa":
+                    h, nc = A.attend_dsa(
+                        h, p, num_heads=arch.num_heads,
+                        qk_nope_head_dim=arch.qk_nope_head_dim,
+                        qk_rope_head_dim=arch.qk_rope_head_dim,
+                        v_head_dim=arch.v_head_dim,
+                        index_n_heads=arch.index_n_heads,
+                        index_head_dim=arch.index_head_dim,
+                        index_topk=arch.index_topk, norm=arch.norm,
+                        positions=positions, rope_theta=arch.rope_theta,
+                        cache=c, cache_pos=cache_pos, shard_fn=sfk)
                 elif kind == "cross_attn":
                     h, nc = A.attend(
                         h, p, num_heads=arch.num_heads,
@@ -415,13 +431,17 @@ class Model:
                                             arch.num_kv_heads, arch.head_dim),
                                            dtype)
                     seg_cache[pk] = {"k": kv(), "v": kv()}
-                elif kind == "mla":
+                elif kind in ("mla", "dsa"):
                     seg_cache[pk] = {
                         "c_kv": jnp.zeros((seg.count, batch_size, max_len,
                                            arch.kv_lora_rank), dtype),
                         "k_rope": jnp.zeros((seg.count, batch_size, max_len,
                                              arch.qk_rope_head_dim), dtype),
                     }
+                    if kind == "dsa":
+                        seg_cache[pk]["index_keys"] = jnp.zeros(
+                            (seg.count, batch_size, max_len,
+                             arch.index_head_dim), dtype)
                 elif kind == "cross_attn":
                     F = arch.num_frames or 1500
                     kv = lambda: jnp.zeros((seg.count, batch_size, F,
@@ -485,11 +505,15 @@ class Model:
                     kv = P(None, batch_ax, rows_ax if kind == "attn" else None,
                            kv_heads_ax, None)
                     seg_specs[pk] = {"k": kv, "v": kv}
-                elif kind == "mla":
-                    mkp = plan.kind_plan("mla", partition)
+                elif kind in ("mla", "dsa"):
+                    # the latent (and index-key) caches: one vector a token
+                    # for all heads, never sharded over the head fold
+                    mkp = plan.kind_plan(kind, partition)
                     lat = P(None, axes(mkp.batch_axes), axes(mkp.rows_axes),
                             None)
                     seg_specs[pk] = {"c_kv": lat, "k_rope": lat}
+                    if kind == "dsa":
+                        seg_specs[pk]["index_keys"] = lat
                 elif kind == "ssm":
                     skp = plan.kind_plan("ssm", partition)
                     seg_specs[pk] = {

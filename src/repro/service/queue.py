@@ -161,7 +161,8 @@ def run_rule_based_lockstep(jobs: Sequence[LockstepJob],
     completion order.
 
     Padding grows monotonically (node/pair/menu axes tiered to
-    ``fleet.NODE_TIER`` multiples, lane count to the next power of two)
+    ``fleet.NODE_TIER`` multiples, lane count to the next power of two,
+    the sparse-attention terms on once a lane has ``dsa`` nodes)
     so late joiners usually ride an already-compiled executable; a
     joiner that genuinely needs bigger shapes restacks every lane and
     retraces once (counted in ``service.rounds.restacks``). Results are
@@ -177,6 +178,7 @@ def run_rule_based_lockstep(jobs: Sequence[LockstepJob],
         _platform_pads,
         bucket_key,
     )
+    from repro.core.accel.lowering import any_dsa
     from repro.core.accel.search_loops import (
         DeviceRuleBased,
         _pow2ceil,
@@ -184,7 +186,7 @@ def run_rule_based_lockstep(jobs: Sequence[LockstepJob],
     )
     from repro.core.optimizers.rule_based import _algorithm2
 
-    pads = {"n": 0, "pairs": 0, "vals": 0, "lut": 0, "mm": 0}
+    pads = {"n": 0, "pairs": 0, "vals": 0, "lut": 0, "mm": 0, "dsa": 0}
     lanes: List[_Lane] = []
     done: List = []
     sig = [None]
@@ -206,6 +208,7 @@ def run_rule_based_lockstep(jobs: Sequence[LockstepJob],
         return DeviceRuleBased(problem, pad_nodes=pads["n"],
                                pad_pairs=pads["pairs"],
                                pad_vals=pads["vals"], pad_lut=pads["lut"],
+                               pad_dsa=bool(pads["dsa"]),
                                tables=(menus,) + tabs[1:])
 
     def admit(new_jobs: Sequence[LockstepJob]) -> bool:
@@ -238,7 +241,9 @@ def run_rule_based_lockstep(jobs: Sequence[LockstepJob],
                       ("pairs", max(1, _node_tier(
                           len(p.batched().scan_pairs)))),
                       ("vals", _node_tier(va)),
-                      ("lut", _node_tier(lu)))
+                      ("lut", _node_tier(lu)),
+                      # a sparse-attention lane turns its terms on for all
+                      ("dsa", int(any_dsa([p]))))
             for key, v in wanted:
                 if v > pads[key]:
                     pads[key] = v

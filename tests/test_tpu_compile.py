@@ -6,8 +6,9 @@ described rather than attached, and refuses what the chip would refuse
 jitted entry point exactly as the engine dispatches it — the arguments are
 captured at the engine's own call site and replaced by shapes placed on a
 described v5e chip — at real node counts: tinyllama-1.1b train_4k (47
-nodes) and qwen2-vl-72b train_4k (163 nodes, the largest registered
-graph). Nothing runs, so these say nothing about results or times.
+nodes), qwen2-vl-72b train_4k (163 nodes) and, for the descent with the
+sparse-attention terms, deepseek-v3.2-671b-a37b decode (183 nodes).
+Nothing runs, so these say nothing about results or times.
 
 The topology is described inside a fixture, never at import: one process
 at a time may load the TPU library, and every xdist worker imports this
@@ -156,3 +157,17 @@ def test_bf_chunk_shard_compiles_for_v5e_2x2(topo, problems,
     compiled = _compile(search_loops._bf_chunk_shard,
                         (static, B * mesh.devices.size, no_cut, mesh, *rest))
     assert "all-reduce" in compiled.as_text()
+
+
+def test_rb_descend_with_sparse_attention_compiles_for_v5e(
+        one_chip, no_persistent_cache, monkeypatch):
+    """The descent with the sparse-attention terms on, at the DeepSeek
+    cell's decode shape (183 nodes, a 131072-position cache)."""
+    from repro.configs.base import ShapeSpec
+    problem = make_problem(get_arch("deepseek-v3.2-671b-a37b"),
+                           ShapeSpec("decode_128k", 131072, 128, "decode"),
+                           exec_model="spmd")
+    args = _capture(monkeypatch, "_rb_descend", lambda: OPTIMIZERS[
+        "rule_based"](problem, engine="jax"))
+    assert args[0].dsa
+    _compile(search_loops._rb_descend, _on(one_chip, 2, args))
