@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import itertools
 import random
+from bisect import bisect_left
 from dataclasses import dataclass
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
@@ -33,6 +34,11 @@ VARS = ("s_in", "s_out", "kern")
 
 def _divisors_from(values: Iterable[int], dim: int) -> List[int]:
     return sorted(v for v in values if v >= 1 and dim % v == 0)
+
+
+def _within(members: List[int], lo: int, hi: int) -> List[int]:
+    """The ascending ``members`` in ``[lo, hi)``."""
+    return members[bisect_left(members, lo):bisect_left(members, hi)]
 
 
 @dataclass(frozen=True)
@@ -89,45 +95,113 @@ class Backend:
     @staticmethod
     def _partition_of(graph: HDGraph, i: int,
                       cuts: Sequence[int]) -> range:
-        lo, hi = 0, len(graph.nodes)
-        for c in sorted(cuts):
-            if c < i:
-                lo = c + 1
-            else:
-                hi = min(hi, c + 1)
-                break
+        """The partition holding node i; ``cuts`` ascending."""
+        k = bisect_left(cuts, i)
+        lo = cuts[k - 1] + 1 if k else 0
+        hi = cuts[k] + 1 if k < len(cuts) else len(graph.nodes)
         return range(lo, hi)
+
+    def _scope_in(self, graph: HDGraph, i: int, var: str,
+                  part: range) -> Sequence[int]:
+        """``scope`` inside node i's partition ``part``."""
+        g = self.granularity[var]
+        if g == "global":
+            return part
+        sg = graph.nodes[i].scan_group
+        if g == "group" and sg >= 0:
+            return _within(graph.scan_members()[sg], part.start, part.stop)
+        return [i]
 
     def scope(self, graph: HDGraph, i: int, var: str,
               cuts: Sequence[int] = ()) -> List[int]:
         """Node indices that share this variable with node i."""
-        g = self.granularity[var]
-        part = self._partition_of(graph, i, cuts)
-        if g == "global":
-            return list(part)
-        if g == "group" and graph.nodes[i].scan_group >= 0:
-            sg = graph.nodes[i].scan_group
-            return [j for j in part if graph.nodes[j].scan_group == sg]
-        return [i]
+        part = self._partition_of(graph, i, sorted(cuts))
+        return list(self._scope_in(graph, i, var, part))
 
     def set_fold(self, graph: HDGraph, v: Variables, i: int, var: str,
                  value: int) -> Variables:
+        """Set ``var`` of node i to ``value`` over its scope, each node
+        clamped to a legal divisor, then ``propagate`` the whole design.
+
+        Takes any design, a ``propagate`` fixed point or not: a design
+        after a cut move (``random_move``), the brute force's slot
+        assignments, the exporter's. A caller that holds a fixed point
+        and rebuilds many moves from it uses ``set_fold_local``."""
         si, so, kk = list(v.s_in), list(v.s_out), list(v.kern)
-        arrays = {"s_in": si, "s_out": so, "kern": kk}
-        for j in self.scope(graph, i, var, v.cuts):
-            node = graph.nodes[j]
-            val = value
-            # clamp to a legal divisor for this node (propagation keeps V valid)
-            dim = {"s_in": node.rows, "s_out": node.col_div,
-                   "kern": node.batch}[var]
-            while val > 1 and dim % val != 0:
-                val -= 1
-            if var == "s_in" and node.internal_rows and \
-                    self.granularity["s_in"] == "global":
-                continue                     # decode split-KV keeps its own s_I
-            arrays[var][j] = val
+        self._scatter(graph, {"s_in": si, "s_out": so, "kern": kk}[var],
+                      self.scope(graph, i, var, v.cuts), var, value)
         out = Variables(v.cuts, tuple(si), tuple(so), tuple(kk))
         return self.propagate(graph, out)
+
+    def set_fold_local(self, graph: HDGraph, v: Variables, i: int, var: str,
+                       value: int) -> Tuple[Variables, List[int]]:
+        """``set_fold`` of a ``propagate`` fixed point, rebuilt where the
+        move reaches: ``(set_fold(graph, v, i, var, value), moved)``, with
+        ``moved`` the sorted nodes whose fold triple differs from ``v``.
+
+        Precondition: ``propagate(graph, v) == v``; on any other design
+        the answer may differ from ``set_fold``'s. So its callers are
+        those that know their design is a fixed point: ``repair`` while
+        its design is one (it checks at the start, after a split, and
+        after a move unless ``keeps_fixed_points``), and the rule-based
+        seeds, built from the all-ones design, which is one. Matching
+        never crosses a cut, and in a fixed point every tie already
+        holds, so only the move's partition can change. Without inter
+        matching, and with a scope narrower than the partition, only the
+        scope and the in-partition members of the scan groups it touches
+        can change, and only they are re-tied."""
+        part = self._partition_of(graph, i, v.cuts)
+        scope = self._scope_in(graph, i, var, part)
+        si, so, kk = list(v.s_in), list(v.s_out), list(v.kern)
+        self._scatter(graph, {"s_in": si, "s_out": so, "kern": kk}[var],
+                      scope, var, value)
+        sg = graph.nodes[i].scan_group
+        if self.inter_matching or self.granularity[var] == "global":
+            work = part
+        elif self.scan_tying and sg >= 0:
+            # a group-wide scope is the group; a node-wide one re-ties it
+            work = _within(graph.scan_members()[sg], part.start, part.stop)
+        else:
+            work = scope
+        self._propagate_part(graph, si, so, kk, work)
+        s_in, s_out, kern = v.s_in, v.s_out, v.kern
+        moved = [j for j in work if si[j] != s_in[j] or so[j] != s_out[j]
+                 or kk[j] != kern[j]]
+        return Variables(v.cuts, tuple(si), tuple(so), tuple(kk)), moved
+
+    def keeps_fixed_points(self, graph: HDGraph) -> bool:
+        """Whether ``set_fold`` maps a ``propagate`` fixed point to another
+        on ``graph``: so it does where the members of each scan group
+        agree on what ``propagate`` reads of them (the intra-matching
+        flag; with inter matching also rows, batch and internal rows), as
+        in every graph the builder makes (one group a node kind)."""
+        if not self.scan_tying:
+            return True
+        keys = ("elementwise",)
+        if self.inter_matching:
+            keys += ("rows", "batch", "internal_rows")
+        return graph.scan_groups_agree(keys)
+
+    def _scatter(self, graph: HDGraph, arr: List[int], scope: Iterable[int],
+                 var: str, value: int) -> None:
+        """Write ``value`` to ``arr`` over ``scope``, clamped per node to
+        the nearest divisor below it (propagation keeps V valid), one
+        clamp per distinct dimension."""
+        dims = graph.fold_dims()[var]
+        nodes = graph.nodes
+        keep_internal = var == "s_in" and self.granularity["s_in"] == "global"
+        clamped: Dict[int, int] = {}
+        for j in scope:
+            if keep_internal and nodes[j].internal_rows:
+                continue                     # decode split-KV keeps its own s_I
+            dim = dims[j]
+            val = clamped.get(dim)
+            if val is None:
+                val = value
+                while val > 1 and dim % val != 0:
+                    val -= 1
+                clamped[dim] = val
+            arr[j] = val
 
     def propagate(self, graph: HDGraph, v: Variables) -> Variables:
         """Fix intra (Eq. 9) and inter (Eq. 10) matching after a change.
@@ -136,42 +210,47 @@ class Backend:
         through HBM, so no layout agreement is required (the paper's data
         lines only wire blocks within one configuration)."""
         si, so, kk = list(v.s_in), list(v.s_out), list(v.kern)
-        n_nodes = len(graph.nodes)
+        bounds = [0] + [c + 1 for c in sorted(v.cuts)] + [len(graph.nodes)]
+        for lo, hi in zip(bounds, bounds[1:]):
+            self._propagate_part(graph, si, so, kk, range(lo, hi))
+        return Variables(v.cuts, tuple(si), tuple(so), tuple(kk))
+
+    def _propagate_part(self, graph: HDGraph, si: List[int], so: List[int],
+                        kk: List[int], part: Sequence[int]) -> None:
+        """``propagate``'s body for the ascending nodes ``part`` of one
+        partition, in place: the whole partition, or (on a backend without
+        inter matching) the partition's members of whole scan groups and
+        any ungrouped nodes, whose matching reads nothing else."""
+        nodes = graph.nodes
         if self.scan_tying:
-            # harmonise scan-group folds within each partition (one stacked
+            # harmonise scan-group folds within the partition (one stacked
             # lax.scan has a single sharding): first member's triple wins.
-            bounds0 = [0] + [c + 1 for c in sorted(v.cuts)] + [n_nodes]
-            for b in range(len(bounds0) - 1):
-                anchors = {}
-                for j in range(bounds0[b], bounds0[b + 1]):
-                    g = graph.nodes[j].scan_group
-                    if g < 0:
-                        continue
-                    if g not in anchors:
-                        anchors[g] = (si[j], so[j], kk[j])
-                    else:
-                        si[j], so[j], kk[j] = anchors[g]
+            anchors = {}
+            for j in part:
+                g = nodes[j].scan_group
+                if g < 0:
+                    continue
+                if g not in anchors:
+                    anchors[g] = (si[j], so[j], kk[j])
+                else:
+                    si[j], so[j], kk[j] = anchors[g]
         if self.intra_matching:
-            for j, n in enumerate(graph.nodes):
-                if n.elementwise:
+            for j in part:
+                if nodes[j].elementwise:
                     so[j] = si[j]
         if self.inter_matching:
             # chain equality on boundary layout => per-partition (s_I, k);
             # anchored at the partition's first non-internal node.
-            bounds = [0] + [c + 1 for c in sorted(v.cuts)] + [n_nodes]
-            for b in range(len(bounds) - 1):
-                part = range(bounds[b], bounds[b + 1])
-                anchor_si = next((si[j] for j in part
-                                  if not graph.nodes[j].internal_rows), 1)
-                anchor_k = kk[part[0]]
-                for j in part:
-                    n = graph.nodes[j]
-                    kk[j] = anchor_k if n.batch % anchor_k == 0 else 1
-                    if not n.internal_rows:
-                        si[j] = anchor_si if n.rows % anchor_si == 0 else 1
-                    if n.elementwise and self.intra_matching:
-                        so[j] = si[j]
-        return Variables(v.cuts, tuple(si), tuple(so), tuple(kk))
+            anchor_si = next((si[j] for j in part
+                              if not nodes[j].internal_rows), 1)
+            anchor_k = kk[part[0]]
+            for j in part:
+                n = nodes[j]
+                kk[j] = anchor_k if n.batch % anchor_k == 0 else 1
+                if not n.internal_rows:
+                    si[j] = anchor_si if n.rows % anchor_si == 0 else 1
+                if n.elementwise and self.intra_matching:
+                    so[j] = si[j]
 
     def initial(self, graph: HDGraph) -> Variables:
         return self.propagate(graph, resource_minimal(graph))

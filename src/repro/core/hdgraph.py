@@ -120,6 +120,42 @@ class HDGraph:
                 groups.setdefault(n.scan_group, []).append(i)
         return groups
 
+    def scan_members(self) -> Dict[int, List[int]]:
+        """``scan_groups()``, built once per node list and shared: callers
+        must not modify it."""
+        return self._index()[0]
+
+    def fold_dims(self) -> Dict[str, Tuple[int, ...]]:
+        """Each node's dimension that a fold variable must divide:
+        ``s_in`` rows, ``s_out`` ``col_div``, ``kern`` batch. Built once
+        per node list."""
+        return self._index()[1]
+
+    def scan_groups_agree(self, attrs: Tuple[str, ...]) -> bool:
+        """Whether the members of each scan group agree on the node
+        attributes ``attrs``. Memoised per node list."""
+        agree = self._index()[2]
+        if attrs not in agree:
+            nodes = self.nodes
+            agree[attrs] = all(
+                len({tuple(getattr(nodes[j], a) for a in attrs)
+                     for j in m}) == 1
+                for m in self.scan_members().values())
+        return agree[attrs]
+
+    def _index(self):
+        """What ``scan_members``, ``fold_dims`` and ``scan_groups_agree``
+        keep, rebuilt when a new ``nodes`` list is assigned."""
+        memo = self.__dict__.get("_index_memo")
+        if memo is None or memo[0] is not self.nodes:
+            nodes = self.nodes
+            dims = {"s_in": tuple(n.rows for n in nodes),
+                    "s_out": tuple(n.col_div for n in nodes),
+                    "kern": tuple(n.batch for n in nodes)}
+            memo = self.__dict__["_index_memo"] = (nodes, self.scan_groups(),
+                                                   dims, {})
+        return memo[1:]
+
 
 def partitions_from_cuts(graph: HDGraph, cuts: Sequence[int]) -> List[List[int]]:
     """Eq. 1: cut positions -> disjoint, complete list of node-index blocks.

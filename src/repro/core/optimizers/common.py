@@ -56,8 +56,11 @@ def repair(problem: Problem, v: Variables, max_steps: int = 1024) -> Variables:
     A fold move keeps the cuts and changes the triples of a few nodes (its
     scope and what ``propagate`` re-ties), so each candidate is scored on
     those nodes alone (``_RepairState``): the answer is the one a full
-    ``check`` and ``evaluate`` of every candidate gives. Every candidate
-    that reaches ``set_fold`` counts in ``optim.repair.candidates``.
+    ``check`` and ``evaluate`` of every candidate gives. While the design
+    is a ``propagate`` fixed point, each candidate is also built on those
+    nodes alone (``Backend.set_fold_local``). Every candidate counts in
+    ``optim.repair.candidates``, and those built locally also in
+    ``optim.repair.local_candidates``.
     """
     rep = problem.check(v)
     if rep.ok:
@@ -67,6 +70,7 @@ def repair(problem: Problem, v: Variables, max_steps: int = 1024) -> Variables:
         return state.run(max_steps)
     finally:
         _metrics.counter("optim.repair.candidates").inc(state.candidates)
+        _metrics.counter("optim.repair.local_candidates").inc(state.local)
 
 
 class _RepairState:
@@ -84,17 +88,21 @@ class _RepairState:
       (Eq. 9) violations; ``eviol[e]``: edge e's inter matching (Eq. 10);
       ``ties[(first node of a partition, group)]``: scan tying;
     - ``struct``: the sum of those and of the cuts' own checks, i.e. every
-      violation not a partition's, which no accepted move may raise.
+      violation not a partition's, which no accepted move may raise;
+    - ``fixed``: whether the design is a ``propagate`` fixed point, so
+      that a candidate can be built by ``set_fold_local``.
     """
 
     def __init__(self, problem: Problem, v: Variables, rep) -> None:
         self.problem = problem
         self.graph, self.backend = problem.graph, problem.backend
         self.platform = problem.platform
-        self.candidates = 0
+        self.candidates = self.local = 0
         self.v = v
+        self.closed = self.backend.keeps_fixed_points(self.graph)
+        self.fixed = self._is_fixed(v)
         self.evals = problem._eval_nodes(v)
-        self.groups = self.graph.scan_groups() if self.backend.scan_tying \
+        self.groups = self.graph.scan_members() if self.backend.scan_tying \
             else {}
         self._menus = {}
         self._infos = {}
@@ -121,6 +129,9 @@ class _RepairState:
                 sum(self.pviol) != len(rep.violations) - self.base:
             raise RuntimeError("repair: partition-local state disagrees "
                                "with check_all")
+
+    def _is_fixed(self, v: Variables) -> bool:
+        return self.backend.propagate(self.graph, v) == v
 
     @property
     def feasible(self) -> bool:
@@ -248,9 +259,14 @@ class _RepairState:
                 higher = [c for c in self._menu(i, var) if c > cur]
                 if not higher:
                     continue
-                v2 = backend.set_fold(graph, v, i, var, higher[0])
                 self.candidates += 1
-                moved = self._moved(v2)
+                if self.fixed:
+                    v2, moved = backend.set_fold_local(graph, v, i, var,
+                                                       higher[0])
+                    self.local += 1
+                else:
+                    v2 = backend.set_fold(graph, v, i, var, higher[0])
+                    moved = self._moved(v2)
                 s_in, s_out, kern = v2.s_in, v2.s_out, v2.kern
                 vals, inside, dnode = hbm[:], False, 0
                 for j in moved:
@@ -275,6 +291,9 @@ class _RepairState:
     def _accept(self, v2: Variables, moved: List[int], struct: int) -> None:
         edges, ties = self._touched(moved)
         self.v, self.struct = v2, struct
+        # a set_fold answer: a fixed point again where the backend keeps
+        # them and the move started from one, else ask propagate
+        self.fixed = (self.fixed and self.closed) or self._is_fixed(v2)
         for j, e in zip(moved, self.problem._eval_nodes(v2, moved)):
             self.evals[j] = e
             self.nviol[j] = self._info(j, v2)[0]
@@ -312,6 +331,10 @@ class _RepairState:
         for key in [k for k in self.ties if k[0] == worst[0]]:
             self.struct -= self.ties.pop(key)
         self.v = self.v.with_cuts(tuple(sorted(set(self.v.cuts) | {e})))
+        if self.backend.inter_matching or not self.fixed:
+            # a cut moves the inter-matching anchors; a tie that held in
+            # the partition holds in its halves
+            self.fixed = self._is_fixed(self.v)
         self.parts[wi:wi + 1] = halves
         self.res.insert(wi + 1, 0.0)
         self.pviol.insert(wi + 1, 0)

@@ -213,7 +213,10 @@ def _seeded_candidates(problem: Problem) -> List[Variables]:
     coarse that V_init (fully split, folds 1) cannot reach some globally
     uniform designs by single-node moves; seeding the classic designs and
     letting Algorithm 2 refine them fixes that. Each seed is clamped
-    per-node to the channel-factor constraint by set_fold."""
+    per-node to the channel-factor constraint by set_fold: from the
+    all-ones start, a fixed point of ``propagate`` on every backend, each
+    move is rebuilt where it reaches (``set_fold_local``) where the
+    backend keeps fixed points on this graph."""
     graph, backend, platform = problem.graph, problem.backend, problem.platform
     n = len(graph.nodes)
     seeds = []
@@ -229,11 +232,15 @@ def _seeded_candidates(problem: Problem) -> List[Variables]:
                 if si * so * kk < platform.chips // 4:
                     continue            # underusing the mesh: never optimal
                 uniform.append((si, so, kk))
+    local = backend.keeps_fixed_points(graph)
     for si, so, kk in uniform:
         v = Variables((), tuple([1] * n), tuple([1] * n), tuple([1] * n))
         for j in range(n):
             for var, val in zip(VARS, (si, so, kk)):
-                v = backend.set_fold(graph, v, j, var, val)
+                if local:
+                    v = backend.set_fold_local(graph, v, j, var, val)[0]
+                else:
+                    v = backend.set_fold(graph, v, j, var, val)
         v = repair(problem, v)
         seeds.append(v)
     return seeds

@@ -111,10 +111,24 @@ def test_host_evals_counts_memo_misses(tiny_arch, small_platform,
     assert metrics.snapshot()["counters"]["optim.host_evals"] == sum(misses)
 
 
+def _spy_fold_moves(monkeypatch, backend):
+    """Record each call of ``set_fold`` and of ``set_fold_local``."""
+    calls = []
+    for name in ("set_fold", "set_fold_local"):
+        real = getattr(type(backend), name)
+
+        def spy(self, *a, _real=real, _name=name, **kw):
+            calls.append(_name)
+            return _real(self, *a, **kw)
+
+        monkeypatch.setattr(type(backend), name, spy)
+    return calls
+
+
 def test_repair_candidates_counts_every_fold_move(tiny_arch, monkeypatch):
     """``optim.repair.candidates`` grows by one for each fold move that
-    ``repair`` hands to ``set_fold``, and not for a design already
-    feasible."""
+    ``repair`` builds (by ``set_fold`` or ``set_fold_local``), and not for
+    a design already feasible."""
     from conftest import TINY_SHAPE
     from repro.core.backends import BACKENDS
     from repro.core.graph_builder import build_hdgraph
@@ -124,14 +138,7 @@ def test_repair_candidates_counts_every_fold_move(tiny_arch, monkeypatch):
 
     graph = build_hdgraph(tiny_arch, TINY_SHAPE)
     backend = BACKENDS["spmd"]
-    calls = []
-    real = type(backend).set_fold
-
-    def spy(self, *a, **kw):
-        calls.append(a)
-        return real(self, *a, **kw)
-
-    monkeypatch.setattr(type(backend), "set_fold", spy)
+    calls = _spy_fold_moves(monkeypatch, backend)
     v0 = backend.initial(graph)
     roomy = Problem(graph=graph, platform=Platform(
         name="roomy", mesh_axes=(("data", 4), ("model", 4))),
@@ -147,6 +154,64 @@ def test_repair_candidates_counts_every_fold_move(tiny_arch, monkeypatch):
     assert len(calls) > 0
     assert metrics.snapshot()["counters"]["optim.repair.candidates"] == \
         len(calls)
+
+
+@pytest.mark.parametrize("backend_name", ["spmd", "megatron", "simple"])
+def test_repair_local_candidates_count_the_local_moves(tiny_arch,
+                                                       monkeypatch,
+                                                       backend_name):
+    """``optim.repair.local_candidates`` counts the candidates built by
+    ``set_fold_local``: all of them from a ``propagate`` fixed point, fewer
+    from a merged design that is not one, none where ``repair`` has
+    nothing to do."""
+    from conftest import TINY_SHAPE
+    from repro.core.backends import BACKENDS
+    from repro.core.graph_builder import build_hdgraph
+    from repro.core.objectives import Problem
+    from repro.core.optimizers.common import repair
+    from repro.core.platform import Platform
+
+    graph = build_hdgraph(tiny_arch, TINY_SHAPE)
+    backend = BACKENDS[backend_name]
+    mesh = (("data", 4), ("model", 4))
+
+    def problem(hbm_bytes):
+        return Problem(graph=graph, platform=Platform(
+            name="t", mesh_axes=mesh, hbm_bytes=hbm_bytes), backend=backend,
+            objective="latency", exec_model="spmd")
+
+    def counters():
+        return metrics.snapshot()["counters"]
+
+    v0 = backend.initial(graph)
+    assert backend.propagate(graph, v0) == v0
+    fat = max(e.hbm_resident
+              for e in problem(2**50).evaluate(v0).node_evals)
+    metrics.reset()
+    assert repair(problem(2**50), v0) == v0
+    assert "optim.repair.local_candidates" not in counters()
+
+    calls = _spy_fold_moves(monkeypatch, backend)
+    metrics.reset()
+    repair(problem(fat * 0.3), v0)
+    got = counters()
+    assert got["optim.repair.candidates"] > 0
+    assert got["optim.repair.local_candidates"] == \
+        got["optim.repair.candidates"] == calls.count("set_fold_local")
+
+    # the graph merged into one partition, one scan group left untied:
+    # not a fixed point until the first accepted move re-ties it
+    second = next(m for m in graph.scan_groups().values() if len(m) > 1)[1]
+    k = max(backend.candidates(graph, second, "kern", problem(1).platform))
+    merged = v0.with_cuts(()).replace_node(second, kern=k)
+    assert backend.propagate(graph, merged) != merged
+    del calls[:]
+    metrics.reset()
+    repair(problem(fat * 0.3), merged)
+    got = counters()
+    assert calls[0] == "set_fold"
+    assert got.get("optim.repair.local_candidates", 0) < \
+        got["optim.repair.candidates"] == len(calls)
 
 
 # ----------------------------------------------------------------------

@@ -9,6 +9,11 @@ start designs (random folds and cuts, merged designs that are not
 backend and three small graphs, and requires the same design and the
 same number of candidates. The cost-shape test holds ``repair`` to a
 constant number of memo misses however many candidates it scores.
+
+``Backend.set_fold_local`` builds a fold move of a ``propagate`` fixed
+point where it reaches; the local-move tests hold it to ``set_fold`` and
+its moved nodes to the whole-design diff for every node, variable and
+menu value, and the rule-based seeds to the ones ``set_fold`` builds.
 """
 import random
 
@@ -20,6 +25,7 @@ from repro.core.graph_builder import build_hdgraph
 from repro.core.hdgraph import Variables, partitions_from_cuts
 from repro.core.objectives import Problem
 from repro.core.optimizers.common import repair
+from repro.core.optimizers.rule_based import _seeded_candidates
 from repro.core.platform import Platform
 from repro.obs import metrics
 
@@ -184,3 +190,122 @@ def test_repair_costs_a_constant_number_of_memo_misses():
     assert v == want
     assert new_prob.host_evals <= 3
     assert metrics.snapshot()["counters"]["optim.repair.candidates"] == cands
+
+
+MESHES = {"4x4": (("data", 4), ("model", 4)), "2x4": (("data", 2), ("model", 4))}
+
+
+def _fixed_points(rng, prob, count):
+    """Random designs with random cuts, propagated: fixed points."""
+    g, b = prob.graph, prob.backend
+    out = []
+    for share in (0.0, 0.3, 0.7)[:count]:
+        v = _random_folds(rng, prob).with_cuts(_random_cuts(rng, g, share))
+        out.append(b.propagate(g, v))
+    return out
+
+
+@pytest.mark.parametrize("mesh", sorted(MESHES))
+@pytest.mark.parametrize("graph_name", sorted(GRAPHS))
+@pytest.mark.parametrize("backend", sorted(BACKENDS))
+def test_local_move_matches_set_fold(mesh, graph_name, backend):
+    graph = _graph(graph_name)
+    prob = _problem(graph, backend, "spmd", 2**40, MESHES[mesh])
+    b, plat = prob.backend, prob.platform
+    n = len(graph.nodes)
+    assert b.keeps_fixed_points(graph)
+    rng = random.Random(f"{mesh}/{graph_name}/{backend}")
+    moves = 0
+    for v in _fixed_points(rng, prob, 3):
+        assert b.propagate(graph, v) == v
+        before = list(zip(v.s_in, v.s_out, v.kern))
+        for i in range(n):
+            for var in VARS:
+                for value in b.candidates(graph, i, var, plat):
+                    want = b.set_fold(graph, v, i, var, value)
+                    got, moved = b.set_fold_local(graph, v, i, var, value)
+                    after = list(zip(want.s_in, want.s_out, want.kern))
+                    diff = [j for j in range(n) if after[j] != before[j]]
+                    assert got == want, (i, var, value)
+                    assert moved == diff, (i, var, value)
+                    # what chains of local moves rely on
+                    assert b.propagate(graph, want) == want
+                    moves += 1
+    assert moves > 3 * n
+
+
+def _sequential_seeds(prob):
+    """The rule-based uniform seeds, in their order, built by ``set_fold``
+    node by node and repaired."""
+    graph, b, plat = prob.graph, prob.backend, prob.platform
+    n = len(graph.nodes)
+    out = []
+    values = plat.fold_values()
+    for trip in ((si, so, kk) for si in values for so in values
+                 for kk in values):
+        chips = trip[0] * trip[1] * trip[2]
+        if chips > plat.chips or not plat.folds_realizable(trip) or \
+                chips < plat.chips // 4:
+            continue
+        v = Variables((), (1,) * n, (1,) * n, (1,) * n)
+        for j in range(n):
+            for var, val in zip(VARS, trip):
+                v = b.set_fold(graph, v, j, var, val)
+        out.append(repair(prob, v))
+    return out
+
+
+@pytest.mark.parametrize("graph_name", sorted(GRAPHS))
+@pytest.mark.parametrize("backend", sorted(BACKENDS))
+def test_seeds_match_the_sequential_set_fold(graph_name, backend):
+    graph = _graph(graph_name)
+    prob = _problem(graph, backend, "spmd", 2**40, MESHES["4x4"])
+    b, plat = prob.backend, prob.platform
+    n = len(graph.nodes)
+    seeds = _seeded_candidates(prob)
+    want = _sequential_seeds(prob)
+    assert len(seeds) == len(want) > 0
+    assert seeds == want
+
+
+def test_keeps_fixed_points_guards_a_mixed_scan_group():
+    """A scan group whose first member alone is elementwise: ``set_fold``
+    can leave a design that ``propagate`` moves again, and a local move of
+    such a design can miss the re-tie. ``keeps_fixed_points`` says so, and
+    ``repair`` and the seeds still give what ``set_fold`` gives."""
+    import dataclasses
+
+    graph = _graph("dense")
+    group = next(m for m in graph.scan_groups().values() if len(m) > 1)
+    other = next(j for j, nd in enumerate(graph.nodes)
+                 if nd.scan_group >= 0 and j not in group)
+    graph.nodes = [dataclasses.replace(nd, elementwise=True)
+                   if j == group[0] else nd
+                   for j, nd in enumerate(graph.nodes)]
+    mesh = MESHES["4x4"]
+    for backend in sorted(BACKENDS):
+        b = BACKENDS[backend]
+        assert not b.keeps_fixed_points(graph)
+        if not b.intra_matching:
+            continue
+        v0 = b.initial(graph).with_cuts(())
+        v1 = b.set_fold(graph, v0, group[0], "s_in", 2)
+        assert b.propagate(graph, v1) != v1
+        if not b.inter_matching:         # else it re-ties the partition
+            assert b.set_fold_local(graph, v1, other, "s_out", 2)[0] != \
+                b.set_fold(graph, v1, other, "s_out", 2)
+        probe = _problem(graph, backend, "spmd", 2**40, mesh)
+        fat = max(e.hbm_resident for e in
+                  probe.evaluate(b.initial(graph)).node_evals)
+        for share in (0.1, 0.3, 0.6):
+            for v in (b.initial(graph), v0):
+                want, cands = _full_repair(
+                    _problem(graph, backend, "spmd", fat * share, mesh), v)
+                metrics.reset()
+                got = repair(_problem(graph, backend, "spmd", fat * share,
+                                      mesh), v)
+                assert got == want, (backend, share)
+                assert metrics.snapshot()["counters"].get(
+                    "optim.repair.candidates", 0) == cands
+        prob = _problem(graph, backend, "spmd", fat * 0.3, mesh)
+        assert _seeded_candidates(prob) == _sequential_seeds(prob)
